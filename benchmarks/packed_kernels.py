@@ -6,7 +6,7 @@ Backend-honest per the dispatch policy (kernels/dispatch.py): on CPU the
 packed number times the jit-compiled XLA lowering of `accumulate_gemv`
 (the same mul-free select/add program the Pallas kernel runs — NEVER
 interpret-mode Pallas, which would be thousands of times slower than the
-serving path actually is); on tpu/gpu it times the compiled `packed_gemv`
+serving path actually is); on the TPU it times the compiled `packed_gemv`
 launch.  The `path` field records which one was measured.  The bytes ratio
 is analytic (16x ternary, 32x binary — codes only, no scale on the RNN
 path) and asserted >= 12x, the paper's memory-bandwidth claim.
@@ -48,7 +48,7 @@ def _gemv_rows(quick: bool):
     """x (B, H) @ W (H, 4H) — the decode-tick gate-matrix shape."""
     hs = (256,) if quick else (256, 512, 1024)
     bs = (1,) if quick else (1, 4, 8)
-    on_cpu = dispatch.backend() == "cpu"
+    on_cpu = not dispatch.use_pallas(None)
     rows = []
     for h in hs:
         for b in bs:

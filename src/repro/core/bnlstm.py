@@ -289,9 +289,12 @@ def rnn_state_init(cfg: RNNConfig, batch: int, dtype=None, *,
     `pos` is bookkeeping (the recurrence itself is position-free), so both
     layouts run the identical prefill/decode compute."""
     dtype = dtype or cfg.dtype
-    z = jnp.zeros((cfg.n_layers, batch, cfg.d_hidden), dtype)
+    shape = (cfg.n_layers, batch, cfg.d_hidden)
     pos = jnp.zeros((batch,) if per_slot else (), jnp.int32)
-    return RNNState(h=z, c=z, pos=pos)
+    # two buffers, not one: the engine donates the pool, and XLA refuses
+    # to donate one buffer twice
+    return RNNState(h=jnp.zeros(shape, dtype), c=jnp.zeros(shape, dtype),
+                    pos=pos)
 
 
 def rnn_write_slots(state: RNNState, sub: RNNState, slots) -> RNNState:

@@ -6,10 +6,13 @@ interpret-mode Pallas emulation in a serving path:
 
     backend   packed matmul / decode tick        interpret-mode Pallas
     -------   -------------------------------    ----------------------
-    tpu/gpu   compiled Pallas kernel             never
-    cpu       dense fp fallback (weights are     opt-in ONLY (parity
+    tpu       compiled Pallas kernel             never
+    cpu, gpu  dense fp fallback (weights are     opt-in ONLY (parity
               dequantized ONCE per session;      tests pass
               memory stays the packed codes)     interpret=True)
+
+The kernels use TPU-only Pallas features (VMEM scratch, Mosaic compiler
+parameters), so no other backend compiles them.
 
 Every entry that used to make this call locally (`rnn_decode_tables(dense=)`,
 `qmatmul`, the decode-step wrappers) now asks this module.  The convention
@@ -33,10 +36,7 @@ from typing import Optional
 
 import jax
 
-try:  # jax moved core types under jax.extend in newer releases
-    from jax.extend.core import ClosedJaxpr, Jaxpr
-except ImportError:  # pragma: no cover - older jax
-    from jax.core import ClosedJaxpr, Jaxpr
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 
 def backend() -> str:
@@ -47,35 +47,35 @@ def backend() -> str:
 def prefer_dense(dense: Optional[bool] = None) -> bool:
     """Should a serving session expand packed weights into dense fp tables?
 
-    None -> the backend policy: True on CPU (packed Pallas would only run
-    emulated there), False on real accelerators (the fused packed kernels
-    are the whole point).  An explicit bool is a caller override.
+    None -> the backend policy: False on the TPU (the fused packed kernels
+    are the whole point), True elsewhere (packed Pallas would only run
+    emulated there).  An explicit bool is a caller override.
     """
     if dense is not None:
         return dense
-    return backend() == "cpu"
+    return backend() != "tpu"
 
 
 def use_pallas(interpret: Optional[bool] = None) -> bool:
     """Should this op run a Pallas kernel at all?
 
-    False only on CPU with no explicit `interpret` request — that is the
+    False off the TPU with no explicit `interpret` request — that is the
     dense-fallback case.  `interpret=True` is the parity-test opt-in
-    (emulated kernel, real kernel semantics); on tpu/gpu the compiled
+    (emulated kernel, real kernel semantics); on the TPU the compiled
     kernel always runs.
     """
     if interpret is not None:
         return True
-    return backend() != "cpu"
+    return backend() == "tpu"
 
 
 def resolve_interpret(interpret: Optional[bool] = None) -> bool:
     """Interpret flag for a Pallas call that IS going to run: None means
-    'emulate on CPU, compile elsewhere' (direct kernel entries keep working
-    on CPU for tests that did not pass an explicit flag)."""
+    'compile on the TPU, emulate elsewhere' (direct kernel entries keep
+    working on CPU for tests that did not pass an explicit flag)."""
     if interpret is not None:
         return interpret
-    return backend() == "cpu"
+    return backend() != "tpu"
 
 
 # ---------------------------------------------------------------------------

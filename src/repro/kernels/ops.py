@@ -15,7 +15,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.qtensor import QTensor
-from repro.core.quantize import BINARY_GROUP, TERNARY_GROUP
 from repro.kernels import dispatch
 from repro.kernels import packed_matmul as PK
 
@@ -41,7 +40,7 @@ def packed_matmul(x: Array, wp: Array, k: int, alpha=1.0, *, mode: str = "ternar
     (`packed_gemv` — zero weight-path multiplies); larger M keeps the MXU
     decode-tile path, which is the right engine for prefill GEMM.
     """
-    group = TERNARY_GROUP if mode == "ternary" else BINARY_GROUP
+    group = PK.group_of(mode)
     lead = x.shape[:-1]
     K = x.shape[-1]
     N = wp.shape[1]
@@ -49,13 +48,15 @@ def packed_matmul(x: Array, wp: Array, k: int, alpha=1.0, *, mode: str = "ternar
     M = xm.shape[0]
 
     if M <= 8:
-        xm = _pad_to(_pad_to(xm.astype(jnp.float32), 8, 0), group, 1)
-        kp = max(xm.shape[1], wp.shape[0] * group)
-        xm = jnp.pad(xm, ((0, 0), (0, kp - xm.shape[1])))
+        # K padded to whole sublane tiles of packed rows (8 words per slot
+        # plane); pad activations are zero, so pad codes add nothing
+        kp = -(-max(K, wp.shape[0] * group) // (8 * group)) * (8 * group)
+        xm = jnp.pad(xm.astype(jnp.float32), ((0, -M % 8), (0, kp - K)))
         wpp = jnp.pad(wp, ((0, kp // group - wp.shape[0]), (0, -N % 128)))
         y = PK.packed_gemv(xm, wpp, kp, mode=mode, interpret=interpret)
     else:
-        bm = 128 if M >= 128 else 8
+        # 16 rows: the smallest block that tiles bf16 as well as fp32
+        bm = 128 if M >= 128 else 16
         bn = 128
         bk = 256 if K % 256 == 0 else group * 8
         xm = _pad_to(_pad_to(xm, bm, 0), bk, 1)
@@ -71,7 +72,7 @@ def quantize_pack(w: Array, u: Array, alpha, *, mode: str = "ternary",
                   interpret: Optional[bool] = None) -> Array:
     """Fused stochastic quantize (paper Eq. 4-6) + bit-pack.  w: (K, N) with
     K % GROUP == 0 (weights in this framework are 128-aligned)."""
-    group = TERNARY_GROUP if mode == "ternary" else BINARY_GROUP
+    group = PK.group_of(mode)
     K, N = w.shape
     bk = min(256, K) if K % 256 == 0 or K <= 256 else group * 8
     while K % bk:
@@ -114,7 +115,9 @@ def qmatmul(x: Array, w, *, interpret: Optional[bool] = None) -> Array:
     engines gate on `dispatch.packed_pallas_active` before construction.
     """
     if not isinstance(w, QTensor):
-        return x @ w
+        # HIGHEST: an fp32 operand is multiplied in fp32 — the TPU default
+        # rounds it to bf16 first (bf16 operands are exact either way)
+        return jnp.matmul(x, w, precision=jax.lax.Precision.HIGHEST)
     if w.codes.ndim > 2:
         L = w.codes.shape[0]
         if x.shape[0] != L:
@@ -134,7 +137,8 @@ def qmatmul(x: Array, w, *, interpret: Optional[bool] = None) -> Array:
         # hit this every step cache the dense weight once per session
         # instead (rnn_decode_tables(dense=True)).  interpret=True is the
         # parity-test opt-in that still forces the emulated kernel here.
-        y = jnp.dot(x.astype(jnp.float32), w.dequantize(jnp.float32))
+        y = jnp.dot(x.astype(jnp.float32), w.dequantize(jnp.float32),
+                    precision=jax.lax.Precision.HIGHEST)
         return y.astype(x.dtype)
     # zero-pad activations to the codes' K coverage: pad lanes multiply
     # zeros, so pack-time pad codes contribute exactly nothing.
@@ -157,14 +161,19 @@ def qmatmul(x: Array, w, *, interpret: Optional[bool] = None) -> Array:
 
 
 def prepare_gate_codes(qt: QTensor, n_gates: int) -> Array:
-    """Gate-align a packed recurrent weight for the fused decode kernel.
+    """Gate-align and strided-pack a packed recurrent weight for the fused
+    decode kernel.
 
-    `qt` packs wh (H, n_gates*H).  Each gate's N columns are sliced out,
+    `qt` packs wh (H, n_gates*H).  Each gate's N columns are sliced out and
     padded to the 128-lane tile Hp (so gate boundaries stay tile-aligned in
-    the kernel), the K code rows are padded to Hp/GROUP, and the gates are
-    stacked: (n_gates, Hp/G, Hp) uint32.  Pad K codes are harmless — the
-    matching activation lanes are zero-padded.  Done ONCE per serving
-    session (serve/recurrent.py caches the result in the decode tables)."""
+    the kernel), the K rows are padded to Kc = Hp rounded up to 8*GROUP,
+    and the gate is re-packed STRIDED: slot j of word r holds row j*R + r
+    (R = Kc/GROUP), so the kernel feeds each slot one aligned slice of h
+    (kernels/decode_step.py).  Stacked: (n_gates, R, Hp) uint32.  Pad codes
+    are harmless — the matching activation lanes are zero-padded.  Done
+    ONCE per serving session (serve/recurrent.py caches the result in the
+    decode tables)."""
+    from repro.core import quantize as Q
     from repro.kernels.decode_step import BN_TILE
 
     if qt.scale is not None:
@@ -178,16 +187,28 @@ def prepare_gate_codes(qt: QTensor, n_gates: int) -> Array:
     if H * n_gates != N or qt.k != H:
         raise ValueError(f"expected a square-per-gate (H, {n_gates}*H) "
                          f"recurrent weight, got k={qt.k}, N={N}")
+    G = qt.group
     hp = -(-max(H, 1) // BN_TILE) * BN_TILE
-    gates = [jnp.pad(qt.codes[:, i * H:(i + 1) * H],
-                     ((0, hp // qt.group - kg), (0, hp - H)))
-             for i in range(n_gates)]
+    kc = -(-hp // (8 * G)) * (8 * G)
+    unpack, pack = ((Q.unpack_ternary, Q.pack_ternary) if qt.mode == "ternary"
+                    else (Q.unpack_binary, Q.pack_binary))
+    vals = unpack(qt.codes, kg * G)[:H]            # exact ±1/0, (H, N)
+    gates = []
+    for i in range(n_gates):
+        w = jnp.pad(vals[:, i * H:(i + 1) * H], ((0, kc - H), (0, hp - H)))
+        # contiguous packing of the row permutation r*G + j <- j*R + r
+        w = w.reshape(G, kc // G, hp).transpose(1, 0, 2).reshape(kc, hp)
+        gates.append(pack(w))
     return jnp.stack(gates)
 
 
 # padded head weight bytes the fused tick will keep in VMEM alongside the
-# codes; beyond this the head runs as one XLA dot outside the launch
-HEAD_VMEM_BYTES = 4 * 1024 * 1024
+# codes; beyond this the head runs as one XLA dot outside the launch.  Set
+# from the v5e compiler, not guessed: with Hp in {1024, 2048} and B=8 a
+# 12 MiB head still compiles and 16 MiB runs out of VMEM; 8 MiB leaves room
+# for the larger code tiles of wider models (tests/test_tpu_compile.py
+# compiles the tick at this edge)
+HEAD_VMEM_BYTES = 8 * 1024 * 1024
 
 
 def fused_decode_tick(tok: Array, h: Array, c: Array, tick: dict, *,
@@ -201,8 +222,8 @@ def fused_decode_tick(tok: Array, h: Array, c: Array, tick: dict, *,
     (arrays only — it travels through jits as a pytree argument):
 
       rows0            (vocab, g*H)  layer-0 token rows, BN + bias folded
-      codes_h          (L, g, Hp/G, Hp)   gate-aligned packed wh codes
-      codes_x          (max(L-1,1), g, Hp/G, Hp)  packed wx codes, l >= 1
+      codes_h          (L, g, R, Hp)   gate-aligned strided wh codes
+      codes_x          (max(L-1,1), g, R, Hp)  same for wx, l >= 1
       scale_h/shift_h  (L, g, Hp)    h-side BN affine, alpha folded in scale
       scale_x/shift_x  (like codes_x's lead, g, Hp)  x-side BN + bias fold
       scale_c/shift_c  (L, 1, Hp)    cell-norm affine
@@ -225,8 +246,8 @@ def fused_decode_tick(tok: Array, h: Array, c: Array, tick: dict, *,
     f32 = jnp.float32
 
     rows = jnp.take(tick["rows0"], tok, axis=0).astype(f32)     # (B, g*H)
-    ax0 = jnp.pad(rows.reshape(B, g, H),
-                  ((0, bp - B), (0, 0), (0, hp - H)))
+    ax0 = jnp.pad(rows.reshape(B, g, H).transpose(1, 0, 2),
+                  ((0, 0), (0, bp - B), (0, hp - H)))          # (g, Bp, Hp)
     pad_state = lambda a: jnp.pad(a.astype(f32),
                                   ((0, 0), (0, bp - B), (0, hp - H)))
     if live is None:
@@ -250,7 +271,8 @@ def fused_decode_tick(tok: Array, h: Array, c: Array, tick: dict, *,
         greedy = tk[:B, 0]
     else:  # head too big for VMEM: one XLA dot outside, still one launch
         hn, cn = out
-        lg = jnp.dot(hn[-1], ws, preferred_element_type=f32) + bs
+        lg = jnp.dot(hn[-1], ws, precision=jax.lax.Precision.HIGHEST,
+                     preferred_element_type=f32) + bs
         logits = lg[:B, :vocab]
         greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     return (logits.astype(h.dtype), hn[:, :B, :H].astype(h.dtype),

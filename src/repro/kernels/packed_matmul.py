@@ -37,70 +37,85 @@ def _unpack_ternary_tile(packed: Array, bk: int) -> Array:
 
 
 def _unpack_binary_tile(packed: Array, bk: int) -> Array:
-    """(bk/32, bn) uint32 -> (bk, bn) float32 in {-1, +1}."""
+    """(bk/32, bn) uint32 -> (bk, bn) float32 in {-1, +1}.  A select, not a
+    uint32 -> float cast: Mosaic has no such cast."""
     shifts = jnp.arange(BINARY_GROUP, dtype=jnp.uint32)[None, :, None]
     bits = (packed[:, None, :] >> shifts) & jnp.uint32(1)
-    vals = bits.astype(jnp.float32) * 2.0 - 1.0
+    vals = jnp.where(bits == 1, 1.0, -1.0)
     return vals.reshape(bk, packed.shape[-1])
 
 
-def code_masks(packed: Array, *, mode: str) -> tuple[Array, Array]:
-    """Decode packed codes to (plus, minus) BOOLEAN masks — no arithmetic on
-    the weight values, ever.  packed: (K/G, N) uint32 -> two (K, N) bools.
+def group_of(mode: str) -> int:
+    return TERNARY_GROUP if mode == "ternary" else BINARY_GROUP
 
-    Ternary: plus where code==0b01, minus where code==0b11, neither for the
-    zero code.  Binary: plus where bit==1, minus where bit==0.  The masks
-    are what the accumulation-only GEMV selects activations through; the
-    ±1/0 weight VALUES never materialize as floats on this path.
-    """
+
+def slot_major(x: Array, group: int) -> Array:
+    """x (B, K) -> (G, K/G, B): entry [j, r, b] is x[b, r*G + j], the
+    activation that code slot j of packed row r selects.  Contiguous packing
+    (core/quantize.py) puts weights r*G .. r*G+G-1 in word r; this layout
+    hands the GEMV each slot's activations as one sublane-major plane."""
+    b, k = x.shape
+    return x.reshape(b, k // group, group).transpose(2, 1, 0)
+
+
+def _slot_masks(packed: Array, j, mode: str):
+    """(plus, minus) boolean masks of code slot `j` (traced int32) of every
+    packed word.  Binary codes have no zero, so minus is `not plus` and is
+    returned as None."""
     if mode == "ternary":
-        # iota << 1, not 2*iota: even the shift table is mul-free so the
-        # static accumulation-only assertion holds over the whole path (a
-        # stepped arange would materialize a constant Pallas can't capture)
-        shifts = (jnp.arange(TERNARY_GROUP, dtype=jnp.uint32)
-                  << jnp.uint32(1))[None, :, None]
-        codes = (packed[:, None, :] >> shifts) & jnp.uint32(3)
-        k = packed.shape[0] * TERNARY_GROUP
-        plus = (codes == 1).reshape(k, packed.shape[-1])
-        minus = (codes == 3).reshape(k, packed.shape[-1])
-        return plus, minus
-    shifts = jnp.arange(BINARY_GROUP, dtype=jnp.uint32)[None, :, None]
-    bits = (packed[:, None, :] >> shifts) & jnp.uint32(1)
-    k = packed.shape[0] * BINARY_GROUP
-    plus = (bits == 1).reshape(k, packed.shape[-1])
-    return plus, jnp.logical_not(plus)
+        code = (packed >> (j << 1).astype(jnp.uint32)) & jnp.uint32(3)
+        return code == 1, code == 3
+    bit = (packed >> j.astype(jnp.uint32)) & jnp.uint32(1)
+    return bit == 1, None
+
+
+def accumulate_planes(xs, packed: Array, *, mode: str) -> Array:
+    """y = x @ unpack(packed) with ZERO multiplies — the paper's MAC-free
+    inner loop (DESIGN.md §11).  xs: (G, R, B) slot-major activations (an
+    array or a VMEM ref); packed: (R, N) uint32; returns (B, N) fp32.
+
+    A loop over the G code slots: slot j's codes decode to (plus, minus)
+    boolean masks over the (R, N) word plane, and each batch row adds
+    `select(plus, x, select(minus, -x, 0))` of its activation column
+    xs[j, :, b] broadcast across the N lanes — shift/and/compare/select/add
+    only, so the ±1/0 weights never become floats and the jaxpr holds no
+    `mul`/`dot_general` (`dispatch.assert_accumulation_only`).  Nothing
+    larger than one (R, N) plane is live, and per row the partial sums fold
+    into one (8, N) sublane tile, so VMEM stays small at any K.
+
+    Binary pad safety: a ZERO pad code decodes to minus (−1), but pad
+    activation lanes are zero-padded by every caller, so the pad adds -0.0.
+    """
+    group, (R, N) = group_of(mode), packed.shape
+    B = xs.shape[-1]
+    fold = 8 if R % 8 == 0 else R
+
+    def body(j, acc):
+        plus, minus = _slot_masks(packed, j, mode)
+        xj = xs[j]                                  # (R, B)
+        out = []
+        for b in range(B):
+            xc = xj[:, b:b + 1]                     # (R, 1) -> N lanes
+            neg = -xc if minus is None else jnp.where(minus, -xc, 0.0)
+            t = jnp.where(plus, xc, neg)
+            out.append(acc[b] + jnp.sum(t.reshape(R // fold, fold, N), axis=0))
+        return tuple(out)
+
+    acc = jax.lax.fori_loop(
+        0, group, body,
+        tuple(jnp.zeros((fold, N), jnp.float32) for _ in range(B)))
+    return jnp.concatenate([jnp.sum(a, axis=0, keepdims=True) for a in acc])
 
 
 def accumulate_gemv(x: Array, packed: Array, *, mode: str) -> Array:
-    """y = x @ unpack(packed) with ZERO multiplies — the paper's MAC-free
-    inner loop (DESIGN.md §11).  x: (B, K) fp; packed: (K/G, N) uint32;
-    returns (B, N) fp32.
-
-    The decoded weight is never a float: codes become (plus, minus) boolean
-    masks, each output column is `sum(select(plus, x, 0)) -
-    sum(select(minus, x, 0))` — shift/and/compare/select/add only.  Tier-1
-    asserts this statically (`dispatch.assert_accumulation_only`): the jaxpr
-    contains no `mul`/`dot_general`.  B is a static Python loop: at decode
-    B is the (padded) slot count, <= 8, and unrolling keeps every step a
-    plain lane-wise select + row reduction the VPU streams.
-
-    Binary pad safety: a ZERO pad code decodes to minus (−1), but pad
-    activation lanes are zero-padded by every caller, so `select(minus, 0,
-    0)` contributes exactly nothing — same invariant the MXU path relies
-    on.
-    """
-    x = x.astype(jnp.float32)
-    plus, minus = code_masks(packed, mode=mode)
-    rows = []
-    for b in range(x.shape[0]):
-        xb = x[b, :, None]  # (K, 1) broadcasts across the N output columns
-        t = jnp.where(plus, xb, 0.0) - jnp.where(minus, xb, 0.0)
-        rows.append(jnp.sum(t, axis=0))
-    return jnp.stack(rows)
+    """`accumulate_planes` on a plain (B, K) activation against contiguously
+    packed (K/G, N) codes: y = x @ unpack(packed), (B, N) fp32."""
+    xs = slot_major(x.astype(jnp.float32), group_of(mode))
+    return accumulate_planes(xs, packed, mode=mode)
 
 
-def _gemv_kernel(x_ref, wp_ref, o_ref, *, mode: str):
-    o_ref[...] = accumulate_gemv(x_ref[...], wp_ref[...], mode=mode)
+def _gemv_kernel(xs_ref, wp_ref, o_ref, *, mode: str):
+    o_ref[...] = accumulate_planes(xs_ref, wp_ref[...], mode=mode)
 
 
 def packed_gemv(x: Array, wp: Array, k: int, *, mode: str,
@@ -112,8 +127,10 @@ def packed_gemv(x: Array, wp: Array, k: int, *, mode: str,
     decodes codes to ±1 floats and feeds a dense dot (right for prefill
     GEMM, M large), this kernel selects/accumulates activations through the
     code masks — the arithmetic the paper's ASIC does.  `ops.packed_matmul`
-    routes M <= 8 here and larger M to the MXU path."""
-    group = TERNARY_GROUP if mode == "ternary" else BINARY_GROUP
+    routes M <= 8 here and larger M to the MXU path.  x goes in slot-major
+    (`slot_major`, an XLA transpose of a few KB), so the kernel reads each
+    slot's activations as a sublane plane."""
+    group = group_of(mode)
     bp, K = x.shape
     N = wp.shape[1]
     if K != k or wp.shape[0] * group != K:
@@ -121,6 +138,7 @@ def packed_gemv(x: Array, wp: Array, k: int, *, mode: str,
     if N % block_n:
         raise ValueError(f"N={N} must be a multiple of block_n={block_n}")
     interpret = dispatch.resolve_interpret(interpret)
+    xs = slot_major(x.astype(jnp.float32), group)
 
     kernel = functools.partial(_gemv_kernel, mode=mode)
     dispatch.count_launch(f"{mode}_packed_gemv")
@@ -128,14 +146,14 @@ def packed_gemv(x: Array, wp: Array, k: int, *, mode: str,
         kernel,
         grid=(N // block_n,),
         in_specs=[
-            pl.BlockSpec((bp, K), lambda j: (0, 0)),
+            pl.BlockSpec(xs.shape, lambda j: (0, 0, 0)),
             pl.BlockSpec((K // group, block_n), lambda j: (0, j)),
         ],
         out_specs=pl.BlockSpec((bp, block_n), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((bp, N), jnp.float32),
         interpret=interpret,
         name=f"{mode}_packed_gemv",
-    )(x, wp)
+    )(xs, wp)
 
 
 def _matmul_kernel(x_ref, wp_ref, o_ref, acc_ref, *, bk: int, mode: str):
@@ -159,7 +177,7 @@ def packed_matmul(x: Array, wp: Array, k: int, *, mode: str,
 
     M, N, K must already be multiples of the block dims (ops.py pads).
     """
-    group = TERNARY_GROUP if mode == "ternary" else BINARY_GROUP
+    group = group_of(mode)
     M, K = x.shape
     N = wp.shape[1]
     if K != k or wp.shape[0] * group != K:

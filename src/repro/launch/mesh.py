@@ -13,6 +13,17 @@ import math
 from typing import Optional, Sequence
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes, devices=None):
+    """`jax.make_mesh` with Auto axes.  Since jax 0.9 the default axis type
+    is Explicit, which types every sharded array by its layout and refuses
+    ops (a dynamic slot gather on a 'data'-sharded pool) whose output
+    sharding it cannot infer; the engine and trainer pin in/out shardings
+    on their jits and let the partitioner place the rest, which is Auto."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -24,7 +35,7 @@ def make_production_mesh(*, multi_pod: bool = False):
         raise RuntimeError(
             f"need {n} devices for mesh {shape}, have {len(devices)} — run "
             f"under dryrun.py (it forces 512 host devices) or on the pod")
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return _mesh(shape, axes, devices[:n])
 
 
 def make_host_mesh(model: int = 1):
@@ -47,8 +58,8 @@ def make_host_mesh(model: int = 1):
             f"mesh — pass model={g} explicitly if that is what you want")
     data = n // model
     if model > 1:
-        return jax.make_mesh((data, model), ("data", "model"))
-    return jax.make_mesh((n,), ("data",))
+        return _mesh((data, model), ("data", "model"))
+    return _mesh((n,), ("data",))
 
 
 def parse_mesh_spec(spec: str) -> dict:
@@ -84,4 +95,4 @@ def make_serve_mesh(spec: str):
             f"mesh data={d},model={m} needs {d * m} devices, have "
             f"{len(devices)} — on CPU run under "
             f"XLA_FLAGS=--xla_force_host_platform_device_count=N")
-    return jax.make_mesh((d, m), ("data", "model"), devices=devices[: d * m])
+    return _mesh((d, m), ("data", "model"), devices[: d * m])

@@ -52,6 +52,7 @@ from repro.core import bnlstm as BL
 from repro.core.qtensor import export_packed, tree_nbytes
 from repro.core.quantize import QuantSpec
 from repro.models import transformer as T
+from repro.runtime import device_banner, enable_compile_cache
 from repro.serve.engine import Request, ServeEngine
 from repro.serve.recurrent import (RNNRuntime, TransformerRuntime,
                                    drive_session, speculative_draft)
@@ -163,9 +164,13 @@ def run_traffic(cfg, rt, args, draft=None) -> dict:
     print(f"traffic: {m['requests']} requests over {m['wall_s']:.2f}s "
           f"({args.rate:.1f} req/s offered, {args.slots} slots, "
           f"prefill chunk {args.prefill_chunk})")
+    # a spec round replaces the plain tick: its draft step's launches count
+    launches = (f"draft Pallas launches per step: {m['draft_launches']}"
+                if draft is not None else
+                f"Pallas launches per tick: {m['tick_launches']}")
     print(f"aggregate decode: {m['agg_tok_s']:.1f} tok/s  "
           f"occupancy: {100 * m['occupancy']:.0f}%  "
-          f"ticks: {m['ticks']} (traces: {m['tick_traces']}, "
+          f"ticks: {m['ticks']} (traces: {m['tick_traces']}, {launches}, "
           f"prefill traces: {m['prefill_traces']})")
     print(f"latency: p50 {m['p50_latency_s']*1e3:.0f} ms  "
           f"p95 {m['p95_latency_s']*1e3:.0f} ms  |  "
@@ -287,6 +292,7 @@ def main(argv=None):
     if args.mesh and not (args.traffic or args.listen):
         raise SystemExit("--mesh shards the continuous-batching engine; "
                          "run it with --traffic or --listen")
+    print(device_banner(), flush=True)
     key = jax.random.PRNGKey(args.seed)
     build = _build_rnn if args.arch in RNN_ARCH_IDS else _build_transformer
     draft = None
@@ -320,4 +326,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

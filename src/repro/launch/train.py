@@ -60,7 +60,8 @@ from repro.data.synth import markov_bytes, token_stream
 from repro.data.text import ByteCorpus
 from repro.launch.sharding import (batch_shardings, param_pspec,
                                    state_shardings)
-from repro.runtime import use_mesh
+from repro.launch.mesh import make_host_mesh
+from repro.runtime import device_banner, enable_compile_cache, use_mesh
 from repro.train import checkpoint as CK
 from repro.train.elastic import best_mesh_shape, make_mesh_from_plan
 from repro.train.fault_tolerance import (RESTART_EXIT_CODE, PreemptionHandler,
@@ -164,7 +165,7 @@ def run_rnn(args):
     if args.compress_grads:
         # pure data parallelism over whatever devices exist (a 1-device mesh
         # still exercises the shard_map compressed path end-to-end)
-        mesh = jax.make_mesh((len(jax.devices()),), ("data",))
+        mesh = make_host_mesh()
 
     opt_cfg = OptConfig(kind=args.opt or "adamw", lr=args.lr,
                         momentum=args.momentum, clip_norm=1.0,
@@ -224,6 +225,9 @@ def run_rnn(args):
                                        jnp.asarray(scale, jnp.float32))
                 jax.block_until_ready(metrics["loss"])
             monitor.record(jax.process_index(), tm.dt)
+            if not np.isfinite(float(metrics["loss"])):
+                raise SystemExit(f"step {step}: loss "
+                                 f"{float(metrics['loss'])} is not finite")
             if step % args.log_every == 0 or step == args.steps - 1:
                 print(f"step {step:6d} loss {float(metrics['loss']):.4f} "
                       f"bpc {float(metrics['bpc']):.3f} "
@@ -387,6 +391,7 @@ def run_rnn_pipeline(args):
     })
 
     # --- export the trained masters and serve them --------------------------
+    print(device_banner(), flush=True)  # the legs are done: now take the chip
     from repro.core.qtensor import tree_nbytes
     from repro.serve.engine import Request, ServeEngine
     from repro.serve.recurrent import (RNNRuntime, drive_session,
@@ -607,6 +612,8 @@ def run_transformer(args):
 
 def main(argv=None):
     args = build_argparser().parse_args(argv)
+    if not args.pipeline:  # the pipeline's legs are child processes: the
+        print(device_banner(), flush=True)  # parent must not hold the chip
     if args.arch in RNN_ARCH_IDS:
         return run_rnn_pipeline(args) if args.pipeline else run_rnn(args)
     if args.pipeline:
@@ -616,4 +623,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

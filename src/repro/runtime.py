@@ -31,14 +31,38 @@ def current_compute_rules():
 
 
 def abstract_mesh(sizes: Sequence[int], names: Sequence[str]):
-    """Device-free mesh for spec-building and tests, across jax versions:
-    jax >= 0.5 takes AbstractMesh(axis_sizes, axis_names); 0.4.x takes one
-    tuple of (name, size) pairs.  Same OrderedDict shape either way."""
+    """Device-free mesh for spec-building and tests."""
     from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(sizes), tuple(names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, sizes)))
+    return AbstractMesh(tuple(sizes), tuple(names))
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where JAX_COMPILATION_CACHE_DIR is set, jax already keeps the cache
+    there and nothing else is set here.  Otherwise the cache lives at one
+    fixed path in the checkout, `<repo>/.jax_cache` (git-ignored), so the
+    next process run from the same checkout finds what this one compiled; a
+    per-process or temporary directory would start empty every run.
+    Launchers call this before their first compile; tests never do."""
+    import os
+    from pathlib import Path
+
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(Path(__file__).resolve().parents[2] / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def device_banner() -> str:
+    """What this process runs on, as JAX reports it: platform, device kind
+    and device count — printed by every launcher, so no output can be read
+    as a chip result when it came from the CPU."""
+    devs = jax.devices()
+    return (f"device: platform={devs[0].platform} "
+            f"kind={devs[0].device_kind} count={len(devs)}")
 
 
 @contextlib.contextmanager

@@ -393,6 +393,9 @@ class ServeEngine:
         # dense-fallback path (no interpret-mode Pallas in serving), -1
         # until the first tick traces.
         self.tick_launches = -1
+        # the same count for the speculative draft's decode step inside
+        # the spec round's scan (1 for a packed draft's fused tick)
+        self.draft_launches = -1
         self._occupancy_sum = 0.0
         self._gen_tokens = 0      # cumulative over the engine's life
         self._drafted = 0         # speculative accounting: proposed drafts
@@ -488,16 +491,16 @@ class ServeEngine:
 
         # the pool is dead the moment the tick/prefill/reset returns its
         # successor, so donate it (and the pending/key chains) — without
-        # donation every tick would COPY all B KV caches.  CPU ignores
-        # donation with a warning, so only ask off-CPU.  The prm tree is
-        # NEVER donated: the same arrays are passed every call.
-        cpu = jax.default_backend() == "cpu"
+        # donation every tick would COPY all B KV caches.  Every backend
+        # donates (the CPU too), so the tests run the chip's buffer
+        # discipline.  The prm tree is NEVER donated: the same arrays are
+        # passed every call.
         self._tick = _mjit(
             tick,
             in_sh=(self._prm_sh, self._pool_sh, self._vec_sh, self._vec_sh,
                    self._row_sh, self._vec_sh, self._vec_sh),
             out_sh=(self._pool_sh, self._vec_sh, self._row_sh),
-            donate=() if cpu else (1, 2, 4))
+            donate=(1, 2, 4))
 
         def admit_commit(logits, key, t, k, pending, keys, temp, topk, live,
                          slot):
@@ -536,7 +539,7 @@ class ServeEngine:
             prefill_slot,
             in_sh=(self._prm_sh, self._pool_sh, R, R, R),
             out_sh=(R, self._pool_sh),
-            donate=() if cpu else (1,))
+            donate=(1,))
         # retire-time slot scrub, shape-aware: recurrent leaves + positions
         # to zero, attention KV masked in place, the device live bit
         # cleared — the freed row must read as fresh because the next
@@ -547,7 +550,7 @@ class ServeEngine:
                 jnp.where(mask, False, live)),
             in_sh=(self._pool_sh, self._vec_sh, self._vec_sh),
             out_sh=(self._pool_sh, self._vec_sh),
-            donate=() if cpu else (0,))
+            donate=(0,))
 
         if self.prefix_cache is not None:
             # prefix-cache device paths.  The splice is the SAME full-row
@@ -564,7 +567,7 @@ class ServeEngine:
 
             self._splice = _mjit(
                 splice, in_sh=(self._pool_sh, self._sub_sh, R),
-                out_sh=self._pool_sh, donate=() if cpu else (0,))
+                out_sh=self._pool_sh, donate=(0,))
 
         if not self.spec:
             return
@@ -597,6 +600,7 @@ class ServeEngine:
             (commit restores their pre-round state exactly), and their
             pending/key chains never advance."""
             self.spec_traces += 1
+            from repro.kernels import dispatch
             ks = jax.vmap(jax.random.split)(keys)          # (B, 2, 2)
             rk = ks[:, 1]
             new_keys = jnp.where(live[:, None], ks[:, 0], keys)
@@ -610,7 +614,9 @@ class ServeEngine:
 
             def dbody(carry, step_keys):
                 dst, tok = carry
+                launches0 = dispatch.launch_count()
                 lg, dst = draft.decode_fn(tok, dst, live, prm=dprm)
+                self.draft_launches = dispatch.launch_count() - launches0
                 nxt = sample_slots(lg, step_keys, temperature=temp,
                                    top_k=topk, vocab=self.vocab)
                 nxt = jnp.where(live, nxt, tok)
@@ -648,7 +654,7 @@ class ServeEngine:
                    self._vec_sh, self._vec_sh),
             out_sh=(self._pool_sh, self._dpool_sh, self._vec_sh,
                     self._row_sh, self._row_sh),
-            donate=() if cpu else (2, 3, 4, 6))
+            donate=(2, 3, 4, 6))
 
         dwrite = (draft.write_slots if hasattr(draft, "write_slots")
                   else tree_write_slot)
@@ -671,7 +677,7 @@ class ServeEngine:
             in_sh=(self._prm_sh, self._dprm_sh, self._pool_sh,
                    self._dpool_sh, R, R, R),
             out_sh=(R, self._pool_sh, self._dpool_sh),
-            donate=() if cpu else (2, 3))
+            donate=(2, 3))
         self._spec_reset = _mjit(
             lambda pool, dpool, live, mask: (
                 tree_reset_slots(pool, self._ref, mask),
@@ -680,7 +686,7 @@ class ServeEngine:
             in_sh=(self._pool_sh, self._dpool_sh, self._vec_sh,
                    self._vec_sh),
             out_sh=(self._pool_sh, self._dpool_sh, self._vec_sh),
-            donate=() if cpu else (0, 1))
+            donate=(0, 1))
 
         if self.prefix_cache is not None:
             self._dgather = _mjit(
@@ -692,7 +698,7 @@ class ServeEngine:
 
             self._dsplice = _mjit(
                 dsplice, in_sh=(self._dpool_sh, self._dsub_sh, R),
-                out_sh=self._dpool_sh, donate=() if cpu else (0,))
+                out_sh=self._dpool_sh, donate=(0,))
 
     # -- clock --------------------------------------------------------------
 
@@ -1143,6 +1149,7 @@ class ServeEngine:
             d["mesh"] = {str(a): int(n) for a, n in self.mesh.shape.items()}
         if self.spec:
             d.update({"spec_traces": self.spec_traces,
+                      "draft_launches": self.draft_launches,
                       "drafted_tokens": self._drafted,
                       "accepted_drafts": self._accepted})
         if self.prefix_cache is not None:
@@ -1158,7 +1165,7 @@ class ServeEngine:
         counters are saved and restored: tick_traces stays a property of
         the SERVING path, not of diagnostics."""
         t, l = self.tick_traces, self.tick_launches
-        s = self.spec_traces
+        s, dl = self.spec_traces, self.draft_launches
         try:
             if self.spec:
                 low = self._spec_tick.lower(
@@ -1172,7 +1179,7 @@ class ServeEngine:
             return low.compile().as_text()
         finally:
             self.tick_traces, self.tick_launches = t, l
-            self.spec_traces = s
+            self.spec_traces, self.draft_launches = s, dl
 
     # -- the batch driver ---------------------------------------------------
 
@@ -1247,6 +1254,7 @@ class ServeEngine:
             "tick_traces": self.tick_traces,  # cumulative on purpose: the
             "prefill_traces": self.prefill_traces,  # invariants are ==1 and
             "occupancy": occ / ticks if ticks else 0.0,  # <= bucket count
+            "tick_launches": self.tick_launches,
         }
         if self.spec:
             drafted = self._drafted - drafted0
@@ -1255,6 +1263,7 @@ class ServeEngine:
                 "spec_k": self.spec_k,
                 "spec_rounds": ticks,      # every tick is a spec round
                 "spec_traces": self.spec_traces,  # cumulative: invariant ==1
+                "draft_launches": self.draft_launches,
                 "drafted_tokens": drafted,
                 "accepted_drafts": accepted,
                 "accept_rate": accepted / drafted if drafted else 0.0,

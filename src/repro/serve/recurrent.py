@@ -74,8 +74,8 @@ class RNNRuntime:
         self.variables = variables
         self._interpret = interpret
         # dense_tables=None lets kernels/dispatch.py pick the backend-honest
-        # path: dense fp tables on CPU (no interpret-mode Pallas in serving),
-        # packed tables + the whole-tick fused kernel on tpu/gpu.  Parity
+        # path: dense fp tables off the TPU (no interpret-mode Pallas in serving),
+        # packed tables + the whole-tick fused kernel on the TPU.  Parity
         # tests opt into packed-on-CPU with dense_tables=False +
         # interpret=True.
         self._dense_tables = dispatch.prefer_dense(dense_tables)

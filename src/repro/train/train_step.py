@@ -113,7 +113,7 @@ def make_train_step(cfg, opt_cfg: OptConfig,
     # This path is pure data parallelism (each replica holds full params and
     # ternarizes its local gradient before the cross-replica mean); combine
     # with TP by nesting meshes at the launcher level.
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from repro.runtime import use_mesh
 
     data_axes = tuple(a for a in ("pod", "data") if a in mesh.shape)
@@ -145,7 +145,7 @@ def make_train_step(cfg, opt_cfg: OptConfig,
             local_grads, mesh=mesh,
             in_specs=(rep, batch_specs, rep, rep),
             out_specs=(rep, rep, rep, rep),
-            check_rep=False)
+            check_vma=False)
         grads, new_res, loss, nll = fn(state.params, batch, sub, state.residual)
         metrics = {"loss": loss, "nll": nll}
         params, opt, metrics = apply_updates(state, grads, metrics)
@@ -200,7 +200,7 @@ def make_rnn_train_step(cfg: BL.RNNConfig, opt_cfg: OptConfig,
 
         return step
 
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from repro.runtime import use_mesh
 
     data_axes = tuple(a for a in ("pod", "data") if a in mesh.shape)
@@ -225,7 +225,7 @@ def make_rnn_train_step(cfg: BL.RNNConfig, opt_cfg: OptConfig,
             local_grads, mesh=mesh,
             in_specs=(rep, rep, tspec, tspec, rep, rep),
             out_specs=(rep, rep, rep, rep),
-            check_rep=False)
+            check_vma=False)
         grads, new_res, loss, new_bn = fn(
             state.params, state.bn_state, batch["tokens"], batch["targets"],
             sub, state.residual)

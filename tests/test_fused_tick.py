@@ -193,16 +193,16 @@ def test_decode_tick_is_one_pallas_launch():
 
 
 def test_cpu_runtime_defaults_to_dense_tables():
-    """Backend-honest dispatch: on CPU a packed runtime serves through dense
-    tables (no tick artifact, no Pallas); elsewhere through packed ones."""
+    """Backend-honest dispatch: off the TPU a packed runtime serves through
+    dense tables (no tick artifact, no Pallas); on it through packed ones."""
     from repro.serve.recurrent import RNNRuntime
 
     cfg = _rnn_cfg("lstm")
     qvar = _packed_vars(cfg)
     rt = RNNRuntime(cfg, qvar)
-    on_cpu = dispatch.backend() == "cpu"
-    assert rt._dense_tables == on_cpu
-    assert ("tick" in rt.tables[0]) == (not on_cpu)
+    on_tpu = dispatch.backend() == "tpu"
+    assert rt._dense_tables == (not on_tpu)
+    assert ("tick" in rt.tables[0]) == on_tpu
 
 
 # --- backend parity guard ----------------------------------------------------
@@ -213,14 +213,14 @@ def test_qmatmul_backend_parity(mode):
     """interpret-mode Pallas == dense fallback == ref oracle on the same
     inputs, so the dispatch policy can never silently diverge per backend.
     On CPU `interpret=None` takes the dense fallback and `interpret=True`
-    the emulated kernel; on tpu/gpu both run the compiled kernel."""
+    the emulated kernel; on the TPU both run the compiled kernel."""
     K, N = 256, 128
     w = jax.random.normal(jax.random.PRNGKey(2), (K, N)) * 0.02
     qt = QTensor.from_master(w, mode, 0.05)
     x = jax.random.normal(jax.random.PRNGKey(3), (2, K))
 
     y_default = ops.qmatmul(x, qt)                    # backend policy path
-    y_pallas = ops.qmatmul(x, qt, interpret=dispatch.backend() == "cpu")
+    y_pallas = ops.qmatmul(x, qt, interpret=dispatch.backend() != "tpu")
     y_dense = jnp.dot(x, qt.dequantize(jnp.float32))  # the CPU fallback math
     fn = (ref.ternary_matmul_ref if mode == "ternary"
           else ref.binary_matmul_ref)

@@ -16,8 +16,7 @@ from repro.runtime import abstract_mesh, use_mesh
 
 
 def _mesh(multi=False):
-    # abstract_mesh bridges the AbstractMesh constructor change between
-    # jax 0.4.x ((name, size) pairs) and >= 0.5 ((sizes, names))
+    # device-free: the production meshes need 256/512 chips
     if multi:
         return abstract_mesh((2, 16, 16), ("pod", "data", "model"))
     return abstract_mesh((16, 16), ("data", "model"))

@@ -313,6 +313,8 @@ def test_spec_engine_self_draft_accepts_everything():
     _, m = eng.run(reqs, realtime=False)
     assert m["accept_rate"] == 1.0
     assert m["spec_traces"] == 1
+    # an fp draft's step runs no Pallas kernel (a packed one on the TPU: 1)
+    assert m["draft_launches"] == 0
     # 1 admit token + ceil(8 / (k+1)) fully-accepted rounds
     assert m["spec_rounds"] == 2
 
