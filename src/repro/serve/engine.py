@@ -76,6 +76,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.serve import telemetry
 from repro.serve.sampler import sample_slots, spec_accept
 
 Array = jax.Array
@@ -83,9 +84,10 @@ Array = jax.Array
 
 @dataclasses.dataclass
 class Request:
-    """One generation request.  `arrival_s` is the submit time relative to
-    engine start (0 = already queued) — the traffic replay sets it from a
-    Poisson process; latency is measured against it.
+    """One generation request.  `arrival_s` is the arrival time relative
+    to engine start — the traffic replay sets it from a Poisson process;
+    latency is measured against it.  None (a live client's request) means
+    it arrives when `submit()` is called, and is timed from that stamp.
 
     `priority` orders ADMISSION (lower = admitted sooner; ties fall back to
     arrival time, then submit order).  Admission is preemption-free: a
@@ -99,7 +101,7 @@ class Request:
     temperature: float = 0.8
     top_k: int = 0
     seed: int = 0
-    arrival_s: float = 0.0
+    arrival_s: Optional[float] = None
     priority: int = 0
     slo: str = "default"
     rid: Optional[int] = None    # engine numbers submissions when None (the
@@ -219,6 +221,16 @@ def _bucket(n: int, cap: int) -> int:
     while b < n and b < cap:
         b <<= 1
     return min(b, cap)
+
+
+def _arrival(req: Request) -> float:
+    # a request with no arrival time of its own orders as already queued
+    return 0.0 if req.arrival_s is None else req.arrival_s
+
+
+def _origin(req: Request, t_submit: float) -> float:
+    # what its latencies are timed from: the arrival, else the submit stamp
+    return t_submit if req.arrival_s is None else req.arrival_s
 
 
 # ---------------------------------------------------------------------------
@@ -372,14 +384,16 @@ class ServeEngine:
         self._prefill_q: Deque[int] = deque()   # slots mid-prefill, FIFO
         self._rid = 0
         # the admission queue: a priority heap of submitted-but-unadmitted
-        # requests ordered (priority, arrival_s, submit seq).  Cancellation
-        # of a queued request is lazy: the rid goes into `_cancel_pending`
-        # and the entry is dropped when it reaches the heap top.
-        self._heap: List[Tuple[int, float, int, int, Request]] = []
+        # requests ordered (priority, arrival_s, submit seq), each carrying
+        # its submit stamp.  Cancellation of a queued request is lazy: the
+        # rid goes into `_cancel_pending` and the entry is dropped when it
+        # reaches the heap top.
+        self._heap: List[Tuple[int, float, int, int, Request, float]] = []
         self._seq = 0
         self._queued_rids: Set[int] = set()
         self._cancel_pending: Set[int] = set()
         self._t0 = time.perf_counter()
+        self._compiles0 = telemetry.compiles()
 
         self.ticks = 0
         self.tick_traces = 0      # python counters bumped at TRACE time only
@@ -816,8 +830,8 @@ class ServeEngine:
         self._validate(req)
         rid = self._rid if req.rid is None else req.rid
         self._rid = max(self._rid, rid) + 1
-        heapq.heappush(self._heap,
-                       (req.priority, req.arrival_s, self._seq, rid, req))
+        heapq.heappush(self._heap, (req.priority, _arrival(req), self._seq,
+                                    rid, req, self._now()))
         self._seq += 1
         self._queued_rids.add(rid)
         return rid
@@ -848,12 +862,14 @@ class ServeEngine:
             # lazy heap deletion: the entry is skipped when it surfaces
             self._queued_rids.discard(rid)
             self._cancel_pending.add(rid)
-            req = next(r for (_, _, _, hr, r) in self._heap if hr == rid)
+            req, t_sub = next((r, t) for (_, _, _, hr, r, t) in self._heap
+                              if hr == rid)
             now = self._now()
             return Completion(
                 rid=rid, tokens=[],
                 prompt_len=int(np.asarray(req.prompt).size),
-                finished="cancelled", slot=-1, t_submit=req.arrival_s,
+                finished="cancelled", slot=-1,
+                t_submit=_origin(req, t_sub),
                 t_admit=now, t_first=now, t_done=now, slo=req.slo)
         return None
 
@@ -873,18 +889,30 @@ class ServeEngine:
         first token when a prompt's last chunk lands; completions are the
         requests that finished this iteration.  `run()` is a loop over
         this; the front door calls it from an event loop, interleaving
-        `submit`/`cancel` between iterations."""
-        now = self._now()
+        `submit`/`cancel` between iterations.
+
+        While a JAX profiler session captures, the iteration records its
+        spans (serve/telemetry.py); otherwise it records nothing."""
+        if telemetry.active():
+            with telemetry.span("serve.step"):
+                return self._step(True)
+        return self._step(False)
+
+    def _step(self, rec: bool):
+        sp = telemetry.span if rec else telemetry.no_span
         while self._heap:
             slot = self._free_slot()
             if slot is None:
                 break
-            _, _, _, rid, req = heapq.heappop(self._heap)
+            _, _, _, rid, req, t_sub = heapq.heappop(self._heap)
             if rid in self._cancel_pending:
                 self._cancel_pending.discard(rid)
                 continue
             self._queued_rids.discard(rid)
-            self._admit(req, rid, slot, self._now())
+            now = self._now()
+            if rec:
+                telemetry.queue_wait(now - t_sub)
+            self._admit(req, rid, slot, now, t_sub)
 
         retired = np.zeros(self.n_slots, bool)
         events: List[Tuple[int, List[int]]] = []
@@ -896,7 +924,8 @@ class ServeEngine:
             if self._live_host.any():
                 self._stall_pending[act0.rid] = \
                     self._stall_pending.get(act0.rid, 0) + 1
-            sampled, comp, slot = self._prefill_step()
+            with sp("serve.prefill"):
+                sampled, comp, slot = self._prefill_step()
             if sampled:
                 self._gen_tokens += sampled
                 events.append((act0.rid, [act0.tokens[-1]]))
@@ -909,16 +938,17 @@ class ServeEngine:
                 self._scrub(retired)
             return events, comps
 
-        if self.spec:
-            (self.pool, self.draft_pool, self._pending, self._keys,
-             spec_out) = self._spec_tick(
-                self._prm, self._dprm, self.pool, self.draft_pool,
-                self._pending, self._live, self._keys, self._temp,
-                self._topk)
-        else:
-            self.pool, self._pending, self._keys = self._tick(
-                self._prm, self.pool, self._pending, self._live, self._keys,
-                self._temp, self._topk)
+        with sp("serve.tick"):
+            if self.spec:
+                (self.pool, self.draft_pool, self._pending, self._keys,
+                 spec_out) = self._spec_tick(
+                    self._prm, self._dprm, self.pool, self.draft_pool,
+                    self._pending, self._live, self._keys, self._temp,
+                    self._topk)
+            else:
+                self.pool, self._pending, self._keys = self._tick(
+                    self._prm, self.pool, self._pending, self._live,
+                    self._keys, self._temp, self._topk)
         self.ticks += 1
         if self._stall_pending:
             self._stall_max = max(self._stall_max,
@@ -937,7 +967,7 @@ class ServeEngine:
             # a spec round emits a VARIABLE number of tokens per slot
             # (accepted prefix + one); truncate at EOS / quota — the
             # overshoot the verify consumed dies with the slot scrub
-            out_host = np.asarray(spec_out)
+            out_host = self._read_ids(spec_out, rec)
             for slot in np.flatnonzero(self._live_host):
                 act = self._active[slot]
                 take = int(out_host[slot, -1])
@@ -962,7 +992,7 @@ class ServeEngine:
                     retired[slot] = True
         else:
             self._gen_tokens += n_live
-            toks = np.asarray(self._pending)
+            toks = self._read_ids(self._pending, rec)
             for slot in np.flatnonzero(self._live_host):
                 act = self._active[slot]
                 act.tokens.append(int(toks[slot]))
@@ -982,7 +1012,20 @@ class ServeEngine:
 
     # -- internals ----------------------------------------------------------
 
-    def _admit(self, req: Request, rid: int, slot: int, now: float) -> None:
+    @staticmethod
+    def _read_ids(ids, rec: bool) -> np.ndarray:
+        """The tick's sampled ids on the host.  While recording, the read
+        is split into the wait for the tick and the copy of the ready ids;
+        otherwise it is the one blocking `np.asarray`."""
+        if not rec:
+            return np.asarray(ids)
+        with telemetry.span("serve.ids_wait"):
+            jax.block_until_ready(ids)
+        with telemetry.span("serve.ids_copy"):
+            return np.asarray(ids)
+
+    def _admit(self, req: Request, rid: int, slot: int, now: float,
+               t_submit: float) -> None:
         """Pure bookkeeping plus (with a prefix cache) at most one splice:
         look up the longest cached prompt prefix, copy its carried state
         into the slot row, split the REMAINING prompt into bucket-padded
@@ -1005,7 +1048,7 @@ class ServeEngine:
             off += n
             chunks.append((c, n))
         self._active[slot] = _Active(
-            req=req, rid=rid, tokens=[], t_submit=req.arrival_s,
+            req=req, rid=rid, tokens=[], t_submit=_origin(req, t_submit),
             t_admit=now, t_first=None, chunks=chunks, prompt=prompt,
             off=cached, cached=cached)
         self._prefill_q.append(slot)
@@ -1050,13 +1093,15 @@ class ServeEngine:
         slot = self._prefill_q[0]
         act = self._active[slot]
         chunk, n = act.chunks.popleft()
+        # the batch axis is added on the host: an eager `[None]` on the
+        # device would build one program per chunk shape that warm() missed
         if self.spec:
             logits, self.pool, self.draft_pool = self._spec_prefill_slot(
                 self._prm, self._dprm, self.pool, self.draft_pool,
-                jnp.asarray(chunk)[None], jnp.int32(n), jnp.int32(slot))
+                jnp.asarray(chunk[None]), jnp.int32(n), jnp.int32(slot))
         else:
             logits, self.pool = self._prefill_slot(
-                self._prm, self.pool, jnp.asarray(chunk)[None], jnp.int32(n),
+                self._prm, self.pool, jnp.asarray(chunk[None]), jnp.int32(n),
                 jnp.int32(slot))
         act.off += n
         if (self.prefix_cache is not None and n == self.prefill_chunk
@@ -1132,6 +1177,9 @@ class ServeEngine:
             "tick_launches": self.tick_launches,
             "prefill_traces": self.prefill_traces,
             "max_decode_stall_ticks": self._stall_max,
+            # programs JAX built since the engine was made (compiles and
+            # persistent-cache loads, process-wide): flat once warm
+            "compiles": telemetry.compiles() - self._compiles0,
         }
         # per-shard occupancy (queue depth is global — admission is one
         # priority heap feeding every shard): a router in front of a mesh
@@ -1196,7 +1244,7 @@ class ServeEngine:
         harness proves about run() holds for the streaming path too."""
         for r in requests:  # fail fast, BEFORE any request is in flight:
             self._validate(r)  # a bad request must not poison the workload
-        arrivals = deque(sorted(requests, key=lambda r: r.arrival_s))
+        arrivals = deque(sorted(requests, key=_arrival))
         completions: List[Completion] = []
         self._t0 = time.perf_counter()
         gen0 = self._gen_tokens
@@ -1209,14 +1257,14 @@ class ServeEngine:
                or self._live_host.any()):
             now = self._now()
             # release traffic that has arrived into the admission heap
-            while arrivals and (not realtime or arrivals[0].arrival_s <= now):
+            while arrivals and (not realtime or _arrival(arrivals[0]) <= now):
                 self.submit(arrivals.popleft())
             _, comps = self.step()
             completions.extend(comps)
             if (not self._prefill_q and not self._live_host.any()
                     and not self._heap and arrivals and realtime):
                 # idle until the next arrival
-                wait = arrivals[0].arrival_s - self._now()
+                wait = _arrival(arrivals[0]) - self._now()
                 if wait > 0:
                     time.sleep(min(wait, 0.05))
 
