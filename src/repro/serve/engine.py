@@ -19,8 +19,8 @@ prefill→decode loop into mixed-length traffic serving:
     prefilling slots are dead for the tick, and the runtimes freeze dead
     rows' state bit-for-bit (a dead row may be mid-prefill);
   * a request's FIRST token is sampled when its last chunk lands —
-    `Completion.t_first` is the real first-token time, not the admission
-    time — then the slot turns live and decodes;
+    `Completion.t_first` is when that token reaches the host, not the
+    admission time — and the slot turns live and decodes;
   * slots RETIRE on EOS or per-request max-tokens and are immediately
     reusable; freed slots are scrubbed in one batched shape-aware reset
     (recurrent leaves and positions to zero, attention KV masked in place)
@@ -28,10 +28,20 @@ prefill→decode loop into mixed-length traffic serving:
 
 The scheduler is driven through a RESUMABLE step API (DESIGN.md §10):
 `submit()` enqueues a request (priority/SLO-ordered admission), `step()`
-runs ONE scheduler iteration and returns the tokens it sampled plus any
+runs ONE scheduler iteration and returns the tokens it read plus any
 completions, and `cancel(rid)` retires an in-flight request mid-stream —
 mid-prefill or mid-decode — through the same batched scrub retirement
 uses, so a hung-up client leaks nothing into the slot's next occupant.
+
+The plain path reads the device one iteration late (DESIGN.md §10): an
+iteration dispatches tick k+1 before it reads tick k's sampled ids (and a
+new first token is read the iteration after it samples), so the host's
+bookkeeping overlaps the device's next tick instead of waiting for it.
+Tick k+1 does not need tick k's ids on the host — they are its device-side
+input already.  A request stopped by EOS has been fed to the tick in
+flight; that token is dropped when read.  `pool` and `cancel()` settle the
+outstanding read first, so outside readers see state that matches the
+tokens recorded.
 `run()` is a thin loop over submit/step (byte-identical to the pre-step-API
 batch driver); the asyncio front door (serve/frontdoor.py) drives step()
 from an event loop while requests arrive and die asynchronously.
@@ -117,8 +127,8 @@ class Completion:
     slot: int
     t_submit: float              # engine-relative seconds
     t_admit: float               # slot allocated; prefill starts after this
-    t_first: float               # the FIRST token was actually sampled (the
-                                 # prompt's last chunk landed) — real TTFT
+    t_first: float               # the FIRST token reached the host (after
+                                 # the prompt's last chunk) — real TTFT
     t_done: float
     cached_tokens: int = 0       # prompt tokens a prefix-cache splice skipped
     slo: str = "default"
@@ -143,11 +153,22 @@ class _Active:
     tokens: List[int]
     t_submit: float
     t_admit: float
-    t_first: Optional[float]            # stamped when the first token samples
+    t_first: Optional[float]            # stamped when the first token is read
     chunks: Deque[Tuple[np.ndarray, int]]  # remaining (padded chunk, n real)
     prompt: np.ndarray  # the full prompt ids (prefix-cache keys slice it)
     off: int            # real prompt tokens consumed so far (incl. spliced)
     cached: int         # tokens a prefix-cache splice made unnecessary
+    sampled: int = 0    # tokens dispatched for it, read or not (plain path)
+
+
+@dataclasses.dataclass
+class _Unread:
+    """Sampled ids dispatched and not yet read (the plain path): a tick's
+    (B,) ids, or an admission's first token (a scalar), with the slots and
+    requests they were sampled for, as they stood at dispatch."""
+    ids: Array
+    feed: List[Tuple[int, _Active]]
+    tick: bool
 
 
 # ---------------------------------------------------------------------------
@@ -350,8 +371,8 @@ class ServeEngine:
                     "a spliced prefix byte-identical to re-prefilling it")
             prefix_cache.bind(self.prefill_chunk)
 
-        self.pool = rt.init_state(self.n_slots, self.max_context,
-                                  per_slot=True)
+        self._pool = rt.init_state(self.n_slots, self.max_context,
+                                   per_slot=True)
         # batch-1 template: fixes the slot axis of every pool leaf for the
         # gather/reset surgery (shapes only — no arrays are materialized)
         self._ref = jax.eval_shape(
@@ -379,8 +400,17 @@ class ServeEngine:
         self._keys = jnp.zeros((B, 2), jnp.uint32)   # per-slot PRNG chain
         self._temp = jnp.ones((B,), jnp.float32)
         self._topk = jnp.zeros((B,), jnp.int32)
-        self._live_host = np.zeros(B, bool)
+        self._live_host = np.zeros(B, bool)   # fed to the next tick
         self._active: List[Optional[_Active]] = [None] * B
+        # deferred reads (plain path), oldest first, and what reading them
+        # produced for the next step() to return: events merge per slot
+        # occupant, so a step returns one event per request
+        self._unread: Deque[_Unread] = deque()
+        self._out_events: List[Tuple[int, List[int]]] = []
+        self._out_comps: List[Completion] = []
+        self._out_slot: Dict[int, Tuple[_Active, List[int]]] = {}
+        self._discarded = 0       # tokens sampled past a request's end
+        self._settles = 0         # reads forced by `pool` / `cancel()`
         self._prefill_q: Deque[int] = deque()   # slots mid-prefill, FIFO
         self._rid = 0
         # the admission queue: a priority heap of submitted-but-unadmitted
@@ -457,10 +487,10 @@ class ServeEngine:
             self._rep = NamedSharding(mesh, P())
             self._vec_sh, self._row_sh = batch_shardings(
                 (self._pending, self._keys), mesh)
-            self._pool_sh = serve_pool_shardings(self.pool, self._ref, mesh)
+            self._pool_sh = serve_pool_shardings(self._pool, self._ref, mesh)
             self._sub_sh = jax.tree.map(lambda _: self._rep, self._pool_sh)
             self._prm_sh = rt.serve_prm_shardings(mesh)
-            self.pool = jax.device_put(self.pool, self._pool_sh)
+            self._pool = jax.device_put(self._pool, self._pool_sh)
             self._prm = jax.device_put(self._prm, self._prm_sh)
             self._pending = jax.device_put(self._pending, self._vec_sh)
             self._live = jax.device_put(self._live, self._vec_sh)
@@ -504,17 +534,18 @@ class ServeEngine:
             return pool, nxt, keys
 
         # the pool is dead the moment the tick/prefill/reset returns its
-        # successor, so donate it (and the pending/key chains) — without
-        # donation every tick would COPY all B KV caches.  Every backend
-        # donates (the CPU too), so the tests run the chip's buffer
-        # discipline.  The prm tree is NEVER donated: the same arrays are
-        # passed every call.
+        # successor, so donate it (and the key chain) — without donation
+        # every tick would COPY all B KV caches.  Every backend donates
+        # (the CPU too), so the tests run the chip's buffer discipline.
+        # The prm tree is NEVER donated: the same arrays are passed every
+        # call.  Nor is `pending`: the host reads a tick's sampled ids one
+        # iteration late, after the next tick has taken them as input.
         self._tick = _mjit(
             tick,
             in_sh=(self._prm_sh, self._pool_sh, self._vec_sh, self._vec_sh,
                    self._row_sh, self._vec_sh, self._vec_sh),
             out_sh=(self._pool_sh, self._vec_sh, self._row_sh),
-            donate=(1, 2, 4))
+            donate=(1, 4))
 
         def admit_commit(logits, key, t, k, pending, keys, temp, topk, live,
                          slot):
@@ -714,6 +745,16 @@ class ServeEngine:
                 dsplice, in_sh=(self._dpool_sh, self._dsub_sh, R),
                 out_sh=self._dpool_sh, donate=(0,))
 
+    # -- the settled view ---------------------------------------------------
+
+    @property
+    def pool(self):
+        """The slot pool's state, settled: the outstanding read is taken
+        first (`_settle`), so every active slot's row has consumed exactly
+        its prompt and the tokens recorded for it but the last."""
+        self._settle()
+        return self._pool
+
     # -- clock --------------------------------------------------------------
 
     def _now(self) -> float:
@@ -779,13 +820,13 @@ class ServeEngine:
         warmed serving path."""
         for Lb in self.declared_buckets(prompt_lens):
             if self.spec:
-                _, self.pool, self.draft_pool = self._spec_prefill_slot(
-                    self._prm, self._dprm, self.pool, self.draft_pool,
+                _, self._pool, self.draft_pool = self._spec_prefill_slot(
+                    self._prm, self._dprm, self._pool, self.draft_pool,
                     jnp.zeros((1, Lb), jnp.int32), jnp.int32(Lb),
                     jnp.int32(0))
             else:
-                _, self.pool = self._prefill_slot(
-                    self._prm, self.pool, jnp.zeros((1, Lb), jnp.int32),
+                _, self._pool = self._prefill_slot(
+                    self._prm, self._pool, jnp.zeros((1, Lb), jnp.int32),
                     jnp.int32(Lb), jnp.int32(0))
         # the warm prefills ran junk through slot 0 — scrub it so the pool
         # is indistinguishable from fresh before any real admission.  (They
@@ -844,8 +885,11 @@ class ServeEngine:
         so a hung-up client cannot leak state into the next occupant (and
         no new jit traces occur: the scrub is already compiled).
 
-        Returns a Completion with finished='cancelled' carrying the tokens
-        streamed so far, or None if the rid is unknown / already done."""
+        The outstanding read is settled first: the Completion (finished=
+        'cancelled') carries every token sampled for the request, and the
+        ones no step() has returned yet reach the caller as events of the
+        next step().  None if the rid is unknown or already done."""
+        self._settle()
         for slot, act in enumerate(self._active):
             if act is not None and act.rid == rid:
                 now = self._now()
@@ -875,21 +919,26 @@ class ServeEngine:
 
     def has_work(self) -> bool:
         """True while a `step()` could make progress: queued, prefilling or
-        decoding requests exist."""
-        return bool(self._heap or self._prefill_q or self._live_host.any())
+        decoding requests exist, a read is outstanding, or a settle left
+        tokens for step() to return."""
+        return bool(self._heap or self._prefill_q or self._live_host.any()
+                    or self._unread or self._out_events or self._out_comps)
 
     def step(self) -> Tuple[List[Tuple[int, List[int]]], List[Completion]]:
         """ONE scheduler iteration: admit queued requests into free slots
-        (priority order), run at most one prefill chunk, run one batched
-        decode tick (or draft-verify-accept round), retire and scrub.
+        (priority order), run at most one prefill chunk, dispatch one
+        batched decode tick (or draft-verify-accept round), read, retire
+        and scrub.
 
         Returns (token_events, completions): token_events is a list of
-        (rid, [token ids sampled this iteration]) in stream order — one id
-        per live slot per plain tick, up to spec_k+1 per spec round, the
-        first token when a prompt's last chunk lands; completions are the
-        requests that finished this iteration.  `run()` is a loop over
-        this; the front door calls it from an event loop, interleaving
-        `submit`/`cancel` between iterations.
+        (rid, [token ids read this iteration]) in stream order, one entry
+        per request; completions are the requests that finished.  The
+        plain path reads one iteration late: the ids of the tick (and the
+        first tokens) dispatched by the previous iteration, plus whatever
+        a settle (`pool`, `cancel`) read since.  A speculative round is
+        read in its own iteration, up to spec_k+1 ids per slot.  `run()`
+        is a loop over this; the front door calls it from an event loop,
+        interleaving `submit`/`cancel` between iterations.
 
         While a JAX profiler session captures, the iteration records its
         spans (serve/telemetry.py); otherwise it records nothing."""
@@ -913,6 +962,8 @@ class ServeEngine:
             if rec:
                 telemetry.queue_wait(now - t_sub)
             self._admit(req, rid, slot, now, t_sub)
+        if not self.spec:
+            return self._step_plain(rec, sp)
 
         retired = np.zeros(self.n_slots, bool)
         events: List[Tuple[int, List[int]]] = []
@@ -920,12 +971,7 @@ class ServeEngine:
 
         # at most ONE prefill chunk per iteration, before the tick
         if self._prefill_q:
-            act0 = self._active[self._prefill_q[0]]
-            if self._live_host.any():
-                self._stall_pending[act0.rid] = \
-                    self._stall_pending.get(act0.rid, 0) + 1
-            with sp("serve.prefill"):
-                sampled, comp, slot = self._prefill_step()
+            act0, (sampled, comp, slot) = self._run_chunk(sp)
             if sampled:
                 self._gen_tokens += sampled
                 events.append((act0.rid, [act0.tokens[-1]]))
@@ -939,16 +985,76 @@ class ServeEngine:
             return events, comps
 
         with sp("serve.tick"):
-            if self.spec:
-                (self.pool, self.draft_pool, self._pending, self._keys,
-                 spec_out) = self._spec_tick(
-                    self._prm, self._dprm, self.pool, self.draft_pool,
-                    self._pending, self._live, self._keys, self._temp,
-                    self._topk)
-            else:
-                self.pool, self._pending, self._keys = self._tick(
-                    self._prm, self.pool, self._pending, self._live,
-                    self._keys, self._temp, self._topk)
+            (self._pool, self.draft_pool, self._pending, self._keys,
+             spec_out) = self._spec_tick(
+                self._prm, self._dprm, self._pool, self.draft_pool,
+                self._pending, self._live, self._keys, self._temp,
+                self._topk)
+        self._count_tick()
+
+        # one small device->host transfer per round: the scheduler needs
+        # the sampled ids to detect EOS / quota and to free slots.  A spec
+        # round emits a VARIABLE number of tokens per slot (accepted
+        # prefix + one); truncate at EOS / quota — the overshoot the
+        # verify consumed dies with the slot scrub
+        now = self._now()
+        out_host = self._read_ids(spec_out, rec)
+        for slot in np.flatnonzero(self._live_host):
+            act = self._active[slot]
+            take = int(out_host[slot, -1])
+            self._drafted += self.spec_k
+            self._accepted += max(take - 1, 0)
+            emitted: List[int] = []
+            done = False
+            for j in range(take):
+                tok = int(out_host[slot, j])
+                act.tokens.append(tok)
+                emitted.append(tok)
+                self._gen_tokens += 1
+                hit_eos = (self.eos_id is not None
+                           and act.tokens[-1] == self.eos_id)
+                if hit_eos or len(act.tokens) >= act.req.max_tokens:
+                    done = True
+                    break
+            events.append((act.rid, emitted))
+            if done:
+                comps.append(self._completion(act, int(slot), now))
+                self._retire(int(slot))
+                retired[slot] = True
+        if retired.any():
+            # scrub the freed slots in ONE batched shape-aware reset:
+            # the next occupant prefills IN the slot, so it must read
+            # exactly like a fresh one
+            self._scrub(retired)
+        return events, comps
+
+    def _step_plain(self, rec: bool, sp):
+        """The plain iteration, with deferred reads: at most one prefill
+        chunk, then tick k+1, then the reads the earlier iterations left
+        (tick k's ids, first tokens), then the scrub of what retired."""
+        old = len(self._unread)
+        retired = np.zeros(self.n_slots, bool)
+        if self._prefill_q:
+            self._run_chunk(sp)
+        if self._live_host.any():
+            self._dispatch_tick(rec, sp, retired)
+        self._collect(old, rec, retired)
+        if retired.any():
+            self._scrub(retired)
+        events, comps = self._out_events, self._out_comps
+        self._out_events, self._out_comps, self._out_slot = [], [], {}
+        return events, comps
+
+    def _run_chunk(self, sp):
+        """At most ONE prefill chunk per iteration, before the tick."""
+        act0 = self._active[self._prefill_q[0]]
+        if self._live_host.any():
+            self._stall_pending[act0.rid] = \
+                self._stall_pending.get(act0.rid, 0) + 1
+        with sp("serve.prefill"):
+            return act0, self._prefill_step()
+
+    def _count_tick(self) -> None:
         self.ticks += 1
         if self._stall_pending:
             self._stall_max = max(self._stall_max,
@@ -960,55 +1066,73 @@ class ServeEngine:
         # chunked prefill, when admission held the slot synchronously
         self._occupancy_sum += (n_live + len(self._prefill_q)) / self.n_slots
 
-        # one small device->host transfer per tick: the scheduler needs
-        # the sampled ids to detect EOS / quota and to free slots
-        now = self._now()
-        if self.spec:
-            # a spec round emits a VARIABLE number of tokens per slot
-            # (accepted prefix + one); truncate at EOS / quota — the
-            # overshoot the verify consumed dies with the slot scrub
-            out_host = self._read_ids(spec_out, rec)
-            for slot in np.flatnonzero(self._live_host):
-                act = self._active[slot]
-                take = int(out_host[slot, -1])
-                self._drafted += self.spec_k
-                self._accepted += max(take - 1, 0)
-                emitted: List[int] = []
-                done = False
-                for j in range(take):
-                    tok = int(out_host[slot, j])
-                    act.tokens.append(tok)
-                    emitted.append(tok)
-                    self._gen_tokens += 1
-                    hit_eos = (self.eos_id is not None
-                               and act.tokens[-1] == self.eos_id)
-                    if hit_eos or len(act.tokens) >= act.req.max_tokens:
-                        done = True
-                        break
-                events.append((act.rid, emitted))
-                if done:
-                    comps.append(self._completion(act, int(slot), now))
-                    self._retire(int(slot))
-                    retired[slot] = True
-        else:
-            self._gen_tokens += n_live
-            toks = self._read_ids(self._pending, rec)
-            for slot in np.flatnonzero(self._live_host):
-                act = self._active[slot]
-                act.tokens.append(int(toks[slot]))
-                events.append((act.rid, [int(toks[slot])]))
-                hit_eos = (self.eos_id is not None
-                           and act.tokens[-1] == self.eos_id)
-                if hit_eos or len(act.tokens) >= act.req.max_tokens:
-                    comps.append(self._completion(act, int(slot), now))
-                    self._retire(int(slot))
-                    retired[slot] = True
+    def _dispatch_tick(self, rec: bool, sp, retired: np.ndarray) -> None:
+        """Dispatch the plain tick and queue the read of its ids.  A slot
+        whose quota this tick fills leaves the tick now (the host counts
+        what it has dispatched), and its row is scrubbed after the tick."""
+        feed = [(int(s), self._active[s])
+                for s in np.flatnonzero(self._live_host)]
+        if rec and any(u.tick for u in self._unread):
+            telemetry.count("serve.ticks_ahead")
+        with sp("serve.tick"):
+            self._pool, self._pending, self._keys = self._tick(
+                self._prm, self._pool, self._pending, self._live,
+                self._keys, self._temp, self._topk)
+        self._pending.copy_to_host_async()
+        self._unread.append(_Unread(self._pending, feed, tick=True))
+        self._count_tick()
+        for slot, act in feed:
+            act.sampled += 1
+            if act.sampled >= act.req.max_tokens:
+                self._retire(slot)
+                retired[slot] = True
+
+    def _collect(self, n: int, rec: bool, retired: np.ndarray) -> None:
+        """Read the `n` oldest outstanding reads, in dispatch order, and
+        record their tokens: events and completions for the next return
+        of step().  A token sampled after its request ended (EOS, or a
+        quota of one filled at admission) is dropped; a request that EOS
+        stops while it still holds its slot retires into `retired`."""
+        for _ in range(n):
+            u = self._unread.popleft()
+            ids = self._read_ids(u.ids, rec) if u.tick else np.asarray(u.ids)
+            now = self._now()
+            for slot, act in u.feed:
+                if self._finished(act):
+                    self._discarded += 1
+                    continue
+                tok = int(ids[slot] if u.tick else ids)
+                act.tokens.append(tok)
+                if act.t_first is None:
+                    act.t_first = now
+                self._gen_tokens += 1
+                out = self._out_slot.get(slot)
+                if out is None or out[0] is not act:
+                    out = self._out_slot[slot] = (act, [])
+                    self._out_events.append((act.rid, out[1]))
+                out[1].append(tok)
+                if self._finished(act):
+                    self._out_comps.append(self._completion(act, slot, now))
+                    if self._active[slot] is act:
+                        self._retire(slot)
+                        retired[slot] = True
+
+    def _finished(self, act: _Active) -> bool:
+        return bool(act.tokens) and (
+            len(act.tokens) >= act.req.max_tokens
+            or (self.eos_id is not None and act.tokens[-1] == self.eos_id))
+
+    def _settle(self) -> None:
+        """Take every outstanding read now (`pool`, `cancel`): record and
+        retire as step() would, and scrub what retired.  What it read is
+        returned by the next step()."""
+        if not self._unread:
+            return
+        self._settles += 1
+        retired = np.zeros(self.n_slots, bool)
+        self._collect(len(self._unread), False, retired)
         if retired.any():
-            # scrub the freed slots in ONE batched shape-aware reset:
-            # the next occupant prefills IN the slot, so it must read
-            # exactly like a fresh one
             self._scrub(retired)
-        return events, comps
 
     # -- internals ----------------------------------------------------------
 
@@ -1062,7 +1186,7 @@ class ServeEngine:
         from repro.serve.prefixcache import widen_state
 
         sub = widen_state(entry.state, self._ref)
-        self.pool = self._splice(self.pool, sub, jnp.int32(slot))
+        self._pool = self._splice(self._pool, sub, jnp.int32(slot))
         if self.spec:
             dsub = widen_state(entry.draft_state, self._dref)
             self.draft_pool = self._dsplice(self.draft_pool, dsub,
@@ -1077,7 +1201,7 @@ class ServeEngine:
         prefix = act.prompt[:act.off]
         if self.prefix_cache.contains(prefix):
             return
-        sub = narrow_state(self._gather(self.pool, jnp.int32(slot)), act.off)
+        sub = narrow_state(self._gather(self._pool, jnp.int32(slot)), act.off)
         dsub = None
         if self.spec:
             dsub = narrow_state(
@@ -1086,22 +1210,25 @@ class ServeEngine:
 
     def _prefill_step(self):
         """Run ONE chunk of the oldest prefilling slot.  When the last
-        chunk lands, sample the request's first token (stamping the real
-        `t_first`) and either turn the slot live or — max_tokens == 1 /
-        EOS on the first token — complete it immediately.  Returns
-        (n_sampled, completion, retired_slot)."""
+        chunk lands, sample the request's first token and turn the slot
+        live.  The plain path queues the token's read for the next
+        iteration (`t_first` is stamped when it is read) and returns
+        (0, None, None).  The speculative path reads it at once, stamps
+        `t_first`, and — max_tokens == 1 / EOS on the first token —
+        completes the request here.  Returns (n_sampled, completion,
+        retired_slot)."""
         slot = self._prefill_q[0]
         act = self._active[slot]
         chunk, n = act.chunks.popleft()
         # the batch axis is added on the host: an eager `[None]` on the
         # device would build one program per chunk shape that warm() missed
         if self.spec:
-            logits, self.pool, self.draft_pool = self._spec_prefill_slot(
-                self._prm, self._dprm, self.pool, self.draft_pool,
+            logits, self._pool, self.draft_pool = self._spec_prefill_slot(
+                self._prm, self._dprm, self._pool, self.draft_pool,
                 jnp.asarray(chunk[None]), jnp.int32(n), jnp.int32(slot))
         else:
-            logits, self.pool = self._prefill_slot(
-                self._prm, self.pool, jnp.asarray(chunk[None]), jnp.int32(n),
+            logits, self._pool = self._prefill_slot(
+                self._prm, self._pool, jnp.asarray(chunk[None]), jnp.int32(n),
                 jnp.int32(slot))
         act.off += n
         if (self.prefix_cache is not None and n == self.prefill_chunk
@@ -1117,6 +1244,14 @@ class ServeEngine:
             jnp.float32(req.temperature), jnp.int32(req.top_k),
             self._pending, self._keys, self._temp, self._topk, self._live,
             jnp.int32(slot))
+        if not self.spec:
+            # the device set the slot live in `admit_commit`: the host
+            # follows at once, and reads the token with the next reads
+            tok0.copy_to_host_async()
+            act.sampled = 1
+            self._unread.append(_Unread(tok0, [(slot, act)], tick=False))
+            self._live_host[slot] = True
+            return 0, None, None
         act.tokens.append(int(tok0))
         act.t_first = self._now()
         if (req.max_tokens <= 1
@@ -1157,10 +1292,10 @@ class ServeEngine:
         mode (the next occupant prefills into BOTH pools)."""
         m = jnp.asarray(retired)
         if self.spec:
-            self.pool, self.draft_pool, self._live = self._spec_reset(
-                self.pool, self.draft_pool, self._live, m)
+            self._pool, self.draft_pool, self._live = self._spec_reset(
+                self._pool, self.draft_pool, self._live, m)
         else:
-            self.pool, self._live = self._reset(self.pool, self._live, m)
+            self._pool, self._live = self._reset(self._pool, self._live, m)
 
     # -- stats (the front door's /v1/stats) ---------------------------------
 
@@ -1177,6 +1312,10 @@ class ServeEngine:
             "tick_launches": self.tick_launches,
             "prefill_traces": self.prefill_traces,
             "max_decode_stall_ticks": self._stall_max,
+            # deferred reads (plain path): tokens sampled past a request's
+            # end and dropped, and reads forced early by `pool`/`cancel`
+            "discarded_tokens": self._discarded,
+            "settles": self._settles,
             # programs JAX built since the engine was made (compiles and
             # persistent-cache loads, process-wide): flat once warm
             "compiles": telemetry.compiles() - self._compiles0,
@@ -1217,12 +1356,12 @@ class ServeEngine:
         try:
             if self.spec:
                 low = self._spec_tick.lower(
-                    self._prm, self._dprm, self.pool, self.draft_pool,
+                    self._prm, self._dprm, self._pool, self.draft_pool,
                     self._pending, self._live, self._keys, self._temp,
                     self._topk)
             else:
                 low = self._tick.lower(
-                    self._prm, self.pool, self._pending, self._live,
+                    self._prm, self._pool, self._pending, self._live,
                     self._keys, self._temp, self._topk)
             return low.compile().as_text()
         finally:
@@ -1253,16 +1392,14 @@ class ServeEngine:
         self._stall_pending.clear()
         self._stall_max = 0
 
-        while (arrivals or self._heap or self._prefill_q
-               or self._live_host.any()):
+        while arrivals or self.has_work():
             now = self._now()
             # release traffic that has arrived into the admission heap
             while arrivals and (not realtime or _arrival(arrivals[0]) <= now):
                 self.submit(arrivals.popleft())
             _, comps = self.step()
             completions.extend(comps)
-            if (not self._prefill_q and not self._live_host.any()
-                    and not self._heap and arrivals and realtime):
+            if arrivals and realtime and not self.has_work():
                 # idle until the next arrival
                 wait = _arrival(arrivals[0]) - self._now()
                 if wait > 0:

@@ -10,9 +10,10 @@ with nothing capturing it opens no span and creates no annotation.
 Each span is a `jax.profiler.TraceAnnotation`, so it lands in the profile
 on the device trace's clock, plus an in-memory record kept as totals per
 name: the count, the summed duration and the summed duration of the spans
-opened directly inside it (its self time is the difference).  Queue waits
-(submit to admit) are kept per admission, the last `MAX_WAITS` of them.
-The record is cleared when capture turns on, so it holds one session.
+opened directly inside it (its self time is the difference).  Counters
+(`count`) are in-memory event counts per name.  Queue waits (submit to
+admit) are kept per admission, the last `MAX_WAITS` of them.  The record
+is cleared when capture turns on, so it holds one session.
 
 `snapshot()` is the read side: plain dicts and lists, read by the
 benchmark's `bench/metrics/*.py`.
@@ -43,6 +44,7 @@ class Recorder:
     def clear(self):
         # name -> [count, total_s, child_s]
         self.spans: Dict[str, List[float]] = {}
+        self.counts: Dict[str, int] = {}
         self.queue: Deque[float] = collections.deque(maxlen=MAX_WAITS)
         self.stack: List[str] = []
 
@@ -95,6 +97,11 @@ def no_span(name: str):
     return _OFF
 
 
+def count(name: str) -> None:
+    """One event of counter `name` (call only after `active()` said True)."""
+    REC.counts[name] = REC.counts.get(name, 0) + 1
+
+
 def queue_wait(wait_s: float) -> None:
     """One admission's wait from submit to admit (while recording)."""
     REC.queue.append(float(wait_s))
@@ -107,9 +114,11 @@ def compiles() -> int:
 
 def snapshot() -> dict:
     """The current session's record: `spans` name -> {"n", "total_s",
-    "self_s"}; `queue_s` the admissions' waits, seconds."""
+    "self_s"}; `counts` name -> events; `queue_s` the admissions' waits,
+    seconds."""
     return {
         "spans": {k: {"n": int(n), "total_s": s, "self_s": s - c}
                   for k, (n, s, c) in REC.spans.items()},
+        "counts": dict(REC.counts),
         "queue_s": list(REC.queue),
     }

@@ -1,7 +1,6 @@
 """The engine's host spans and counters (serve/telemetry.py): recorded only
 while a JAX profiler session captures, counted the way the engine's own
-counters count, and never a change to the tokens.  The only test file that
-starts a profiler session."""
+counters count, and never a change to the tokens."""
 import dataclasses
 import time
 
@@ -116,6 +115,23 @@ def test_session_counts_match_the_engine(engines, mode, tmp_path):
     assert step["self_s"] < step["total_s"]
     # a warmed engine builds nothing while it is traced
     assert eng.stats()["compiles"] == compiles0
+
+
+@pytest.mark.parametrize("mode", MODES)
+def test_ticks_ahead_counts_plain_ticks_dispatched_before_a_read(
+        engines, mode, tmp_path):
+    """`serve.ticks_ahead` counts ticks dispatched while an earlier tick's
+    ids were unread: on the plain path, every tick of a stretch of steady
+    decoding but its first; a speculative round is read in its own step."""
+    eng = engines[mode]
+    _traced(eng, _requests([3, 5]), tmp_path)
+    snap = telemetry.snapshot()
+    ahead = snap["counts"].get("serve.ticks_ahead", 0)
+    ticks = snap["spans"]["serve.tick"]["n"]
+    if mode == "spec":
+        assert ahead == 0
+    else:
+        assert 0 < ahead < ticks
 
 
 def test_a_new_session_clears_the_last(engines, tmp_path):

@@ -198,11 +198,15 @@ def test_disconnect_then_readmit_same_slot():
             got_a.extend(toks)
     assert eng._live_host[0] and len(got_a) >= 2
     comp_a = eng.cancel(rid_a)
-    assert comp_a.finished == "cancelled" and comp_a.tokens == got_a
-    assert comp_a.tokens == _expected("lstm-fp", a)[:len(got_a)], \
+    # the cancel settles the tick in flight: its token is in the
+    # completion, and reaches the stream as an event of the next step()
+    assert comp_a.finished == "cancelled"
+    assert comp_a.tokens == got_a + [comp_a.tokens[-1]]
+    assert comp_a.tokens == _expected("lstm-fp", a)[:len(comp_a.tokens)], \
         "the partial stream up to the hangup is still oracle-exact"
     rid_b = eng.submit(dataclasses.replace(b))
     streams, comps = _drain(eng)
+    assert got_a + streams[rid_a] == comp_a.tokens
     assert comps[0].rid == rid_b and comps[0].slot == 0  # SAME slot
     assert streams[rid_b] == _expected("lstm-fp", b)
     assert eng.tick_traces == 1

@@ -454,8 +454,10 @@ def test_prompt_exactly_fills_context():
 
 
 def test_eos_on_admission_token():
-    """EOS hit by the very first sampled token: the request completes at
-    prefill time without ever occupying a decode tick."""
+    """EOS hit by the very first sampled token: the request completes when
+    that token is read, one iteration after its last chunk.  The slot went
+    live with the commit, so the two ticks dispatched before the read were
+    fed it; both of their tokens are dropped, and the slot is free."""
     cfg, rt = _rnn_runtime("lstm")
     probe, _ = drive_session(rt, jnp.zeros((1, 5), jnp.int32), cfg.vocab,
                              gen=1, temperature=0.8, top_k=0, seed=11)
@@ -467,7 +469,8 @@ def test_eos_on_admission_token():
                                 temperature=0.8, top_k=0, seed=11)],
                        realtime=False)
     assert comps[0].finished == "eos" and comps[0].tokens == [eos]
-    assert eng.ticks == ticks0  # never decoded
+    assert eng.ticks == ticks0 + 2
+    assert eng.stats()["discarded_tokens"] == 2
     assert comps[0].t_first == comps[0].t_done
     assert eng._free_slot() == 0
 
@@ -601,3 +604,160 @@ def test_params_are_jit_arguments_not_baked_constants():
                             gen=req.max_tokens, temperature=0.0, top_k=0,
                             seed=req.seed)
     assert c2[0].tokens == out2[0].tolist()
+
+
+# --- deferred reads: the settled view, the discard rule, the counters --------
+
+
+def test_settled_pool_matches_recorded_tokens_after_every_step():
+    """Reading `pool` settles the outstanding read: after every step() of a
+    mixed-length run, each decoding slot's row has consumed its prompt and
+    every recorded token but the last, and each prefilling slot exactly the
+    prompt tokens chunked so far.  Settling changes no stream."""
+    cfg, rt = _rnn_runtime("lstm")
+    reqs = _requests(cfg.vocab, 6, rng_seed=41, max_prompt=12, max_gen=9)
+    eng = ServeEngine(rt, cfg.vocab, slots=3, max_context=64,
+                      prefill_chunk=4)
+    rids = [eng.submit(dataclasses.replace(r)) for r in reqs]
+    got = {rid: [] for rid in rids}
+    checked = 0
+    while eng.has_work():
+        for rid, toks in eng.step()[0]:
+            got[rid].extend(toks)
+        pos = np.asarray(eng.pool.pos)
+        for slot, act in enumerate(eng._active):
+            if act is None:
+                continue
+            want = (act.prompt.size + len(act.tokens) - 1 if act.tokens
+                    else act.off)
+            assert pos[slot] == want, (slot, act.rid)
+            checked += 1
+    assert checked > 0 and eng.stats()["settles"] > 0
+    for r in reqs:
+        out, _ = drive_session(
+            rt, jnp.asarray(np.asarray(r.prompt, np.int32))[None], cfg.vocab,
+            gen=r.max_tokens, temperature=r.temperature, top_k=r.top_k,
+            seed=r.seed)
+        assert got[r.rid] == out[0].tolist()
+
+
+def _eos_mid_stream(rt, vocab):
+    """(prompt, seed, eos): a stream whose third token is its first EOS."""
+    for seed in range(40, 80):
+        probe, _ = drive_session(rt, jnp.zeros((1, 3), jnp.int32), vocab,
+                                 gen=6, temperature=0.8, top_k=0, seed=seed)
+        s = probe[0].tolist()
+        if s[2] not in s[:2]:
+            return np.zeros(3, np.int32), seed, s[2]
+    raise AssertionError("no seed gives a fresh third token")
+
+
+@pytest.mark.parametrize("stop", ["eos", "max_tokens_1"])
+def test_a_stopped_request_drops_one_token(stop):
+    """A request stopped by EOS, or by a quota of one, with a tick in
+    flight: exactly one token (the in-flight tick's) is dropped, and the
+    next occupant of the slot streams exactly what a fresh engine gives."""
+    cfg, rt = _rnn_runtime("lstm")
+    prompt, seed, eos = _eos_mid_stream(rt, cfg.vocab)
+    stopped = Request(prompt=prompt, max_tokens=6, temperature=0.8,
+                      top_k=0, seed=seed, rid=0)
+    if stop == "max_tokens_1":
+        stopped = dataclasses.replace(stopped, max_tokens=1)
+        eos = None
+    nxt = _requests(cfg.vocab, 1, rng_seed=43)[0]
+    nxt = dataclasses.replace(nxt, max_tokens=7, rid=1)
+
+    def engine():
+        return ServeEngine(rt, cfg.vocab, slots=1, max_context=64,
+                           prefill_chunk=4, eos_id=eos)
+
+    eng = engine()
+    comps, _ = eng.run([stopped, dataclasses.replace(nxt)], realtime=False)
+    by = {c.rid: c for c in comps}
+    assert eng.stats()["discarded_tokens"] == 1
+    if stop == "eos":
+        assert by[0].finished == "eos" and len(by[0].tokens) == 3
+    else:
+        assert by[0].finished == "length" and len(by[0].tokens) == 1
+    assert by[1].slot == by[0].slot == 0
+    fresh, _ = engine().run([dataclasses.replace(nxt)], realtime=False)
+    assert by[1].tokens == fresh[0].tokens
+
+
+def test_cancel_with_a_tick_in_flight_returns_settled_tokens():
+    """cancel() settles the tick in flight first: the completion holds its
+    token too, that token is oracle-exact, and the next step() returns it
+    as the request's last event."""
+    cfg, rt = _rnn_runtime("lstm")
+    req = Request(prompt=np.arange(5, dtype=np.int32) % cfg.vocab,
+                  max_tokens=20, temperature=0.8, top_k=5, seed=9, rid=0)
+    eng = ServeEngine(rt, cfg.vocab, slots=2, max_context=64,
+                      prefill_chunk=4)
+    eng.submit(dataclasses.replace(req))
+    got = []
+    while len(got) < 3:
+        for _, toks in eng.step()[0]:
+            got.extend(toks)
+    assert any(u.tick for u in eng._unread)   # a tick is in flight
+    comp = eng.cancel(0)
+    assert comp.finished == "cancelled" and eng.stats()["settles"] == 1
+    assert comp.tokens == got + [comp.tokens[-1]]
+    out, _ = drive_session(rt, jnp.asarray(req.prompt)[None], cfg.vocab,
+                           gen=req.max_tokens, temperature=req.temperature,
+                           top_k=req.top_k, seed=req.seed)
+    assert comp.tokens == out[0].tolist()[:len(comp.tokens)]
+    events, comps = eng.step()
+    assert events == [(0, [comp.tokens[-1]])] and comps == []
+    assert not eng.has_work() and eng._free_slot() == 0
+
+
+def test_scripted_run_counts_discards_settles_and_ticks_ahead(tmp_path):
+    """A run whose every read is known in advance.  Two slots; A (quota 4)
+    and B (quota 1) each commit their one-chunk prompt:
+      step 1  A commits; tick 1 feeds A
+      step 2  B commits; tick 2 feeds A and B, the first tick dispatched
+              with an earlier tick unread (ahead 1); B's quota is full, so
+              B leaves; A's first token and tick 1 are read
+      pool    settle 1: B's first token, tick 2 (B's token dropped: 1)
+      step 3  tick 3 feeds A (nothing unread: not ahead); A's quota fills
+      cancel  settle 2: tick 3, A's last token; A is done, so None
+      step 4  returns what settle 2 read."""
+    from repro.serve import telemetry
+
+    cfg, rt = _rnn_runtime("lstm")
+    eng = ServeEngine(rt, cfg.vocab, slots=2, max_context=64,
+                      prefill_chunk=4)
+    eng.warm()
+    d0, s0 = eng.stats()["discarded_tokens"], eng.stats()["settles"]
+    ticks0 = eng.ticks
+    a = Request(prompt=np.array([1, 2, 3], np.int32), max_tokens=4,
+                temperature=0.0, seed=1, rid=10)
+    b = Request(prompt=np.array([4, 5], np.int32), max_tokens=1,
+                temperature=0.0, seed=2, rid=11)
+    got, comps = {10: [], 11: []}, []
+
+    def step():
+        events, cs = eng.step()
+        for rid, toks in events:
+            got[rid].extend(toks)
+        comps.extend(cs)
+
+    with jax.profiler.trace(str(tmp_path)):
+        eng.submit(a)
+        eng.submit(b)
+        step()
+        step()
+        assert got == {10: [got[10][0], got[10][1]], 11: []}
+        eng.pool
+        step()
+        assert eng.cancel(10) is None
+        step()
+        counts = telemetry.snapshot()["counts"]
+    assert not eng.has_work()
+    assert eng.ticks - ticks0 == 3
+    assert eng.stats()["discarded_tokens"] - d0 == 1
+    assert eng.stats()["settles"] - s0 == 2
+    assert counts == {"serve.ticks_ahead": 1}
+    assert [c.rid for c in comps] == [11, 10]
+    assert len(got[10]) == 4 and len(got[11]) == 1
+    assert {c.rid: c.tokens for c in comps} == got
