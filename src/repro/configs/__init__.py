@@ -14,6 +14,7 @@ _ARCH_MODULES = {
     "whisper-base": "whisper_base",
     "llama-3.2-vision-90b": "llama32_vision_90b",
     "zamba2-1.2b": "zamba2_1_2b",
+    "zamba2-7b": "zamba2_7b",
     "qwen3-moe-30b-a3b": "qwen3_moe_30b_a3b",
     "mixtral-8x7b": "mixtral_8x7b",
 }

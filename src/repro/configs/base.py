@@ -46,7 +46,14 @@ class ModelConfig:
     ssm_expand: int = 2
     ssm_headdim: int = 64
     ssm_conv: int = 4
-    attn_every: int = 0  # zamba2: shared attn block period
+    ssm_groups: int = 1  # B/C groups; heads of a group share B and C
+    # zamba2 hybrids: the shared attention+MLP blocks (`n_shared` of them,
+    # called in turn) run before the mamba layers listed in `hybrid_ids`;
+    # each call has its own rank-`adapter_rank` gate_up adapter and its own
+    # d x d output projection (models/transformer.py, hybrid path)
+    hybrid_ids: Tuple[int, ...] = ()
+    n_shared: int = 1
+    adapter_rank: int = 0
 
     # enc-dec (whisper): n_layers = decoder depth
     n_enc_layers: int = 0
@@ -102,7 +109,18 @@ class ModelConfig:
     def reduced(self) -> "ModelConfig":
         """Tiny same-family config for CPU smoke tests."""
         pat = self.block_pattern
-        n_layers = max(len(pat), 2) if self.family != "hybrid" else max(self.attn_every + 1, 4)
+        if self.family == "hybrid":
+            # six mamba layers, three shared-block calls at irregular gaps
+            # (a prefix of 1, then 2, 1, 2), both shared blocks and both
+            # B/C groups kept; head_dim = 2 * d_model / n_heads as published
+            return dataclasses.replace(
+                self, n_layers=6, d_model=64, n_heads=4, n_kv=4, head_dim=32,
+                d_ff=128, vocab=256, ssm_state=16, ssm_headdim=16,
+                ssm_groups=min(self.ssm_groups, 2),
+                n_shared=min(self.n_shared, 2), hybrid_ids=(1, 3, 4),
+                adapter_rank=min(self.adapter_rank, 8), attn_chunk=32,
+                ssm_chunk=8, dtype="float32")
+        n_layers = max(len(pat), 2)
         return dataclasses.replace(
             self,
             n_layers=n_layers,
@@ -118,7 +136,6 @@ class ModelConfig:
             ssm_state=min(self.ssm_state, 16) if self.ssm_state else 0,
             ssm_headdim=16,
             n_enc_layers=min(self.n_enc_layers, 2),
-            attn_every=min(self.attn_every, 2) if self.attn_every else 0,
             n_img_tokens=min(self.n_img_tokens, 16) if self.n_img_tokens else 0,
             attn_chunk=32,
             ssm_chunk=8,

@@ -162,8 +162,22 @@ def _matmul_kernel(x_ref, wp_ref, o_ref, acc_ref, *, bk: int, mode: str):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     unpack = _unpack_ternary_tile if mode == "ternary" else _unpack_binary_tile
-    w = unpack(wp_ref[...], bk).astype(x_ref.dtype)
-    acc_ref[...] += jnp.dot(x_ref[...], w, preferred_element_type=jnp.float32)
+    w = unpack(wp_ref[...], bk).astype(jnp.bfloat16)   # +-1/0: exact
+    x = x_ref[...]
+    if x.dtype == jnp.float32:
+        # float32 activations in float32: x is the sum of three bfloat16
+        # parts (8 significand bits each), every product with a +-1/0
+        # weight is exact, and the sums accumulate in float32 — where one
+        # bfloat16 pass would round x to 8 bits
+        parts = []
+        for _ in range(3):
+            hi = x.astype(jnp.bfloat16)
+            parts.append(hi)
+            x = x - hi.astype(jnp.float32)
+    else:
+        parts = [x.astype(jnp.bfloat16)]
+    for xp in parts:
+        acc_ref[...] += jnp.dot(xp, w, preferred_element_type=jnp.float32)
 
     @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
     def _done():

@@ -211,9 +211,10 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         from repro.models.transformer import expand_pattern
         pat, rep, tail = expand_pattern(cfg)
         corrected = main
-        if rep > 1:
-            per = cfg.attn_every if (cfg.family == "hybrid" and cfg.attn_every)\
-                else len(cfg.block_pattern)
+        # (a hybrid's call sites are fixed layer ids: its scan is not
+        # corrected)
+        if rep > 1 and cfg.family != "hybrid":
+            per = len(cfg.block_pattern)
             cfg0 = dataclasses.replace(cfg, n_layers=0, n_enc_layers=0,
                                        unroll=True)
             cfg1 = dataclasses.replace(cfg, n_layers=per, n_enc_layers=0,

@@ -163,13 +163,16 @@ def param_counts(cfg) -> tuple[float, float]:
         active = total
     elif cfg.family == "hybrid":
         di = cfg.d_inner
-        N = cfg.ssm_state
+        GN = cfg.ssm_groups * cfg.ssm_state
         H = cfg.ssm_heads
-        mamba = d * (2 * di + 2 * N + H) + di * d
-        shared = attn + 3 * d * ff
-        total = cfg.n_layers * mamba + shared
-        napp = cfg.n_layers // max(cfg.attn_every, 1)
-        active = cfg.n_layers * mamba + napp * shared
+        mamba = d * (2 * di + 2 * GN + H) + di * d
+        # a shared block reads the 2d concat; each call owns its adapter
+        # and its d x d linear
+        shared = 2 * attn - cfg.n_heads * hd * d + 3 * d * ff
+        own = d * d + cfg.adapter_rank * (d + 2 * ff)
+        calls = len(cfg.hybrid_ids)
+        total = cfg.n_layers * mamba + cfg.n_shared * shared + calls * own
+        active = cfg.n_layers * mamba + calls * (shared + own)
     else:
         if cfg.n_experts > 0:
             moe = cfg.n_experts * 3 * d * ff
@@ -245,11 +248,12 @@ def analytic_hbm_bytes(cfg, shape, n_chips: int, *, weight_bits: float = 16,
     from repro.models.transformer import expand_pattern
     pat, rep, tail = expand_pattern(cfg)
     kinds = list(pat) * rep + list(tail)
+    if cfg.family == "hybrid":  # one K/V cache per shared-block call
+        kinds += ["full"] * len(cfg.hybrid_ids)
     for k in kinds:
         if k in ("mamba", "rwkv"):
             if cfg.family == "hybrid":
-                di, N = cfg.d_inner, cfg.ssm_state
-                kv_read += B * (di // cfg.ssm_headdim) * N * cfg.ssm_headdim * 4
+                kv_read += B * cfg.d_inner * cfg.ssm_state * 4
             else:
                 H = cfg.d_model // cfg.hd
                 kv_read += B * H * cfg.hd * cfg.hd * 4

@@ -27,6 +27,7 @@ from repro.runtime import constrain
 
 Array = jax.Array
 NEG_INF = -1e30
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def rms_norm(x: Array, scale: Array, eps: float = 1e-6) -> Array:
@@ -65,7 +66,8 @@ def sinusoidal_positions(seq: int, d: int) -> Array:
 
 
 def _sdpa(q: Array, k: Array, v: Array, q_pos: Array, kv_pos: Array,
-          *, causal: bool, window: int, softcap: float = 0.0) -> Array:
+          *, causal: bool, window: int, softcap: float = 0.0,
+          scale: float = 0.0) -> Array:
     """q: (B, Sq, Hq, hd); k, v: (B, Skv, Hkv, hd) with Hq % Hkv == 0;
     *_pos: (Sq,), (Skv,) absolute positions (kv_pos < 0 marks invalid /
     unwritten cache slots) — or per-sequence (B, Sq) / (B, Skv) when slots
@@ -80,9 +82,11 @@ def _sdpa(q: Array, k: Array, v: Array, q_pos: Array, kv_pos: Array,
     Hkv = k.shape[2]
     G = Hq // Hkv
     qg = q.reshape(B, Sq, Hkv, G, hd)
-    scale = hd ** -0.5
+    scale = scale or hd ** -0.5
+    # HIGHEST: float32 operands are multiplied in float32 (the TPU default
+    # rounds them to bfloat16 first; bfloat16 operands are exact either way)
     logits = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k,
-                        preferred_element_type=jnp.float32)
+                        preferred_element_type=jnp.float32, precision=HIGHEST)
     logits = logits * scale
     if softcap > 0.0:
         logits = jnp.tanh(logits / softcap) * softcap
@@ -95,20 +99,22 @@ def _sdpa(q: Array, k: Array, v: Array, q_pos: Array, kv_pos: Array,
         mask = mask & (kp[:, None, :] > qp[:, :, None] - window)
     logits = jnp.where(mask[:, None, None], logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-    o = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v)
+    o = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v, precision=HIGHEST)
     return o.reshape(B, Sq, Hq, hd)
 
 
 def attention(q: Array, k: Array, v: Array, *, causal: bool = True,
               window: int = 0, q_offset=0, kv_pos: Optional[Array] = None,
-              chunk: int = 1024, softcap: float = 0.0) -> Array:
+              chunk: int = 1024, softcap: float = 0.0,
+              scale: float = 0.0) -> Array:
     """Grouped-query attention with query chunking.
 
     q: (B, Sq, Hq, hd); k, v: (B, Skv, Hkv, hd) with Hq % Hkv == 0.
     `q_offset` is the absolute position of q[0] (decode: cache length) —
     scalar, or (B,) when a per-slot cache puts every sequence at its own
     depth.  `kv_pos` gives absolute positions of cache slots (ring buffers);
-    (Skv,) or per-slot (B, Skv); defaults to arange(Skv).
+    (Skv,) or per-slot (B, Skv); defaults to arange(Skv).  `scale` of the
+    logits, 0 for hd ** -0.5.
     """
     B, Sq, Hq, hd = q.shape
     Skv, Hkv = k.shape[1], k.shape[2]
@@ -120,7 +126,8 @@ def attention(q: Array, k: Array, v: Array, *, causal: bool = True,
     batched = q_pos.ndim == 2 or kv_pos.ndim == 2
 
     if Sq <= chunk or Sq % chunk != 0:
-        return _sdpa(q, k, v, q_pos, kv_pos, causal=causal, window=window, softcap=softcap)
+        return _sdpa(q, k, v, q_pos, kv_pos, causal=causal, window=window,
+                     softcap=softcap, scale=scale)
 
     n_chunks = Sq // chunk
     # the sliding-window KV slice needs one scalar start per chunk, so it
@@ -144,7 +151,8 @@ def attention(q: Array, k: Array, v: Array, *, causal: bool = True,
             kp = start + jnp.arange(kv_span, dtype=jnp.int32)
         else:
             ki, vi, kp = k, v, kv_pos
-        return _sdpa(qi, ki, vi, qp, kp, causal=causal, window=window, softcap=softcap)
+        return _sdpa(qi, ki, vi, qp, kp, causal=causal, window=window,
+                     softcap=softcap, scale=scale)
 
     out = jax.lax.map(one, jnp.arange(n_chunks))  # (n_chunks, B, chunk, Hq, hd)
     return jnp.moveaxis(out, 0, 1).reshape(B, Sq, Hq, hd)

@@ -1,6 +1,6 @@
 """Mamba2 (SSD) mixer — chunked selective-state-space recurrence.
 
-Used by `zamba2-1.2b` (hybrid).  TPU-native formulation:
+Used by the zamba2 hybrids.  TPU-native formulation:
 
   * the sequence is processed in chunks of `cfg.ssm_chunk`; within a chunk the
     recurrence is a dense (Q x Q) causally-masked matmul (MXU work), across
@@ -12,7 +12,17 @@ Used by `zamba2-1.2b` (hybrid).  TPU-native formulation:
     mirroring the paper keeping biases/BN parameters full-precision.
 
 Shapes: d_inner = expand * d_model, H = d_inner / headdim ssm heads,
-N = ssm_state, single B/C group (zamba2 uses n_groups=1).
+N = ssm_state, G = ssm_groups B/C groups (head h reads group h // (H/G)).
+The in-projection packs [z, x, B, C, dt]; x, B and C pass through the
+causal conv; the output is RMS-normalized per group of d_inner / G after
+the silu(z) gate, and the time step is floored at 0.001 (transformers'
+`Zamba2MambaMixer`).
+
+Bucket-padded chunks (chunked in-slot prefill, DESIGN.md §8): `n` marks
+the first n tokens as real.  A pad token gets dt = 0 and x = 0, so it
+leaves the state h exactly as it was, and the conv tail is taken at the
+last real token, so a padded chunk carries the same state as its real
+prefix alone.
 """
 from __future__ import annotations
 
@@ -26,6 +36,11 @@ from repro.core.qlinear import maybe_scale, scaled, winit
 from repro.runtime import constrain
 
 Array = jax.Array
+# every product here multiplies float32 in float32 (the TPU default rounds
+# float32 operands to bfloat16 first)
+HIGHEST = jax.lax.Precision.HIGHEST
+DT_MIN = 1e-3    # floor of the time step (Zamba2's time_step_min)
+NORM_EPS = 1e-5  # of the gated RMSNorm (Zamba2's mixer)
 
 
 class SSMState(NamedTuple):
@@ -39,21 +54,22 @@ def _dims(cfg):
     H = cfg.ssm_heads
     P = cfg.ssm_headdim
     N = cfg.ssm_state
-    conv_dim = di + 2 * N  # x, B, C pass through the causal conv
+    GN = cfg.ssm_groups * N
+    conv_dim = di + 2 * GN  # x, B, C pass through the causal conv
     return di, H, P, N, conv_dim
 
 
 def mamba2_init(key, cfg) -> dict:
     d = cfg.d_model
     di, H, P, N, conv_dim = _dims(cfg)
-    d_proj = 2 * di + 2 * N + H  # z, x, B, C, dt
+    d_proj = di + conv_dim + H  # z, x, B, C, dt
     ki, ko, kc, kd = jax.random.split(key, 4)
     p = {
         "Win": winit(ki, (d, d_proj)),
         "Wout": winit(ko, (di, d)),
         "conv_w": jax.random.normal(kc, (cfg.ssm_conv, conv_dim)) * 0.1,
         "conv_b": jnp.zeros((conv_dim,)),
-        "A_log": jnp.log(jnp.linspace(1.0, 16.0, H)),  # A = -exp(A_log)
+        "A_log": jnp.log(jnp.arange(1, H + 1, dtype=jnp.float32)),  # A = -exp
         "D": jnp.ones((H,)),
         "dt_bias": jnp.log(jnp.expm1(
             jnp.exp(jax.random.uniform(kd, (H,)) * (jnp.log(0.1) - jnp.log(1e-3))
@@ -65,16 +81,31 @@ def mamba2_init(key, cfg) -> dict:
     return p
 
 
-def _causal_conv(x: Array, w: Array, b: Array, tail: Optional[Array] = None):
+def _causal_conv(x: Array, w: Array, b: Array, tail: Optional[Array] = None,
+                 n=None):
     """x: (B, S, C) depthwise causal conv with kernel (K, C).  `tail` is the
-    last K-1 inputs from the previous call (decode); returns (y, new_tail)."""
+    last K-1 inputs from the previous call (decode); returns (y, new_tail).
+    With `n` (traced) only the first n inputs are real: the new tail is the
+    last K-1 inputs up to and including the n-th."""
     K = w.shape[0]
     if tail is None:
         tail = jnp.zeros((x.shape[0], K - 1, x.shape[2]), x.dtype)
     xp = jnp.concatenate([tail, x], axis=1)
-    new_tail = xp[:, -(K - 1):, :] if K > 1 else tail
+    if K == 1:
+        new_tail = tail
+    elif n is None:
+        new_tail = xp[:, -(K - 1):, :]
+    else:
+        new_tail = jax.lax.dynamic_slice_in_dim(xp, n, K - 1, axis=1)
     y = sum(xp[:, i:i + x.shape[1], :] * w[i].astype(x.dtype) for i in range(K))
     return jax.nn.silu(y + b.astype(x.dtype)), new_tail
+
+
+def _heads(m: Array, H: int, grouped: bool) -> Array:
+    if not grouped:
+        return jnp.broadcast_to(m[..., None, :], m.shape[:-1] + (H, m.shape[-1]))
+    G = m.shape[-2]
+    return jnp.repeat(m, H // G, axis=-2)
 
 
 def ssd_chunked(x: Array, dt: Array, A: Array, Bm: Array, Cm: Array,
@@ -82,11 +113,13 @@ def ssd_chunked(x: Array, dt: Array, A: Array, Bm: Array, Cm: Array,
     """Chunked SSD scan.
 
     x:  (B, S, H, P)   dt: (B, S, H)   A: (H,) (negative)
-    Bm, Cm: (B, S, N)  (single group, broadcast over heads)
+    Bm, Cm: (B, S, N) (one group, shared by every head) or (B, S, G, N)
     h0: optional (B, H, N, P) initial state.
     Returns (y (B, S, H, P), h_final (B, H, N, P)).
     """
     Bsz, S, H, P = x.shape
+    grouped = Bm.ndim == 4
+    Bm, Cm = _heads(Bm, H, grouped), _heads(Cm, H, grouped)  # (B, S, H, N)
     N = Bm.shape[-1]
     Q = min(chunk, S)
     S0_len = S
@@ -96,8 +129,8 @@ def ssd_chunked(x: Array, dt: Array, A: Array, Bm: Array, Cm: Array,
         pad = Q - S % Q
         x = jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
         dt = jnp.pad(dt, ((0, 0), (0, pad), (0, 0)))
-        Bm = jnp.pad(Bm, ((0, 0), (0, pad), (0, 0)))
-        Cm = jnp.pad(Cm, ((0, 0), (0, pad), (0, 0)))
+        Bm = jnp.pad(Bm, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        Cm = jnp.pad(Cm, ((0, 0), (0, pad), (0, 0), (0, 0)))
         S = S + pad
     nc = S // Q
 
@@ -113,13 +146,15 @@ def ssd_chunked(x: Array, dt: Array, A: Array, Bm: Array, Cm: Array,
     causal = idx[:, None] >= idx[None, :]
     logdec = cum[:, :, :, None, :] - cum[:, :, None, :, :]   # (B,nc,Qi,Qj,H)
     dec = jnp.where(causal[None, None, :, :, None], jnp.exp(logdec), 0.0)
-    cb = jnp.einsum("bcin,bcjn->bcij", Cm, Bm)               # (B,nc,Qi,Qj)
-    att = cb[..., None] * dec * dt[:, :, None, :, :]         # (B,nc,Qi,Qj,H)
-    y_intra = jnp.einsum("bcijh,bcjhp->bcihp", att, x)
+    cb = jnp.einsum("bcihn,bcjhn->bcijh", Cm, Bm,
+                    precision=HIGHEST)                       # (B,nc,Qi,Qj,H)
+    att = cb * dec * dt[:, :, None, :, :]                    # (B,nc,Qi,Qj,H)
+    y_intra = jnp.einsum("bcijh,bcjhp->bcihp", att, x, precision=HIGHEST)
 
     # chunk states: S_c = sum_j exp(seg_end - cum_j) * dt_j * B_j x_j^T
     w_state = jnp.exp(seg_end[:, :, None, :] - cum) * dt     # (B,nc,Q,H)
-    states = jnp.einsum("bcjh,bcjn,bcjhp->bchnp", w_state, Bm, x)
+    states = jnp.einsum("bcjh,bcjhn,bcjhp->bchnp", w_state, Bm, x,
+                        precision=HIGHEST)
 
     # inter-chunk scan over nc (tiny: S/Q iterations of an (H,N,P) op).
     # The recurrent state is ALWAYS fp32 (decay products compound; bf16
@@ -140,74 +175,94 @@ def ssd_chunked(x: Array, dt: Array, A: Array, Bm: Array, Cm: Array,
     h_in = jnp.moveaxis(h_in, 0, 1)                          # (B,nc,H,N,P)
 
     # inter-chunk contribution: y_i += C_i . (exp(cum_i) * h_in)
-    y_inter = jnp.einsum("bcin,bchnp,bcih->bcihp", Cm, h_in, jnp.exp(cum))
+    y_inter = jnp.einsum("bcihn,bchnp,bcih->bcihp", Cm, h_in, jnp.exp(cum),
+                         precision=HIGHEST)
     y = (y_intra + y_inter).reshape(Bsz, S, H, P)
     return y[:, :S0_len], hT
 
 
 def ssd_step(x: Array, dt: Array, A: Array, Bm: Array, Cm: Array, h: Array):
     """Single-token recurrence (decode).  x: (B, H, P), dt: (B, H),
-    Bm/Cm: (B, N), h: (B, H, N, P) -> (y, h').
+    Bm/Cm: (B, N) or (B, G, N), h: (B, H, N, P) -> (y, h').
 
     This IS the serving decode_step body (serve/recurrent.py): decay, rank-1
     state update, readout — no sequence axis."""
+    H = x.shape[1]
+    grouped = Bm.ndim == 3
+    Bm, Cm = _heads(Bm, H, grouped), _heads(Cm, H, grouped)  # (B, H, N)
     dA = jnp.exp(dt * A)                                     # (B, H)
-    inc = jnp.einsum("bh,bn,bhp->bhnp", dt, Bm, x)
+    inc = jnp.einsum("bh,bhn,bhp->bhnp", dt, Bm, x, precision=HIGHEST)
     h = h * dA[:, :, None, None] + inc
-    y = jnp.einsum("bn,bhnp->bhp", Cm, h)
+    y = jnp.einsum("bhn,bhnp->bhp", Cm, h, precision=HIGHEST)
     return y, h
 
 
+def gated_group_norm(y: Array, z: Array, w: Array, groups: int,
+                     eps: float) -> Array:
+    """norm(y * silu(z)) with the RMS taken over each of `groups` equal
+    slices of the last axis, then scaled by `w` (fp32 inside)."""
+    g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    gs = g.reshape(g.shape[:-1] + (groups, g.shape[-1] // groups))
+    gs = gs * jax.lax.rsqrt(jnp.mean(gs * gs, axis=-1, keepdims=True) + eps)
+    return gs.reshape(g.shape) * w.astype(jnp.float32)
+
+
 def mamba2_apply(p: dict, x: Array, cfg, *, state: Optional[SSMState] = None,
-                 decode: Optional[bool] = None) -> Tuple[Array, Optional[SSMState]]:
+                 decode: Optional[bool] = None,
+                 n=None) -> Tuple[Array, Optional[SSMState]]:
     """x: (B, S, d_model). decode=True expects S == 1 and a state;
     decode=None auto-selects the `ssd_step` path for a single carried-state
     token (direct mixer callers; the transformer block driver passes the
-    flag explicitly)."""
+    flag explicitly).  `n` (traced int32, prefill only) marks the first n
+    tokens as real and the rest as bucket padding (module docstring)."""
     Bsz, S, d = x.shape
     di, H, P, N, conv_dim = _dims(cfg)
+    G = cfg.ssm_groups
     if decode is None:
         decode = state is not None and S == 1
 
     proj = scaled(qmatmul(x, p["Win"]), p, "Win", cfg.quant)
-    z, xin, Bc, Cc, dt = jnp.split(
-        proj, [di, 2 * di, 2 * di + N, 2 * di + 2 * N], axis=-1)
+    z, xBC, dt = jnp.split(proj, [di, di + conv_dim], axis=-1)
     dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])  # (B,S,H)
-    A = -jnp.exp(p["A_log"])
+    dt = jnp.maximum(dt, DT_MIN)
+    A = -jnp.exp(p["A_log"].astype(jnp.float32))
 
-    conv_in = jnp.concatenate([xin, Bc, Cc], axis=-1)
     tail = state.conv if state is not None else None
-    conv_out, new_tail = _causal_conv(conv_in, p["conv_w"], p["conv_b"], tail)
-    xin, Bc, Cc = jnp.split(conv_out, [di, di + N], axis=-1)
+    conv_out, new_tail = _causal_conv(xBC, p["conv_w"], p["conv_b"], tail,
+                                      None if n is None
+                                      else jnp.asarray(n, jnp.int32))
+    xin, Bc, Cc = jnp.split(conv_out, [di, di + G * N], axis=-1)
+    if n is not None:
+        real = (jnp.arange(S) < n)[None, :, None]
+        dt = jnp.where(real, dt, 0.0)
+        xin = jnp.where(real, xin, 0.0)
 
     xh = xin.reshape(Bsz, S, H, P)
     xh = constrain(xh, ("pod", "data"), None, "model", None)
+    grp = (lambda m: m.reshape(m.shape[:-1] + (G, N))) if G > 1 else \
+        (lambda m: m)
     h0 = state.h if state is not None else None
 
     if decode:
         h0 = (h0 if h0 is not None
               else jnp.zeros((Bsz, H, N, P), jnp.float32)).astype(jnp.float32)
         y1, hT = ssd_step(xh[:, 0].astype(jnp.float32), dt[:, 0], A,
-                          Bc[:, 0].astype(jnp.float32),
-                          Cc[:, 0].astype(jnp.float32), h0)
+                          grp(Bc[:, 0].astype(jnp.float32)),
+                          grp(Cc[:, 0].astype(jnp.float32)), h0)
         y = y1[:, None]
     else:
-        y, hT = ssd_chunked(xh, dt, A, Bc, Cc, cfg.ssm_chunk, h0)
+        y, hT = ssd_chunked(xh, dt, A, grp(Bc), grp(Cc), cfg.ssm_chunk, h0)
 
     y = y + p["D"].astype(y.dtype)[None, None, :, None] * xh.astype(y.dtype)
     y = y.reshape(Bsz, S, di)
-
-    # gated RMSNorm (mamba2): norm(y * silu(z))
-    g = y * jax.nn.silu(z)
-    g32 = g.astype(jnp.float32)
-    g = (g32 * jax.lax.rsqrt(jnp.mean(g32 * g32, axis=-1, keepdims=True) + 1e-6)
-         ).astype(x.dtype) * p["norm"].astype(x.dtype)
+    g = gated_group_norm(y, z, p["norm"], G, NORM_EPS).astype(x.dtype)
 
     out = scaled(qmatmul(g, p["Wout"]), p, "Wout", cfg.quant)
     new_state = None
     if state is not None or decode:
-        pos = (state.pos if state is not None else jnp.zeros((), jnp.int32)) + S
-        new_state = SSMState(h=hT, conv=new_tail, pos=pos)
+        pos = (state.pos if state is not None else jnp.zeros((), jnp.int32))
+        new_state = SSMState(h=hT, conv=new_tail,
+                             pos=pos + (S if n is None else n))
     return out, new_state
 
 

@@ -1,9 +1,10 @@
 """Pattern-driven model covering the whole assigned pool.
 
 One code path builds dense (llama3/qwen3/gemma3), MoE (mixtral/qwen3-moe),
-SSM (rwkv6), hybrid (zamba2: mamba2 + shared attention block), enc-dec audio
-(whisper backbone) and VLM (llama-3.2-vision: interleaved cross-attn) models
-from a `ModelConfig`.
+SSM (rwkv6), enc-dec audio (whisper backbone) and VLM (llama-3.2-vision:
+interleaved cross-attn) models from a `ModelConfig`; the zamba2 hybrids
+(mamba2 layers with shared attention blocks called between them) have the
+one path of their own below (`_hybrid_layers`).
 
 HLO-size discipline (critical for the 512-device dry-run): layers are grouped
 by the repeating `block_pattern`; parameters of repeat r, pattern position i
@@ -28,8 +29,9 @@ from typing import Any, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from repro.core.qlinear import quantize_tree, winit
+from repro.core.qlinear import maybe_scale, quantize_tree, scaled, winit
 from repro.core.qtensor import QTensor
 from repro.kernels.ops import qmatmul
 from repro.models import layers as L
@@ -62,21 +64,14 @@ def _remat_policy(cfg):
 
 
 def expand_pattern(cfg) -> Tuple[Tuple[str, ...], int, Tuple[str, ...]]:
-    """-> (pattern, repeats, tail_kinds).  n_layers counts *pattern* layers;
-    for hybrids the shared-attn applications are extra (zamba2 style)."""
-    if cfg.family == "hybrid" and cfg.attn_every > 0:
-        pat = ("mamba",) * cfg.attn_every + ("shared",)
-        rep = cfg.n_layers // cfg.attn_every
-        tail = ("mamba",) * (cfg.n_layers % cfg.attn_every)
-        return pat, rep, tail
+    """-> (pattern, repeats, tail_kinds).  A hybrid is n_layers mamba
+    layers; its shared-block calls (`cfg.hybrid_ids`) are extra."""
+    if cfg.family == "hybrid":
+        return ("mamba",), cfg.n_layers, ()
     pat = cfg.block_pattern
     rep = cfg.n_layers // len(pat)
     tail = pat[: cfg.n_layers % len(pat)]
     return pat, rep, tail
-
-
-def owns_params(kind: str) -> bool:
-    return kind != "shared"
 
 
 # ---------------------------------------------------------------------------
@@ -128,15 +123,16 @@ def model_init(key, cfg) -> dict:
     }
     if not cfg.tie_embeddings:
         params["head"] = winit(keys[next(ki)], (d, Vp))
-    params["stack"] = tuple(
-        _stacked_init(keys[next(ki)], cfg, k, rep) if owns_params(k) else {}
-        for k in pat
-    )
-    params["tail"] = tuple(
-        block_init(keys[next(ki)], cfg, k) if owns_params(k) else {} for k in tail
-    )
     if cfg.family == "hybrid":
-        params["shared"] = block_init(keys[next(ki)], cfg, "full")
+        params["mamba"] = _stacked_init(keys[next(ki)], cfg, "mamba", rep)
+        params["shared"] = jax.vmap(lambda k: shared_block_init(k, cfg))(
+            jax.random.split(keys[next(ki)], cfg.n_shared))
+        params["calls"] = jax.vmap(lambda k: call_init(k, cfg))(
+            jax.random.split(keys[next(ki)], len(cfg.hybrid_ids)))
+        return params
+    params["stack"] = tuple(_stacked_init(keys[next(ki)], cfg, k, rep)
+                            for k in pat)
+    params["tail"] = tuple(block_init(keys[next(ki)], cfg, k) for k in tail)
     if cfg.family == "audio":
         ek = jax.random.split(keys[next(ki)], cfg.n_enc_layers + 1)
         params["enc"] = {
@@ -216,8 +212,7 @@ def _attn_cached(p, x, cfg, kind, cache, xcache: Optional[CrossCache],
                  live=None):
     """Prefill/decode attention block; returns (x, new_cache, new_xcache)."""
     window = cfg.window if (kind == "local" or cfg.swa_all) else 0
-    if cfg.parallel_block and kind in ("full", "global", "self", "local",
-                                       "shared"):
+    if cfg.parallel_block and kind in ("full", "global", "self", "local"):
         h = L.rms_norm(x, p["norm1"])
         o, cache = _self_attn_cached(p["attn"], h, cfg, cache, window=window,
                                      live=live)
@@ -246,12 +241,6 @@ def _attn_cached(p, x, cfg, kind, cache, xcache: Optional[CrossCache],
     return x + y, cache, xcache, aux
 
 
-def _mamba_block(p, x, cfg, state, decode):
-    h = L.rms_norm(x, p["norm"])
-    y, new_state = M.mamba2_apply(p["mixer"], h, cfg, state=state, decode=decode)
-    return x + y.astype(x.dtype), new_state
-
-
 def _rwkv_block(p, x, cfg, state: Optional[R.RWKVState], decode):
     h = L.rms_norm(x, p["ln1"])
     y, S, tm_last = R.rwkv6_time_mix(p["mix"], h, cfg, state=state, decode=decode)
@@ -268,6 +257,219 @@ def _rwkv_block(p, x, cfg, state: Optional[R.RWKVState], decode):
 
 
 # ---------------------------------------------------------------------------
+# zamba2 hybrid (transformers' modeling_zamba2): n_layers mamba2 layers; before
+# the layers in cfg.hybrid_ids a shared attention+MLP block runs on
+# concat(hidden, embeddings), the n_shared blocks called in turn
+# ---------------------------------------------------------------------------
+
+
+HYBRID_EPS = 1e-5   # every RMSNorm of a zamba2 hybrid (rms_norm_eps)
+
+
+def hybrid_calls(cfg) -> np.ndarray:
+    """(n_layers,) int32: the shared-block call made before mamba layer i,
+    -1 where there is none."""
+    calls = np.full(cfg.n_layers, -1, np.int32)
+    calls[list(cfg.hybrid_ids)] = np.arange(len(cfg.hybrid_ids))
+    return calls
+
+
+def shared_block_init(key, cfg) -> dict:
+    """One shared block: attention over the 2*d_model concat (head_dim is
+    2*d_model/n_heads as published), then the gated-GELU MLP."""
+    d, hd, ff = cfg.d_model, cfg.hd, cfg.d_ff
+    kq, kk, kv, ko, kg, kd = jax.random.split(key, 6)
+    p = {"norm1": jnp.zeros((2 * d,)), "norm2": jnp.zeros((d,)),
+         "Wq": winit(kq, (2 * d, cfg.n_heads * hd)),
+         "Wk": winit(kk, (2 * d, cfg.n_kv * hd)),
+         "Wv": winit(kv, (2 * d, cfg.n_kv * hd)),
+         "Wo": winit(ko, (cfg.n_heads * hd, d)),
+         "Wgate_up": winit(kg, (d, 2 * ff)),
+         "Wdown": winit(kd, (ff, d))}
+    for n in ("Wq", "Wk", "Wv", "Wo", "Wgate_up", "Wdown"):
+        maybe_scale(p, n, cfg.quant, p[n].shape[1], jnp.float32)
+    return p
+
+
+def call_init(key, cfg) -> dict:
+    """What each call owns: the rank-r gate_up adapter and the d x d linear
+    that projects the block's output into the next mamba layer's input."""
+    d, r = cfg.d_model, cfg.adapter_rank
+    ka, kb, kl = jax.random.split(key, 3)
+    p = {"Wlinear": winit(kl, (d, d))}
+    if r:
+        p["Wadapt_in"] = winit(ka, (d, r))
+        p["Wadapt_out"] = winit(kb, (r, 2 * cfg.d_ff))
+    for n in list(p):
+        maybe_scale(p, n, cfg.quant, p[n].shape[1], jnp.float32)
+    return p
+
+
+def _mm(x, p, name, cfg):
+    return scaled(qmatmul(x, p[name]), p, name, cfg.quant)
+
+
+def hybrid_kv_width(cfg) -> int:
+    """Lanes of one head in a hybrid's K/V cache: hd rounded up to 128.
+    Zamba2-7B's hd = 224 is no multiple of the 128 lanes; padded with
+    zeros to 256, the cache keeps its plain layout and the attention dots
+    read it in place."""
+    return -(-cfg.hd // 128) * 128
+
+
+def _call_qkv(qp, x, emb, pos, c, cfg):
+    """Call `c` of the shared blocks, up to its roped queries, keys and
+    values over the 2 d-wide concat of x and the embeddings, (B, S, heads,
+    hybrid_kv_width) each, zero past hd.  `pos` is the position of x's
+    first token: () or per row (B,)."""
+    B, S, _ = x.shape
+    p = jax.tree.map(lambda a: a[c % cfg.n_shared], qp["shared"])
+    h = L.rms_norm(jnp.concatenate([x, emb], axis=-1), p["norm1"],
+                   HYBRID_EPS)
+    positions = (pos[:, None] if pos.ndim else pos) + jnp.arange(
+        S, dtype=jnp.int32)
+    pad = [(0, 0)] * 3 + [(0, hybrid_kv_width(cfg) - cfg.hd)]
+
+    def heads(name, n, roped):
+        y = _mm(h, p, name, cfg).reshape(B, S, n, cfg.hd)
+        if roped:
+            y = L.rope(y, positions, cfg.rope_theta)
+        return jnp.pad(y, pad)
+
+    return (heads("Wq", cfg.n_heads, True), heads("Wk", cfg.n_kv, True),
+            heads("Wv", cfg.n_kv, False))
+
+
+def _call_out(qp, q, k, v, kv_pos, pos, c, cfg):
+    """The rest of call `c`: attention of q over keys and values (B, Skv,
+    heads, hybrid_kv_width) at positions kv_pos (-1 unwritten), o_proj,
+    the gated-GELU MLP with the call's gate_up adapter, and the call's
+    linear.  -> (B, S, d), what the call adds to the next mamba layer's
+    input."""
+    B, S = q.shape[:2]
+    p = jax.tree.map(lambda a: a[c % cfg.n_shared], qp["shared"])
+    pc = jax.tree.map(lambda a: a[c], qp["calls"])
+    # Zamba2: hd is 2 d / heads, over the concat, and the scale (hd/2)^-0.5
+    o = L.attention(q, k, v, causal=True, q_offset=pos, kv_pos=kv_pos,
+                    chunk=cfg.attn_chunk, scale=(cfg.hd / 2) ** -0.5)
+    o = _mm(o[..., :cfg.hd].reshape(B, S, cfg.n_heads * cfg.hd), p, "Wo",
+            cfg)
+    h = L.rms_norm(o, p["norm2"], HYBRID_EPS)
+    gu = _mm(h, p, "Wgate_up", cfg)
+    if cfg.adapter_rank:
+        gu = gu + _mm(_mm(h, pc, "Wadapt_in", cfg), pc, "Wadapt_out", cfg)
+    gate, up = jnp.split(gu, 2, axis=-1)
+    h = jax.nn.gelu(gate, approximate=False) * up
+    return _mm(_mm(h, p, "Wdown", cfg), pc, "Wlinear", cfg)
+
+
+def _hybrid_layers(qp, x, caches: Optional[dict], cfg, *, decode=False,
+                   n=None, live=None):
+    """All layers of a zamba2 hybrid over x (B, S, d): one `lax.scan` over
+    the n_layers mamba layers, the shared-block call before a layer of
+    `hybrid_ids` in two `lax.cond`s.  The call's output is added to that
+    mamba layer's input only; the residual stream carries x.
+
+    With `caches` ({"ssm": per-layer SSMState, "kv": per-call AttnCache},
+    `init_caches`) every state advances in place in the scan's carry.  The
+    first cond makes the call's q, k and v; they are written into the
+    call's cache between the conds, by a scatter of a few rows (at a layer
+    without a call it writes back the rows it read); the second cond only
+    reads the caches.  So no copy of the caches is made.  `n` marks bucket
+    padding (prefill) and `live` freezes dead rows (decode).
+    -> (x, caches)."""
+    emb = x
+    B, S, _ = x.shape
+    calls = jnp.asarray(hybrid_calls(cfg))
+    layers = jnp.arange(cfg.n_layers, dtype=jnp.int32)
+    ssm = kv = None
+    if caches is not None:
+        ssm, kv = caches["ssm"], caches["kv"]
+    w = hybrid_kv_width(cfg)
+    qkv_shapes = [(B, S, h, w) for h in (cfg.n_heads, cfg.n_kv, cfg.n_kv)]
+
+    def body(carry, xs):
+        x, ssm, kv = carry
+        p, i, c = xs
+        ci = jnp.maximum(c, 0)
+        on = c >= 0
+        pos = jnp.zeros((), jnp.int32) if kv is None else kv.pos[ci]
+        q, k, v = jax.lax.cond(
+            on, lambda x: _call_qkv(qp, x, emb, pos, ci, cfg),
+            lambda x: tuple(jnp.zeros(s, x.dtype) for s in qkv_shapes), x)
+        if kv is None:
+            t = jax.lax.cond(
+                on, lambda: _call_out(qp, q, k, v, None, pos, ci, cfg),
+                lambda: jnp.zeros_like(x))
+        else:
+            kv = _write_call(kv, ci, k, v, jnp.logical_and(
+                on, True if live is None else live))
+
+            def attend(kv):
+                # the call's cache as (B, cap, heads, w): a transpose the
+                # dots absorb
+                ck, cv = (jnp.swapaxes(a[ci], 1, 2) for a in (kv.k, kv.v))
+                cap = ck.shape[1]
+                slots = jnp.arange(cap, dtype=jnp.int32)
+                top = kv.pos[ci]
+                top = top[:, None] if top.ndim else top
+                kv_pos = jnp.where(slots < top, slots, -1)
+                return _call_out(qp, q, ck, cv, kv_pos, pos, ci, cfg)
+
+            t = jax.lax.cond(on, attend, lambda kv: jnp.zeros_like(x), kv)
+        h = L.rms_norm(x + t, p["norm"], HYBRID_EPS)
+        if ssm is None:
+            y, _ = M.mamba2_apply(p["mixer"], h, cfg, decode=False)
+        else:
+            st = jax.tree.map(lambda a: a[i], ssm)
+            y, st2 = M.mamba2_apply(p["mixer"], h, cfg, state=st,
+                                    decode=decode, n=n)
+            if live is not None:
+                st2 = _freeze_dead(st2, st, live)
+            ssm = jax.tree.map(lambda a, b: a.at[i].set(b), ssm, st2)
+        x = constrain(x + y.astype(x.dtype), ("pod", "data"), None, None)
+        return (x, ssm, kv), None
+
+    if caches is None and cfg.remat:
+        body = jax.checkpoint(body, prevent_cse=False,
+                              policy=_remat_policy(cfg))
+    (x, ssm, kv), _ = jax.lax.scan(body, (x, ssm, kv),
+                                   (qp["mamba"], layers, calls))
+    return x, (None if caches is None else {"ssm": ssm, "kv": kv})
+
+
+def _write_call(kv: AttnCache, c, k, v, write) -> AttnCache:
+    """Append call c's new keys and values (B, S, heads, w) to its cache
+    (calls, B, heads, cap, w) at each slot's own depth, in the rows where
+    `write` ((B,) or scalar) holds; elsewhere the rows keep their bytes
+    and pos.  One scatter of (heads, S, w) windows, a row's window
+    starting at its depth (at most cap - S), a row not written pointed
+    past the calls and dropped: a window that leaves the cache's layout
+    as it is (an index per token would make XLA copy the cache into
+    another).  Bucket padding is rewound off pos by `prefill`, as for
+    every attention cache."""
+    B, S = k.shape[:2]
+    n, cap = kv.k.shape[0], kv.k.shape[3]
+    pos = kv.pos[c]                                       # (B,) or ()
+    pos = pos if pos.ndim else jnp.broadcast_to(pos, (B,))
+    w = jnp.broadcast_to(write, (B,))
+    idx = jnp.stack([jnp.where(w, c, n), jnp.arange(B),
+                     jnp.clip(pos, 0, cap - S)], axis=1)
+    dn = jax.lax.ScatterDimensionNumbers(
+        update_window_dims=(1, 2, 3), inserted_window_dims=(0, 1),
+        scatter_dims_to_operand_dims=(0, 1, 3))
+
+    def put(cache, new):
+        return jax.lax.scatter(cache, idx, jnp.swapaxes(new, 1, 2), dn,
+                               mode=jax.lax.GatherScatterMode.FILL_OR_DROP)
+
+    step = S * w.astype(jnp.int32)
+    pos_c = kv.pos[c] + (step if kv.pos.ndim == 2 else step[0])
+    return kv._replace(k=put(kv.k, k), v=put(kv.v, v),
+                       pos=kv.pos.at[c].set(pos_c))
+
+
+# ---------------------------------------------------------------------------
 # cache construction
 # ---------------------------------------------------------------------------
 
@@ -276,7 +478,7 @@ def _kind_cache(cfg, kind: str, batch: int, cap: int, src_len: int, dtype,
                 per_slot: bool = False):
     """Cache pytree for one layer of `kind` (python structure, zero arrays)."""
     Hkv, hd = cfg.n_kv, cfg.hd
-    if kind in ("full", "global", "self", "shared"):
+    if kind in ("full", "global", "self"):
         c = cap if not cfg.swa_all else min(cfg.window + DECODE_MARGIN, cap)
         return {"attn": cache_init(batch, c, Hkv, hd, dtype, ring=cfg.swa_all,
                                    per_slot=per_slot)}
@@ -312,6 +514,17 @@ def init_caches(cfg, batch: int, context: int, *, src_len: int = 0,
         one = _kind_cache(cfg, kind, batch, cap, src_len, dtype, per_slot)
         return jax.tree.map(lambda a: jnp.broadcast_to(a, (rep,) + a.shape), one)
 
+    if cfg.family == "hybrid":
+        # one SSM state per mamba layer, one K/V cache per shared-block
+        # call (the calls of one block share its weights, not its cache):
+        # (calls, B, Hkv, cap, hybrid_kv_width), the layout the attention
+        # dots read without a copy
+        n = len(cfg.hybrid_ids)
+        shape = (n, batch, cfg.n_kv, cap, hybrid_kv_width(cfg))
+        pos = jnp.zeros((n, batch) if per_slot else (n,), jnp.int32)
+        return {"ssm": stack("mamba")["ssm"],
+                "kv": AttnCache(k=jnp.zeros(shape, dtype),
+                                v=jnp.zeros(shape, dtype), pos=pos)}
     return {
         "stack": tuple(stack(k) for k in pat),
         "tail": tuple(_kind_cache(cfg, k, batch, cap, src_len, dtype, per_slot)
@@ -327,6 +540,11 @@ def init_caches(cfg, batch: int, context: int, *, src_len: int = 0,
 def _embed(params, tokens: Array, cfg) -> Array:
     x = jnp.take(params["embed"], tokens, axis=0).astype(_dt(cfg))
     return constrain(x, ("pod", "data"), None, None)
+
+
+def _final_norm(params, x: Array, cfg) -> Array:
+    eps = HYBRID_EPS if cfg.family == "hybrid" else 1e-6
+    return L.rms_norm(x, params["final_norm"], eps)
 
 
 def _head(params, x: Array, cfg) -> Array:
@@ -382,19 +600,20 @@ def forward(params, tokens: Array, cfg, *, training: bool = False,
         xsrc = img.astype(_dt(cfg))
 
     x = _embed(qparams, tokens, cfg)
+    if cfg.family == "hybrid":
+        x, _ = _hybrid_layers(qparams, x, None, cfg)
+        x = _final_norm(qparams, x, cfg)
+        if last_only:
+            x = x[:, -1:]
+        return _head(qparams, x, cfg), jnp.zeros((), jnp.float32)
     S = tokens.shape[1]
     positions = jnp.arange(S, dtype=jnp.int32)
     pat, rep, tail = expand_pattern(cfg)
 
     def apply_kind(p, x, kind):
-        if kind == "mamba":
-            y, _ = _mamba_block(p, x, cfg, None, False)
-            return y, jnp.zeros((), jnp.float32)
         if kind == "rwkv":
             y, _ = _rwkv_block(p, x, cfg, None, False)
             return y, jnp.zeros((), jnp.float32)
-        if kind == "shared":
-            return _attn_full(qparams["shared"], x, cfg, "full", positions, xsrc)
         return _attn_full(p, x, cfg, kind, positions, xsrc)
 
     def body(carry, p_slices):
@@ -454,25 +673,18 @@ def _step_cached(qparams, x, caches, cfg, *, decode: bool,
 
     def apply_kind(p, x, kind, cache):
         aux0 = jnp.zeros((), jnp.float32)
-        if kind == "mamba":
-            y, st = _mamba_block(p, x, cfg, cache["ssm"], decode)
-            if live is not None:
-                st = _freeze_dead(st, cache["ssm"], live)
-            return y, {"ssm": st}, aux0
         if kind == "rwkv":
             y, st = _rwkv_block(p, x, cfg, cache["rwkv"], decode)
             if live is not None:
                 st = _freeze_dead(st, cache["rwkv"], live)
             return y, {"rwkv": st}, aux0
-        pp = qparams["shared"] if kind == "shared" else p
-        kk = "full" if kind == "shared" else kind
         xc = cache.get("cross")
         if xc is not None and not decode and xsrc is not None:
             # prefill: encode the cross source into the cache once
-            name = "xattn" if kk == "selfcross" else "attn"
-            k, v = L.attn_kv(pp[name], xsrc, cfg)
+            name = "xattn" if kind == "selfcross" else "attn"
+            k, v = L.attn_kv(p[name], xsrc, cfg)
             xc = CrossCache(k=k, v=v)
-        y, ac, xc, aux = _attn_cached(pp, x, cfg, kk, cache.get("attn"), xc,
+        y, ac, xc, aux = _attn_cached(p, x, cfg, kind, cache.get("attn"), xc,
                                       live)
         out = {}
         if ac is not None:
@@ -541,7 +753,8 @@ def prefill(params, tokens: Array, caches: dict, cfg, *,
 
     `n` (traced int32) marks the first n of tokens as real and the tail as
     bucket padding: the returned logits are taken at position n-1 and the
-    attention caches' pos is rewound by the pad count, so a fixed bucket
+    attention caches' pos is rewound by the pad count (a hybrid's mamba
+    layers leave their state as the real tokens left it), so a fixed bucket
     length serves every real chunk length with one jit trace (chunked
     in-slot prefill, DESIGN.md §8)."""
     qparams = _serve_quant(params, cfg)
@@ -551,14 +764,18 @@ def prefill(params, tokens: Array, caches: dict, cfg, *,
     elif cfg.family == "vlm":
         xsrc = img.astype(_dt(cfg))
     x = _embed(qparams, tokens, cfg)
-    x, caches, _ = _step_cached(qparams, x, caches, cfg, decode=False, xsrc=xsrc)
+    if cfg.family == "hybrid":
+        x, caches = _hybrid_layers(qparams, x, caches, cfg, n=n)
+    else:
+        x, caches, _ = _step_cached(qparams, x, caches, cfg, decode=False,
+                                    xsrc=xsrc)
     if n is None:
         x = x[:, -1:]
     else:
         n = jnp.asarray(n, jnp.int32)
         x = jax.lax.dynamic_slice_in_dim(x, n - 1, 1, axis=1)
         caches = _rewind_pad(caches, tokens.shape[1] - n)
-    x = L.rms_norm(x, qparams["final_norm"])
+    x = _final_norm(qparams, x, cfg)
     return _head(qparams, x, cfg)[:, 0], caches
 
 
@@ -597,9 +814,13 @@ def decode_step(params, token: Array, caches: dict, cfg,
         token = token[:, None]
     qparams = _serve_quant(params, cfg)
     x = _embed(qparams, token, cfg)
-    x, caches, _ = _step_cached(qparams, x, caches, cfg, decode=True,
-                                xsrc=None, live=live)
-    x = L.rms_norm(x, qparams["final_norm"])
+    if cfg.family == "hybrid":
+        x, caches = _hybrid_layers(qparams, x, caches, cfg, decode=True,
+                                   live=live)
+    else:
+        x, caches, _ = _step_cached(qparams, x, caches, cfg, decode=True,
+                                    xsrc=None, live=live)
+    x = _final_norm(qparams, x, cfg)
     return _head(qparams, x, cfg)[:, 0], caches
 
 

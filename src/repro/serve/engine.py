@@ -351,8 +351,8 @@ class ServeEngine:
         self.prefill_chunk = int(prefill_chunk)
         # how the runtime lets prompts be split (serve/recurrent.py):
         # 'token' granularity chunks anywhere; 'whole' archs (MoE capacity
-        # competition, rwkv/mamba internal scan chunking) prefill the prompt
-        # as one in-slot chunk.  pad_buckets = padded tails are exact.
+        # competition, rwkv internal scan chunking) prefill the prompt as
+        # one in-slot chunk.  pad_buckets = padded tails are exact.
         self._granularity = getattr(rt, "chunk_granularity", "whole")
         self._pad = bool(getattr(rt, "pad_buckets", False))
 
@@ -971,7 +971,7 @@ class ServeEngine:
 
         # at most ONE prefill chunk per iteration, before the tick
         if self._prefill_q:
-            act0, (sampled, comp, slot) = self._run_chunk(sp)
+            act0, (sampled, comp, slot) = self._run_chunk(rec, sp)
             if sampled:
                 self._gen_tokens += sampled
                 events.append((act0.rid, [act0.tokens[-1]]))
@@ -1035,7 +1035,7 @@ class ServeEngine:
         old = len(self._unread)
         retired = np.zeros(self.n_slots, bool)
         if self._prefill_q:
-            self._run_chunk(sp)
+            self._run_chunk(rec, sp)
         if self._live_host.any():
             self._dispatch_tick(rec, sp, retired)
         self._collect(old, rec, retired)
@@ -1045,12 +1045,15 @@ class ServeEngine:
         self._out_events, self._out_comps, self._out_slot = [], [], {}
         return events, comps
 
-    def _run_chunk(self, sp):
+    def _run_chunk(self, rec: bool, sp):
         """At most ONE prefill chunk per iteration, before the tick."""
         act0 = self._active[self._prefill_q[0]]
         if self._live_host.any():
             self._stall_pending[act0.rid] = \
                 self._stall_pending.get(act0.rid, 0) + 1
+        if rec:
+            chunk, n = act0.chunks[0]
+            telemetry.count("serve.prefill_pad", chunk.size - n)
         with sp("serve.prefill"):
             return act0, self._prefill_step()
 
@@ -1072,8 +1075,14 @@ class ServeEngine:
         what it has dispatched), and its row is scrubbed after the tick."""
         feed = [(int(s), self._active[s])
                 for s in np.flatnonzero(self._live_host)]
-        if rec and any(u.tick for u in self._unread):
-            telemetry.count("serve.ticks_ahead")
+        if rec:
+            if any(u.tick for u in self._unread):
+                telemetry.count("serve.ticks_ahead")
+            # the context each live slot's tick attends over: its prompt
+            # and every token sampled for it, the one this tick feeds
+            # included
+            telemetry.count("serve.live_context", sum(
+                int(act.prompt.size) + act.sampled for _, act in feed))
         with sp("serve.tick"):
             self._pool, self._pending, self._keys = self._tick(
                 self._prm, self._pool, self._pending, self._live,

@@ -190,8 +190,8 @@ class RNNRuntime:
 
 class TransformerRuntime:
     """Transformer-pool serving session — includes the recurrent members
-    (rwkv6-7b, zamba2-1.2b), whose decode steps are `wkv6_step`/`ssd_step`
-    carried inside the cache pytree."""
+    (rwkv6-7b, the zamba2 hybrids), whose decode steps are
+    `wkv6_step`/`ssd_step` carried inside the cache pytree."""
 
     family = "transformer"
 
@@ -203,27 +203,28 @@ class TransformerRuntime:
             lambda p, t, c: T.prefill(p, t, c, cfg, **self.extras))
         self._decode = jax.jit(lambda p, t, c: T.decode_step(p, t, c, cfg))
         # chunked in-slot prefill policy (DESIGN.md §8).  Splitting a prompt
-        # mid-sequence is byte-exact only when every layer's math is
-        # per-token given the cache: recurrent mixers (rwkv/mamba) re-chunk
-        # their internal scans at different boundaries and MoE capacity
-        # competition spans the whole slice, so those archs prefill the
-        # prompt as ONE in-slot chunk.  Bucket PADDING additionally requires
-        # that pad writes land past the rewound pos in a non-ring cache —
-        # sliding-window rings recycle those slots, so they chunk exactly.
+        # mid-sequence is exact when every layer carries its state across
+        # the split: attention through its cache, mamba2 through h and the
+        # conv tail (a bucket-pad token gets dt = 0 and x = 0, so padding
+        # is exact too).  rwkv re-chunks its internal scan at different
+        # boundaries and MoE capacity competition spans the whole slice, so
+        # those archs prefill the prompt as ONE in-slot chunk.  Bucket
+        # PADDING additionally requires that pad writes land past the
+        # rewound pos in a non-ring cache — sliding-window rings recycle
+        # those slots, so they chunk exactly.
         pat, rep, tail = T.expand_pattern(cfg)
         kinds = set(pat) | set(tail)
-        whole = bool(kinds & {"mamba", "rwkv"}) or cfg.n_experts > 0
+        whole = "rwkv" in kinds or cfg.n_experts > 0
         self.chunk_granularity = "whole" if whole else "token"
         self.pad_buckets = (not whole) and not cfg.swa_all and \
             "local" not in kinds
         # speculative decoding needs (a) a multi-token step that is
-        # per-token exact (token granularity: rules out MoE capacity
-        # competition and rwkv/mamba internal scan re-chunking) and (b)
-        # non-ring caches so a rejected suffix can be rolled back without
-        # having recycled in-window history — exactly the pad_buckets
-        # predicate.
+        # per-token exact with (b) non-ring caches, so a rejected suffix
+        # can be rolled back without having recycled in-window history —
+        # the pad_buckets predicate — and (c) no recurrent mixer, whose
+        # state a rejected suffix has already advanced (ROADMAP R4)
         self.spec_capable = self.chunk_granularity == "token" and \
-            self.pad_buckets
+            self.pad_buckets and "mamba" not in kinds
 
     def init_state(self, batch: int, context: int, *,
                    per_slot: bool = False):

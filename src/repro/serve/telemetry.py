@@ -11,7 +11,8 @@ Each span is a `jax.profiler.TraceAnnotation`, so it lands in the profile
 on the device trace's clock, plus an in-memory record kept as totals per
 name: the count, the summed duration and the summed duration of the spans
 opened directly inside it (its self time is the difference).  Counters
-(`count`) are in-memory event counts per name.  Queue waits (submit to
+(`count`) are in-memory sums per name: event counts, or amounts such as
+tokens.  Queue waits (submit to
 admit) are kept per admission, the last `MAX_WAITS` of them.  The record
 is cleared when capture turns on, so it holds one session.
 
@@ -97,9 +98,10 @@ def no_span(name: str):
     return _OFF
 
 
-def count(name: str) -> None:
-    """One event of counter `name` (call only after `active()` said True)."""
-    REC.counts[name] = REC.counts.get(name, 0) + 1
+def count(name: str, n: int = 1) -> None:
+    """Add `n` (one event by default) to counter `name` (call only after
+    `active()` said True)."""
+    REC.counts[name] = REC.counts.get(name, 0) + int(n)
 
 
 def queue_wait(wait_s: float) -> None:

@@ -62,6 +62,7 @@ def test_full_config_dimensions_match_assignment(arch):
         "whisper-base": (6, 512, 8, 8, 2048, 51865),
         "llama-3.2-vision-90b": (100, 8192, 64, 8, 28672, 128256),
         "zamba2-1.2b": (38, 2048, 32, 32, 8192, 32000),
+        "zamba2-7b": (81, 3584, 32, 32, 14336, 32000),
         "qwen3-moe-30b-a3b": (48, 2048, 32, 4, 768, 151936),
         "mixtral-8x7b": (32, 4096, 32, 8, 14336, 32000),
     }[arch]
@@ -107,8 +108,11 @@ def test_pattern_expansion_counts():
     from repro.models.transformer import expand_pattern
     pat, rep, tail = expand_pattern(get_config("gemma3-27b"))
     assert len(pat) == 6 and rep == 10 and len(tail) == 2
-    pat, rep, tail = expand_pattern(get_config("zamba2-1.2b"))
-    assert pat == ("mamba",) * 6 + ("shared",) and rep == 6 and tail == ("mamba",) * 2
+    # a hybrid is its mamba layers; the shared-block calls are extra
+    cfg = get_config("zamba2-1.2b")
+    pat, rep, tail = expand_pattern(cfg)
+    assert pat == ("mamba",) and rep == 38 and tail == ()
+    assert cfg.hybrid_ids == (6, 12, 18, 24, 30, 36) and cfg.n_shared == 1
     pat, rep, tail = expand_pattern(get_config("llama-3.2-vision-90b"))
     assert len(pat) == 5 and rep == 20 and not tail
 

@@ -4,8 +4,9 @@ A request whose prompt prefix is served from the cache — one state-row
 splice instead of re-prefilling — must stream bytes identical to the same
 request cold, which (by the §8 chunked-prefill contract) is identical to
 the sequential oracle.  Proven for the paper's LSTM (packed ternary: the
-snapshot is two (L, H) rows) and for an attention arch (qwen3: narrowed kv
-columns, zero-widened at splice).  Plus the cache's own guarantees: LRU
+snapshot is two (L, H) rows), for an attention arch (qwen3: narrowed kv
+columns, zero-widened at splice) and for the zamba2 hybrid (SSM states
+beside narrowed kv).  Plus the cache's own guarantees: LRU
 eviction under the byte budget, a poisoned-prefix guard (digest match with
 different stored ids is a collision, never a hit), and one-trace splicing.
 """
@@ -46,7 +47,8 @@ def _runtime(family):
             rt = RNNRuntime(cfg, {"params": params, "state": var["state"]})
             _RUNTIMES[family] = (rt, cfg.vocab, None)
         else:
-            cfg = get_config("qwen3-0.6b").reduced()
+            arch = "zamba2-7b" if family == "zamba2" else "qwen3-0.6b"
+            cfg = get_config(arch).reduced()
             params = T.model_init(jax.random.PRNGKey(0), cfg)
             rt = TransformerRuntime(cfg, params)
             _RUNTIMES[family] = (rt, cfg.vocab, CTX)
@@ -152,11 +154,13 @@ def _cached_engine(family, *, slots=2, chunk=4, budget=1 << 24, spec_k=0):
                        draft=draft, spec_k=spec_k), vocab
 
 
-@pytest.mark.parametrize("family", ["lstm-packed", "qwen3"])
+@pytest.mark.parametrize("family", ["lstm-packed", "qwen3", "zamba2"])
 def test_prefix_hit_resume_is_bit_exact(family):
     """Request 1 (cold) populates boundary snapshots; requests sharing its
     prefix splice instead of re-prefilling — and every stream matches the
-    oracle bit for bit, hit or miss."""
+    oracle bit for bit, hit or miss.  For the zamba2 hybrid the snapshot
+    holds every mamba layer's h and conv tail beside the calls' narrowed
+    K/V."""
     eng, vocab = _cached_engine(family)
     rng = np.random.default_rng(5)
     system = rng.integers(0, vocab, size=9).astype(np.int32)  # 2 boundaries

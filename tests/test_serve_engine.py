@@ -347,15 +347,17 @@ def test_transformer_decode_live_mask_freezes_dead_rows():
 
 
 def test_engine_matches_sequential_hybrid_ssm():
-    """zamba2 (mamba + shared attention): 'whole' chunk granularity, and
-    the decode tick's recurrent-state freeze (_freeze_dead) must keep a
-    dead slot's S-matrices / conv tails bit-frozen — with in-slot prefill
-    a dead row can be mid-prefill, so this is load-bearing, not cosmetic."""
+    """zamba2 (mamba + shared attention): 'token' chunk granularity with
+    exact bucket padding, and the decode tick's recurrent-state freeze
+    (_freeze_dead) must keep a dead slot's S-matrices / conv tails / K/V
+    bit-frozen — with in-slot prefill a dead row can be mid-prefill, so
+    this is load-bearing, not cosmetic."""
     from repro.serve.engine import tree_gather_slot
     cfg = get_config("zamba2-1.2b").reduced()
     params = T.model_init(jax.random.PRNGKey(0), cfg)
     rt = TransformerRuntime(cfg, params)
-    assert rt.chunk_granularity == "whole" and not rt.pad_buckets
+    assert rt.chunk_granularity == "token" and rt.pad_buckets
+    assert not rt.spec_capable
 
     # dead-row freeze across EVERY pool leaf (ssm h/conv/pos included)
     st = rt.init_state(2, 16, per_slot=True)
@@ -721,7 +723,10 @@ def test_scripted_run_counts_discards_settles_and_ticks_ahead(tmp_path):
       pool    settle 1: B's first token, tick 2 (B's token dropped: 1)
       step 3  tick 3 feeds A (nothing unread: not ahead); A's quota fills
       cancel  settle 2: tick 3, A's last token; A is done, so None
-      step 4  returns what settle 2 read."""
+      step 4  returns what settle 2 read.
+    The contexts the ticks attend over (prompt + tokens sampled so far)
+    are 4, then 5 + 3, then 6; A's 3-token prompt is padded to a bucket
+    of 4, B's 2 tokens fill theirs."""
     from repro.serve import telemetry
 
     cfg, rt = _rnn_runtime("lstm")
@@ -757,7 +762,9 @@ def test_scripted_run_counts_discards_settles_and_ticks_ahead(tmp_path):
     assert eng.ticks - ticks0 == 3
     assert eng.stats()["discarded_tokens"] - d0 == 1
     assert eng.stats()["settles"] - s0 == 2
-    assert counts == {"serve.ticks_ahead": 1}
+    assert counts == {"serve.ticks_ahead": 1,
+                      "serve.live_context": 4 + (5 + 3) + 6,
+                      "serve.prefill_pad": 1 + 0}
     assert [c.rid for c in comps] == [11, 10]
     assert len(got[10]) == 4 and len(got[11]) == 1
     assert {c.rid: c.tokens for c in comps} == got

@@ -93,6 +93,9 @@ def test_param_counts_sane():
     assert 28e9 < total < 33e9 and 2.5e9 < active < 4.5e9
     total, active = param_counts(get_config("llama-3.2-vision-90b"))
     assert 80e9 < total < 100e9
+    # zamba2-7b holds two shared blocks and runs them at 13 calls a token
+    total, active = param_counts(get_config("zamba2-7b"))
+    assert 7.3e9 < total < 7.4e9 and 10.9e9 < active < 11.1e9
 
 
 def test_model_flops_conventions():
