@@ -1,10 +1,10 @@
-"""Packed-kernel microbenchmark (DESIGN.md §11): accumulation-only GEMV vs
+"""Packed-kernel microbenchmark (DESIGN.md §11): packed GEMV vs
 fp32 dense GEMV across H x B x {binary, ternary}, the analytic weight-bytes
 ratio those shapes move, and launches-per-tick for the paper-LSTM decode tick.
 
 Backend-honest per the dispatch policy (kernels/dispatch.py): on CPU the
 packed number times the jit-compiled XLA lowering of `accumulate_gemv`
-(the same mul-free select/add program the Pallas kernel runs — NEVER
+(the mul-free select/add loop of the fused decode tick — NEVER
 interpret-mode Pallas, which would be thousands of times slower than the
 serving path actually is); on the TPU it times the compiled `packed_gemv`
 launch.  The `path` field records which one was measured.  The bytes ratio

@@ -82,24 +82,29 @@ def resolve_interpret(interpret: Optional[bool] = None) -> bool:
 # trace-time launch counter
 # ---------------------------------------------------------------------------
 
-_launches = 0
+_launches: dict[str, int] = {}
 
 
 def count_launch(name: str) -> None:
     """Called by every pallas_call wrapper in kernels/ at TRACE time, once
-    per launch it emits into the computation being traced.  Like the
-    engine's `tick_traces`, the count is a property of the traced program,
-    not of executions — a jitted tick that traces N launches dispatches N
-    kernels every call thereafter."""
-    del name
-    global _launches
-    _launches += 1
+    per launch it emits into the computation being traced, under the
+    kernel's name (`launch_counts`).  Like the engine's `tick_traces`,
+    the count is a property of the traced program, not of executions — a
+    jitted tick that traces N launches dispatches N kernels every call
+    thereafter."""
+    _launches[name] = _launches.get(name, 0) + 1
 
 
 def launch_count() -> int:
     """Monotonic total of Pallas launches traced so far; callers diff it
     around a trace to count launches-per-tick."""
-    return _launches
+    return sum(_launches.values())
+
+
+def launch_counts() -> dict[str, int]:
+    """Monotonic tally of traced launches per name (a copy); callers diff
+    it around a trace to see which kernels a program holds."""
+    return dict(_launches)
 
 
 def traced_launches(fn, *args, **kwargs) -> int:

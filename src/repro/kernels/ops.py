@@ -36,9 +36,12 @@ def packed_matmul(x: Array, wp: Array, k: int, alpha=1.0, *, mode: str = "ternar
     """y = alpha * (x @ unpack(wp)).  x: (..., K); wp: (K/G, N) uint32.
 
     Leading batch dims are flattened into M; M/N/K padded to block multiples.
-    Decode shapes (M <= 8 rows) route to the accumulation-only GEMV kernel
-    (`packed_gemv` — zero weight-path multiplies); larger M keeps the MXU
-    decode-tile path, which is the right engine for prefill GEMM.
+    The kernel follows the row count M: M <= 8 (decode, and the one-row
+    GEMV of BN-LSTM prefill) takes `packed_gemv`, which decodes each pair of
+    code planes once for every row and contracts it on the MXU; M > 8 takes
+    the decode-tile GEMM `packed_matmul`, the prefill path.  The paper's
+    zero-multiply loop (`packed_matmul.accumulate_planes`) runs in the fused
+    decode tick (`fused_decode_tick`).
     """
     group = PK.group_of(mode)
     lead = x.shape[:-1]
@@ -51,7 +54,7 @@ def packed_matmul(x: Array, wp: Array, k: int, alpha=1.0, *, mode: str = "ternar
         # K padded to whole sublane tiles of packed rows (8 words per slot
         # plane); pad activations are zero, so pad codes add nothing
         kp = -(-max(K, wp.shape[0] * group) // (8 * group)) * (8 * group)
-        xm = jnp.pad(xm.astype(jnp.float32), ((0, -M % 8), (0, kp - K)))
+        xm = jnp.pad(xm.astype(jnp.float32), ((0, 0), (0, kp - K)))
         wpp = jnp.pad(wp, ((0, kp // group - wp.shape[0]), (0, -N % 128)))
         y = PK.packed_gemv(xm, wpp, kp, mode=mode, interpret=interpret)
     else:

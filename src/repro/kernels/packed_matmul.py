@@ -53,7 +53,8 @@ def slot_major(x: Array, group: int) -> Array:
     """x (B, K) -> (G, K/G, B): entry [j, r, b] is x[b, r*G + j], the
     activation that code slot j of packed row r selects.  Contiguous packing
     (core/quantize.py) puts weights r*G .. r*G+G-1 in word r; this layout
-    hands the GEMV each slot's activations as one sublane-major plane."""
+    hands the accumulation loop each slot's activations as one
+    sublane-major plane."""
     b, k = x.shape
     return x.reshape(b, k // group, group).transpose(2, 1, 0)
 
@@ -114,46 +115,117 @@ def accumulate_gemv(x: Array, packed: Array, *, mode: str) -> Array:
     return accumulate_planes(xs, packed, mode=mode)
 
 
-def _gemv_kernel(xs_ref, wp_ref, o_ref, *, mode: str):
-    o_ref[...] = accumulate_planes(xs_ref, wp_ref[...], mode=mode)
+# rows of the MXU GEMV's activation operand: three bfloat16 parts of 8 rows,
+# padded to two whole (16, 128) bfloat16 tiles
+MXU_ROWS = 32
+
+
+def bf16_parts(x: Array) -> list:
+    """float32 x as three bfloat16 parts, each the remainder of the ones
+    before; their sum is x exactly (8 significand bits a part), so their
+    products with +-1/0 weights are exact and float32 sums of them give
+    x @ w in float32.  Each part is cut by a bit mask, not rounded by a
+    cast: outside a kernel the TPU compiler may keep a float32 value through
+    a float32 -> bfloat16 -> float32 round trip (excess precision), and with
+    casts the remainders came out zero on a v5e, as one bfloat16 pass."""
+    parts = []
+    for _ in range(3):
+        bits = jax.lax.bitcast_convert_type(x, jnp.uint32)
+        hi = jax.lax.bitcast_convert_type(bits & jnp.uint32(0xFFFF0000),
+                                          jnp.float32)
+        parts.append(hi.astype(jnp.bfloat16))    # exact: 16 low bits zero
+        x = x - hi                                # exact
+    return parts
+
+
+def pair_major(x: Array, group: int) -> Array:
+    """x (B, K) -> (B, K) with columns in the row order of the GEMV's
+    decoded tile: pair p (slots p and p + G/2) takes rows p*2R .. p*2R+2R-1,
+    and row p*2R + 2r + h of it is the weight of code slot p + h*G/2 of
+    packed row r, whose activation is x[:, r*G + h*G/2 + p]."""
+    b, k = x.shape
+    half = group // 2
+    return x.reshape(b, k // group, 2, half).transpose(0, 3, 1, 2).reshape(b, k)
+
+
+def _pair_plane(words: Array, p: int, mode: str) -> Array:
+    """(R, bn) uint32 words -> (2R, bn) bfloat16 holding 2*w for code slots
+    p (row 2r) and p + G/2 (row 2r + 1) of word r: +-2 or 0, exact.
+
+    One shift and one mask decode two weights: the two slots' codes land on
+    the top bits of the word's low and high 16-bit halves, which are then
+    read as two bfloat16 numbers (2.0 is 0x4000, -2.0 is 0xC000: a single
+    exponent bit and the sign).  Ternary code 1 (+1) becomes 0x4000, code 3
+    (-1) 0xC000, code 0 zero; a binary bit of 1 becomes 0x4000, of 0 0xC000."""
+    if mode == "ternary":
+        t = (words << jnp.uint32(14 - 2 * p)) & jnp.uint32(0xC000C000)
+    else:
+        t = (((words << jnp.uint32(15 - p)) & jnp.uint32(0x80008000))
+             ^ jnp.uint32(0xC000C000))
+    return pltpu.bitcast(t, jnp.bfloat16)
+
+
+def _gemv_kernel(xt_ref, wp_ref, o_ref, w_ref, *, mode: str):
+    words = wp_ref[...]                                  # (R, bn) uint32
+    rows2 = 2 * words.shape[0]
+    for p in range(group_of(mode) // 2):
+        w_ref[p * rows2:(p + 1) * rows2, :] = _pair_plane(words, p, mode)
+    acc = jnp.dot(xt_ref[...], w_ref[...],
+                  preferred_element_type=jnp.float32)    # (MXU_ROWS, bn)
+    # the three parts' row groups, and the decoded weights' factor of 2
+    o_ref[...] = (acc[0:8] + acc[8:16] + acc[16:24]) * 0.5
 
 
 def packed_gemv(x: Array, wp: Array, k: int, *, mode: str,
                 block_n: int = 128, interpret: bool | None = None) -> Array:
-    """Accumulation-only decode-shape matmul: x (Bp, K) with Bp <= 8, packed
-    wp (K/G, N) -> (Bp, N) fp32, one launch, grid over N tiles.
+    """Decode-shape matmul against packed codes: x (M, K) with M <= 8 rows,
+    packed wp (K/G, N) -> (M, N) fp32, one launch named
+    `<mode>_packed_gemv`, grid over N tiles.  K must cover whole 8-row tiles
+    of packed words (`ops.packed_matmul` pads it).
 
-    This is the mul-free sibling of `packed_matmul`: where the MXU path
-    decodes codes to ±1 floats and feeds a dense dot (right for prefill
-    GEMM, M large), this kernel selects/accumulates activations through the
-    code masks — the arithmetic the paper's ASIC does.  `ops.packed_matmul`
-    routes M <= 8 here and larger M to the MXU path.  x goes in slot-major
-    (`slot_major`, an XLA transpose of a few KB), so the kernel reads each
-    slot's activations as a sublane plane."""
+    Inside each N tile the codes decode once, whatever the row count: each
+    pair of code planes, with one shift and one mask, to exact +-2/0
+    bfloat16 (`_pair_plane`), into a (K, bn) VMEM tile that one dot
+    contracts on the MXU.  x, padded to 8 rows, goes in as its three
+    bfloat16 parts (`bf16_parts`) stacked as 24 rows of one operand in
+    `pair_major` column order, so every product is exact and the tile
+    passes the MXU once; the float32 sums of the three row groups add up to
+    the float32 result.  The paper's select-and-add loop
+    (`accumulate_planes`) pays its vector work once per row, padding rows
+    included, so it is slower here even at one row (DESIGN.md §11); the
+    fused decode tick keeps it.  The decoded tile holds all of K: the
+    widest contraction of the repo's configurations, K = 28,672, compiles
+    for a v5e (tests/test_tpu_compile.py)."""
     group = group_of(mode)
-    bp, K = x.shape
+    m, K = x.shape
     N = wp.shape[1]
+    if m > 8:
+        raise ValueError(f"packed_gemv takes at most 8 rows, got {m}")
     if K != k or wp.shape[0] * group != K:
         raise ValueError(f"packed K mismatch: {wp.shape[0]}*{group} != {K}")
     if N % block_n:
         raise ValueError(f"N={N} must be a multiple of block_n={block_n}")
+    if wp.shape[0] % 8:
+        raise ValueError(f"packed rows {wp.shape[0]} must be a multiple "
+                         "of 8 (whole bfloat16 tiles once decoded)")
     interpret = dispatch.resolve_interpret(interpret)
-    xs = slot_major(x.astype(jnp.float32), group)
-
-    kernel = functools.partial(_gemv_kernel, mode=mode)
-    dispatch.count_launch(f"{mode}_packed_gemv")
-    return pl.pallas_call(
-        kernel,
+    x8 = jnp.pad(x.astype(jnp.float32), ((0, 8 - m), (0, 0)))
+    xt = pair_major(jnp.concatenate(bf16_parts(x8)), group)    # (24, K)
+    xt = jnp.pad(xt, ((0, MXU_ROWS - xt.shape[0]), (0, 0)))
+    name = f"{mode}_packed_gemv"
+    dispatch.count_launch(name)
+    y = pl.pallas_call(
+        functools.partial(_gemv_kernel, mode=mode),
         grid=(N // block_n,),
-        in_specs=[
-            pl.BlockSpec(xs.shape, lambda j: (0, 0, 0)),
-            pl.BlockSpec((K // group, block_n), lambda j: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((bp, block_n), lambda j: (0, j)),
-        out_shape=jax.ShapeDtypeStruct((bp, N), jnp.float32),
+        in_specs=[pl.BlockSpec(xt.shape, lambda j: (0, 0)),
+                  pl.BlockSpec((K // group, block_n), lambda j: (0, j))],
+        out_specs=pl.BlockSpec((8, block_n), lambda j: (0, j)),
+        out_shape=jax.ShapeDtypeStruct((8, N), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((K, block_n), jnp.bfloat16)],
         interpret=interpret,
-        name=f"{mode}_packed_gemv",
-    )(xs, wp)
+        name=name,
+    )(xt, wp)
+    return y[:m]
 
 
 def _matmul_kernel(x_ref, wp_ref, o_ref, acc_ref, *, bk: int, mode: str):
@@ -164,18 +236,10 @@ def _matmul_kernel(x_ref, wp_ref, o_ref, acc_ref, *, bk: int, mode: str):
     unpack = _unpack_ternary_tile if mode == "ternary" else _unpack_binary_tile
     w = unpack(wp_ref[...], bk).astype(jnp.bfloat16)   # +-1/0: exact
     x = x_ref[...]
-    if x.dtype == jnp.float32:
-        # float32 activations in float32: x is the sum of three bfloat16
-        # parts (8 significand bits each), every product with a +-1/0
-        # weight is exact, and the sums accumulate in float32 — where one
-        # bfloat16 pass would round x to 8 bits
-        parts = []
-        for _ in range(3):
-            hi = x.astype(jnp.bfloat16)
-            parts.append(hi)
-            x = x - hi.astype(jnp.float32)
-    else:
-        parts = [x.astype(jnp.bfloat16)]
+    # float32 activations in float32, where one bfloat16 pass would round x
+    # to 8 significand bits
+    parts = (bf16_parts(x) if x.dtype == jnp.float32
+             else [x.astype(jnp.bfloat16)])
     for xp in parts:
         acc_ref[...] += jnp.dot(xp, w, preferred_element_type=jnp.float32)
 

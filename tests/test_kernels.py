@@ -5,13 +5,19 @@ import numpy as np
 import pytest
 
 from repro.core import quantize as Q
-from repro.kernels import ops, ref
+from repro.kernels import dispatch, ops, ref
 
 
 @pytest.mark.parametrize("mode", ["ternary", "binary"])
 @pytest.mark.parametrize("mkn", [(4, 256, 384), (128, 512, 512),
-                                 (1, 1024, 256), (67, 320, 136), (8, 64, 8)])
+                                 (1, 1024, 256), (67, 320, 136), (8, 64, 8),
+                                 (2, 320, 136), (5, 480, 200), (8, 1056, 264)])
 def test_packed_matmul_matches_ref(mode, mkn):
+    """Both kernels `ops.packed_matmul` routes to (the GEMV at 1-8 rows, the
+    GEMM above) against the plain reference, and float32 to float32
+    accuracy: with activations spread over 1e-3 .. 1e3 it agrees to 1e-5 of
+    its largest output, where the same reference fed one bfloat16 pass of
+    the activations does not."""
     M, K, N = mkn
     kw, kx, ku = jax.random.split(jax.random.PRNGKey(M * K + N), 3)
     w = jax.random.normal(kw, (K, N)) * 0.02
@@ -24,10 +30,52 @@ def test_packed_matmul_matches_ref(mode, mkn):
 
     x = jax.random.normal(kx, (M, K), jnp.float32)
     y = ops.packed_matmul(x, wp, K, alpha, mode=mode)
-    y_ref = (ref.ternary_matmul_ref if mode == "ternary"
-             else ref.binary_matmul_ref)(x, wp, K, alpha)
+    matmul_ref = (ref.ternary_matmul_ref if mode == "ternary"
+                  else ref.binary_matmul_ref)
+    y_ref = matmul_ref(x, wp, K, alpha)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
                                rtol=1e-5, atol=1e-5)
+
+    spread = jax.random.uniform(jax.random.fold_in(kx, 1), (M, K),
+                                minval=np.log(1e-3), maxval=np.log(1e3))
+    xw = x * jnp.exp(spread)
+    yw_ref = np.asarray(matmul_ref(xw, wp, K, alpha))
+    tol = dict(rtol=1e-5, atol=1e-5 * np.abs(yw_ref).max())
+    np.testing.assert_allclose(
+        np.asarray(ops.packed_matmul(xw, wp, K, alpha, mode=mode)), yw_ref,
+        **tol)
+    one_pass = matmul_ref(xw.astype(jnp.bfloat16).astype(jnp.float32), wp, K,
+                          alpha)
+    assert not np.allclose(np.asarray(one_pass), yw_ref, **tol)
+
+
+@pytest.mark.parametrize("mode", ["ternary", "binary"])
+@pytest.mark.parametrize("M", range(1, 10))
+def test_packed_matmul_kernel_follows_rows(mode, M):
+    """`ops.packed_matmul` makes one launch: `<mode>_packed_gemv` at 1-8
+    rows, `<mode>_packed_matmul` above, as `dispatch.launch_counts`
+    tallies it and the jaxpr names it."""
+    K, N = 352, 264                  # shapes no other test traces
+    x = jnp.ones((M, K), jnp.float32)
+    group = Q.TERNARY_GROUP if mode == "ternary" else Q.BINARY_GROUP
+    wp = jnp.zeros((K // group, N), jnp.uint32)
+    before = dispatch.launch_counts()
+    closed = jax.make_jaxpr(lambda x, w: ops.packed_matmul(
+        x, w, K, mode=mode, interpret=True))(x, wp)
+    after = dispatch.launch_counts()
+    traced = {k: n - before.get(k, 0) for k, n in after.items()
+              if n != before.get(k, 0)}
+    kernel = f"{mode}_packed_gemv" if M <= 8 else f"{mode}_packed_matmul"
+    assert traced == {kernel: 1}
+
+    def names(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn.params["name"]
+            for v in eqn.params.values():
+                if hasattr(v, "jaxpr"):
+                    yield from names(getattr(v.jaxpr, "jaxpr", v.jaxpr))
+    assert list(names(closed.jaxpr)) == [kernel]
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

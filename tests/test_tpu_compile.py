@@ -9,7 +9,9 @@ and assert the compiled program holds the Pallas kernel (`tpu_custom_call`).
 Shapes: the paper's BN-LSTM (`rnn-paper` = char-PTB H=1000 vocab 50, whose
 head fits the fused tick; word-PTB large, 2 x H=1500 vocab 10k, whose head
 runs as an XLA dot beside it), the fused tick at the in-kernel head's VMEM
-budget, the packed GEMV and MXU GEMM at prefill shapes, and the qwen3-0.6b
+budget, the packed GEMV and MXU GEMM at prefill shapes, the 8-row GEMV at
+every zamba2-7b width and at the widest contraction of the configurations
+(llama-3.2-vision-90b's down projection), in both modes, and the qwen3-0.6b
 projections through `qmatmul` at decode (M <= 8) and prefill (M = 256).
 
 The topology is described inside a fixture, never at import: describing
@@ -114,8 +116,9 @@ def test_fused_tick_compiles(one_chip, config, mode):
 @pytest.mark.parametrize("mode", MODES)
 @pytest.mark.parametrize("M", [1, 8])
 def test_packed_gemv_compiles(one_chip, mode, M):
-    """The decode GEMV (M <= 8) of `rnn-paper`'s h-side: (H, 4H), with x
-    zero-padded to the codes' K coverage as `qmatmul` does."""
+    """The GEMV of `rnn-paper`'s h-side, (H, 4H), at one row (prefill) and
+    at 8 (decode), with x zero-padded to the codes' K coverage as `qmatmul`
+    does."""
     H = rnn_paper.char_ptb().d_hidden
     kg = -(-H // GROUP[mode])
     K = kg * GROUP[mode]
@@ -178,8 +181,8 @@ def _zamba2_7b_matrices():
                                  "adapter_out", "linear"])
 def test_zamba2_7b_matrix_compiles(one_chip, mat, M):
     """zamba2-7b's ternary matrices through `qmatmul` at their published
-    widths, float32 activations: the accumulation-only GEMV of a decode
-    tick over 8 slots, and the MXU GEMM of a 128-token prefill chunk."""
+    widths, float32 activations: the GEMV (MXU body) of a decode tick over
+    8 slots, and the MXU GEMM of a 128-token prefill chunk."""
     K, N = _zamba2_7b_matrices()[mat]
     S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     qt = QTensor(codes=S((K // 16, N), jnp.uint32), scale=S((N,), jnp.float32),
@@ -188,11 +191,42 @@ def test_zamba2_7b_matrix_compiles(one_chip, mat, M):
              S((M, K), jnp.float32), qt)
 
 
+@pytest.mark.parametrize("mode", MODES)
+@pytest.mark.parametrize("kn", [(3584, 14704), (7168, 3584), (7168, 7168),
+                                (14336, 3584), (3584, 28672), (128, 28672),
+                                (3584, 128)])
+def test_zamba2_7b_gemv_compiles(one_chip, kn, mode):
+    """The 8-row GEMV of a zamba2-7b decode tick at every packed width of the model, in both modes, float32 activations:
+    the rank-128 adapters contract only 8 (ternary) or 4 (binary) packed
+    rows, padded to 8."""
+    K, N = kn
+    kg = _up(K, GROUP[mode]) // GROUP[mode]
+    x = jax.ShapeDtypeStruct((8, K), jnp.float32, sharding=one_chip)
+    wp = jax.ShapeDtypeStruct((kg, N), jnp.uint32, sharding=one_chip)
+    _compile(lambda x, wp: ops.packed_matmul(x, wp, K, 0.05, mode=mode,
+                                             interpret=False), x, wp)
+
+
+@pytest.mark.parametrize("mode", MODES)
+def test_widest_gemv_compiles(one_chip, mode):
+    """The 8-row GEMV at the widest contraction of the configurations,
+    llama-3.2-vision-90b's down projection (d_ff, d_model): the GEMV decodes
+    all of K into one VMEM tile, so its VMEM grows with K."""
+    cfg = get_config("llama-3.2-vision-90b")
+    K, N = cfg.d_ff, cfg.d_model
+    x = jax.ShapeDtypeStruct((8, K), jnp.float32, sharding=one_chip)
+    wp = jax.ShapeDtypeStruct((K // GROUP[mode], N), jnp.uint32,
+                              sharding=one_chip)
+    _compile(lambda x, wp: ops.packed_matmul(x, wp, K, 0.05, mode=mode,
+                                             interpret=False), x, wp)
+
+
 def test_zamba2_7b_decode_tick_compiles_in_place(one_chip, monkeypatch):
     """The whole zamba2-7b decode tick at its published widths, 8 slots at
     1,024 tokens of context, packed ternary, as the engine jits it (pool
-    donated): it fits a v5e's HBM, and it updates the SSM states and the
-    13 calls' K/V caches in place — no copy of a 3.4 GB cache stack, so
+    donated): every packed matmul is an 8-row GEMV, the tick fits
+    a v5e's HBM, and it updates the SSM states and the 13 calls' K/V
+    caches in place — no copy of a 3.4 GB cache stack, so
     next to the arguments it needs only small temporaries."""
     import dataclasses
     from repro.core.qtensor import export_packed
@@ -216,9 +250,14 @@ def test_zamba2_7b_decode_tick_compiles_in_place(one_chip, monkeypatch):
         logits, pool = T.decode_step(prm, pending, pool, cfg, live=live)
         return pool, jnp.argmax(logits, -1)
 
+    jax.clear_caches()               # so every packed matmul traces anew
+    before = dispatch.launch_counts()
     compiled = jax.jit(tick, donate_argnums=(1,)).lower(
         prm, pool, S((8,), jnp.int32), S((8,), jnp.bool_)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    traced = {k for k, n in dispatch.launch_counts().items()
+              if n != before.get(k, 0)}
+    assert traced == {"ternary_packed_gemv"}
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 64 * 2**20
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.5e9
