@@ -1,61 +1,19 @@
-"""Benchmark harness — one entry per paper table/figure.
+"""Paper-table harness — one entry per paper table/figure, trained as
+reduced reproductions on the CPU (no speed records: those come from the
+chip benchmark, `bench/`).
 
   PYTHONPATH=src python -m benchmarks.run            # all tables
   PYTHONPATH=src python -m benchmarks.run --quick    # smoke pass
-  PYTHONPATH=src python -m benchmarks.run --only table1_char_lm roofline
+  PYTHONPATH=src python -m benchmarks.run --only table1_char_lm train_rnn
 
 Prints a compact CSV per table and writes results/benchmarks/*.json.
 """
 from __future__ import annotations
 
 import argparse
-import json
 import time
-from pathlib import Path
 
 from benchmarks import tables as T
-from benchmarks.common import REPO, RESULTS
-
-
-def roofline_report(quick=False):
-    """Aggregate results/dryrun/*.json into the §Roofline table."""
-    outdir = REPO / "results" / "dryrun"
-    rows = []
-    for p in sorted(outdir.glob("*.json")) if outdir.exists() else []:
-        c = json.loads(p.read_text())
-        if c["status"] == "ok":
-            r = c["roofline"]
-            rows.append({
-                "arch": c["arch"], "shape": c["shape"], "mesh": c["mesh"],
-                "t_compute_s": f"{r['t_compute_s']:.3e}",
-                "t_memory_s": f"{r['t_memory_s']:.3e}",
-                "t_collective_s": f"{r['t_collective_s']:.3e}",
-                "dominant": r["dominant"],
-                "useful_flop_ratio": round(r["useful_flop_ratio"], 3),
-                "roofline_fraction": round(r["roofline_fraction"], 4),
-            })
-        elif c["status"] == "skipped":
-            rows.append({"arch": c["arch"], "shape": c["shape"],
-                         "mesh": c["mesh"], "dominant": "N/A",
-                         "note": c["reason"][:60]})
-    RESULTS.mkdir(parents=True, exist_ok=True)
-    (RESULTS / "roofline_report.json").write_text(json.dumps(rows, indent=1))
-    return rows
-
-
-def _serve_decode(quick=False):
-    from benchmarks.serve_decode import serve_decode
-    return serve_decode(quick=quick)
-
-
-def _serve_engine(quick=False):
-    from benchmarks.serve_engine import serve_engine
-    return serve_engine(quick=quick)
-
-
-def _packed_kernels(quick=False):
-    from benchmarks.packed_kernels import packed_kernels
-    return packed_kernels(quick=quick)
 
 
 def _train_rnn(quick=False):
@@ -64,9 +22,6 @@ def _train_rnn(quick=False):
 
 
 BENCHES = {
-    "packed_kernels": _packed_kernels,
-    "serve_decode": _serve_decode,
-    "serve_engine": _serve_engine,
     "train_rnn": _train_rnn,
     "table1_char_lm": T.table1_char_lm,
     "table1b_convergence": T.table1b_convergence,
@@ -79,7 +34,6 @@ BENCHES = {
     "fig1b_variance": T.fig1b_stochastic_variance,
     "fig2_generalization": T.fig2_generalization,
     "fig3_batch_size": T.fig3_batch_size,
-    "roofline": roofline_report,
 }
 
 

@@ -33,15 +33,21 @@ completions, and `cancel(rid)` retires an in-flight request mid-stream —
 mid-prefill or mid-decode — through the same batched scrub retirement
 uses, so a hung-up client leaks nothing into the slot's next occupant.
 
-The plain path reads the device one iteration late (DESIGN.md §10): an
-iteration dispatches tick k+1 before it reads tick k's sampled ids (and a
-new first token is read the iteration after it samples), so the host's
-bookkeeping overlaps the device's next tick instead of waiting for it.
-Tick k+1 does not need tick k's ids on the host — they are its device-side
-input already.  A request stopped by EOS has been fed to the tick in
-flight; that token is dropped when read.  `pool` and `cancel()` settle the
-outstanding read first, so outside readers see state that matches the
-tokens recorded.
+Every iteration is the same loop body: admit, run at most one chunk (its
+first token queued as an unread read), dispatch the tick or the speculative
+round (its ids queued too), collect, scrub.  Reads are recorded in one
+place (`_collect`): the same end-of-request truncation, one event per
+request per step, the same completion and retirement.  The plain path
+reads the device one iteration late (DESIGN.md §10): an iteration
+dispatches tick k+1 before it reads tick k's sampled ids (and a new first
+token is read the iteration after it samples), so the host's bookkeeping
+overlaps the device's next tick instead of waiting for it.  Tick k+1 does
+not need tick k's ids on the host — they are its device-side input
+already.  A request stopped by EOS has been fed to the tick in flight;
+that token is dropped when read.  A speculative round is read in its own
+iteration (the read-depth rule is in `_spec_round`).  `pool` and
+`cancel()` settle the outstanding read first, so outside readers see state
+that matches the tokens recorded.
 `run()` is a thin loop over submit/step (byte-identical to the pre-step-API
 batch driver); the asyncio front door (serve/frontdoor.py) drives step()
 from an event loop while requests arrive and die asynchronously.
@@ -58,15 +64,21 @@ carries its own temperature / top-k / PRNG key chain, and a slot's draws are
 bit-identical to running that request alone through `drive_session` — the
 engine changes the schedule, not the tokens.
 
+The engine holds its runtimes as a tuple of LANES: the target, then the
+speculative draft when there is one.  Parameter trees, batch-1 templates,
+slot writers, pools and (under a mesh) their shardings are tuples in the
+same order, and each slot-surgery region (chunk prefill, scrub, prefix
+gather and splice) is ONE jitted program mapped over the lanes, so the
+draft is prefilled, scrubbed and spliced in lockstep with the target.
+
 Speculative decoding (DESIGN.md §9) replaces the tick with a
-draft-verify-accept round: a packed binary/ternary DRAFT runtime (its own
-slot pool, prefilled and scrubbed in lockstep) proposes `spec_k` tokens per
-live slot, the target verifies them all in one multi-token step, and
-rejection sampling commits each slot's accepted prefix — the output
-distribution is exactly the target's, byte-identical to plain decoding at
-temperature 0.  Rollback of rejected suffixes reuses the slot surgery:
-per-step state SELECT for RNN families, KV suffix byte-restore + pos
-rewind for attention.
+draft-verify-accept round: the packed binary/ternary DRAFT proposes
+`spec_k` tokens per live slot, the target verifies them all in one
+multi-token step, and rejection sampling commits each slot's accepted
+prefix — the output distribution is exactly the target's, byte-identical
+to plain decoding at temperature 0.  Rollback of rejected suffixes reuses
+the slot surgery: per-step state SELECT for RNN families, KV suffix
+byte-restore + pos rewind for attention.
 
 Every jitted region takes the runtime's parameter tree as an ARGUMENT
 (`rt.jit_prm`) instead of closing over it: closed-over weights get
@@ -163,12 +175,14 @@ class _Active:
 
 @dataclasses.dataclass
 class _Unread:
-    """Sampled ids dispatched and not yet read (the plain path): a tick's
-    (B,) ids, or an admission's first token (a scalar), with the slots and
-    requests they were sampled for, as they stood at dispatch."""
+    """Sampled ids dispatched and not yet read, with the slots and requests
+    they were sampled for, as they stood at dispatch.  `kind` says what the
+    ids are: "first", an admission's first token (a scalar); "tick", a
+    tick's (B,) ids; "round", a speculative round's (B, spec_k+2) output,
+    each slot's emitted ids with their count in the last column."""
     ids: Array
     feed: List[Tuple[int, _Active]]
-    tick: bool
+    kind: str
 
 
 # ---------------------------------------------------------------------------
@@ -371,29 +385,25 @@ class ServeEngine:
                     "a spliced prefix byte-identical to re-prefilling it")
             prefix_cache.bind(self.prefill_chunk)
 
-        self._pool = rt.init_state(self.n_slots, self.max_context,
-                                   per_slot=True)
-        # batch-1 template: fixes the slot axis of every pool leaf for the
-        # gather/reset surgery (shapes only — no arrays are materialized)
-        self._ref = jax.eval_shape(
-            lambda: rt.init_state(1, self.max_context, per_slot=True))
-        # the parameter trees every jitted region takes as ARGUMENTS (see
-        # module docstring: closing over them constant-folds the weights)
-        self._prm = rt.jit_prm
-        # speculative mode (DESIGN.md §9): the packed draft runs its OWN
-        # slot pool in lockstep with the target's — admission prefills
-        # both, retirement scrubs both, and the spec tick rolls both back
-        # to the accepted prefix
-        self.draft = draft
+        # the lanes (module docstring): the target, then the speculative
+        # draft (DESIGN.md §9), whose own slot pool runs in lockstep with
+        # the target's — admission prefills both, retirement scrubs both,
+        # and the spec round rolls both back to the accepted prefix
         self.spec_k = int(spec_k)
         self.spec = draft is not None
-        if self.spec:
-            self.draft_pool = draft.init_state(self.n_slots,
-                                               self.max_context,
-                                               per_slot=True)
-            self._dref = jax.eval_shape(
-                lambda: draft.init_state(1, self.max_context, per_slot=True))
-            self._dprm = draft.jit_prm
+        self._rts = (rt,) if draft is None else (rt, draft)
+        self._pools = tuple(r.init_state(self.n_slots, self.max_context,
+                                         per_slot=True) for r in self._rts)
+        # batch-1 templates: fix the slot axis of every pool leaf for the
+        # gather/reset surgery (shapes only — no arrays are materialized)
+        self._refs = tuple(jax.eval_shape(
+            lambda r=r: r.init_state(1, self.max_context, per_slot=True))
+            for r in self._rts)
+        # the parameter trees every jitted region takes as ARGUMENTS (see
+        # module docstring: closing over them constant-folds the weights)
+        self._prms = tuple(r.jit_prm for r in self._rts)
+        self._writes = tuple(getattr(r, "write_slots", tree_write_slot)
+                             for r in self._rts)
         B = self.n_slots
         self._pending = jnp.zeros((B,), jnp.int32)   # next token to feed
         self._live = jnp.zeros((B,), bool)
@@ -460,17 +470,15 @@ class ServeEngine:
         # exactly as it does per device.
         self.mesh = mesh
         self._data_shards = 1
-        self._pool_sh = self._dpool_sh = None
-        self._sub_sh = self._dsub_sh = None
-        self._prm_sh = self._dprm_sh = None
+        self._pool_shs = self._sub_shs = self._prm_shs = (None,) * len(
+            self._rts)
         self._vec_sh = self._row_sh = self._rep = None
         if mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
             from repro.kernels import dispatch
             from repro.launch.sharding import (batch_shardings,
                                                serve_pool_shardings)
-            if dispatch.packed_pallas_active(
-                    (self._prm, self._dprm if self.spec else None)):
+            if dispatch.packed_pallas_active(self._prms):
                 raise NotImplementedError(
                     "mesh-sharded serving of packed trees runs through the "
                     "compiled dense fallback (CPU) — the packed Pallas "
@@ -487,25 +495,19 @@ class ServeEngine:
             self._rep = NamedSharding(mesh, P())
             self._vec_sh, self._row_sh = batch_shardings(
                 (self._pending, self._keys), mesh)
-            self._pool_sh = serve_pool_shardings(self._pool, self._ref, mesh)
-            self._sub_sh = jax.tree.map(lambda _: self._rep, self._pool_sh)
-            self._prm_sh = rt.serve_prm_shardings(mesh)
-            self._pool = jax.device_put(self._pool, self._pool_sh)
-            self._prm = jax.device_put(self._prm, self._prm_sh)
+            self._pool_shs = tuple(
+                serve_pool_shardings(p, r, mesh)
+                for p, r in zip(self._pools, self._refs))
+            self._sub_shs = jax.tree.map(lambda _: self._rep, self._pool_shs)
+            self._prm_shs = tuple(r.serve_prm_shardings(mesh)
+                                  for r in self._rts)
+            self._pools = jax.device_put(self._pools, self._pool_shs)
+            self._prms = jax.device_put(self._prms, self._prm_shs)
             self._pending = jax.device_put(self._pending, self._vec_sh)
             self._live = jax.device_put(self._live, self._vec_sh)
             self._keys = jax.device_put(self._keys, self._row_sh)
             self._temp = jax.device_put(self._temp, self._vec_sh)
             self._topk = jax.device_put(self._topk, self._vec_sh)
-            if self.spec:
-                self._dpool_sh = serve_pool_shardings(
-                    self.draft_pool, self._dref, mesh)
-                self._dsub_sh = jax.tree.map(lambda _: self._rep,
-                                             self._dpool_sh)
-                self._dprm_sh = draft.serve_prm_shardings(mesh)
-                self.draft_pool = jax.device_put(self.draft_pool,
-                                                 self._dpool_sh)
-                self._dprm = jax.device_put(self._dprm, self._dprm_sh)
 
         def _mjit(fn, in_sh=None, out_sh=None, donate=()):
             # sharding-annotated jit for the mesh-native engine; the
@@ -540,12 +542,16 @@ class ServeEngine:
         # The prm tree is NEVER donated: the same arrays are passed every
         # call.  Nor is `pending`: the host reads a tick's sampled ids one
         # iteration late, after the next tick has taken them as input.
+        # The plain tick takes the target lane alone, not the tuple.
         self._tick = _mjit(
             tick,
-            in_sh=(self._prm_sh, self._pool_sh, self._vec_sh, self._vec_sh,
-                   self._row_sh, self._vec_sh, self._vec_sh),
-            out_sh=(self._pool_sh, self._vec_sh, self._row_sh),
+            in_sh=(self._prm_shs[0], self._pool_shs[0], self._vec_sh,
+                   self._vec_sh, self._row_sh, self._vec_sh, self._vec_sh),
+            out_sh=(self._pool_shs[0], self._vec_sh, self._row_sh),
             donate=(1, 4))
+        self._lower_tick = lambda: self._tick.lower(
+            self._prms[0], self._pools[0], self._pending, self._live,
+            self._keys, self._temp, self._topk)
 
         def admit_commit(logits, key, t, k, pending, keys, temp, topk, live,
                          slot):
@@ -569,32 +575,38 @@ class ServeEngine:
             out_sh=(R, self._vec_sh, self._row_sh, self._vec_sh,
                     self._vec_sh, self._vec_sh))
 
-        write = rt.write_slots if hasattr(rt, "write_slots") else tree_write_slot
-
-        def prefill_slot(prm, pool, tokens, n, slot):
+        def prefill_slot(prms, pools, tokens, n, slot):
             # in-slot chunked prefill: the slot row IS the session state.
-            # Retraces once per bucket length (tokens' static shape); slot
-            # and n are traced, so one trace serves every admission.
+            # Every lane runs the same chunk in this one region — a draft
+            # must carry the same prompt state as the target or its
+            # proposals start from nowhere.  Retraces once per bucket
+            # length (tokens' static shape); slot and n are traced, so one
+            # trace serves every admission.  The logits are the target's.
             self.prefill_traces += 1
-            sub = tree_gather_slot(pool, self._ref, slot)
-            logits, sub = rt.prefill_chunk(tokens, sub, n, prm=prm)
-            return logits, write(pool, sub, slot)
+            outs = []
+            for r, prm, pool, ref, write in zip(self._rts, prms, pools,
+                                                self._refs, self._writes):
+                sub = tree_gather_slot(pool, ref, slot)
+                logits, sub = r.prefill_chunk(tokens, sub, n, prm=prm)
+                outs.append((logits, write(pool, sub, slot)))
+            return outs[0][0], tuple(p for _, p in outs)
 
         self._prefill_slot = _mjit(
             prefill_slot,
-            in_sh=(self._prm_sh, self._pool_sh, R, R, R),
-            out_sh=(R, self._pool_sh),
+            in_sh=(self._prm_shs, self._pool_shs, R, R, R),
+            out_sh=(R, self._pool_shs),
             donate=(1,))
         # retire-time slot scrub, shape-aware: recurrent leaves + positions
         # to zero, attention KV masked in place, the device live bit
         # cleared — the freed row must read as fresh because the next
         # prefill resumes from it
         self._reset = _mjit(
-            lambda pool, live, mask: (
-                tree_reset_slots(pool, self._ref, mask),
+            lambda pools, live, mask: (
+                tuple(tree_reset_slots(p, r, mask)
+                      for p, r in zip(pools, self._refs)),
                 jnp.where(mask, False, live)),
-            in_sh=(self._pool_sh, self._vec_sh, self._vec_sh),
-            out_sh=(self._pool_sh, self._vec_sh),
+            in_sh=(self._pool_shs, self._vec_sh, self._vec_sh),
+            out_sh=(self._pool_shs, self._vec_sh),
             donate=(0,))
 
         if self.prefix_cache is not None:
@@ -603,25 +615,27 @@ class ServeEngine:
             # row shape outside jit), so it traces exactly once; the gather
             # reads the slot row for snapshotting without donating the pool.
             self._gather = _mjit(
-                lambda pool, slot: tree_gather_slot(pool, self._ref, slot),
-                in_sh=(self._pool_sh, R), out_sh=self._sub_sh)
+                lambda pools, slot: tuple(
+                    tree_gather_slot(p, r, slot)
+                    for p, r in zip(pools, self._refs)),
+                in_sh=(self._pool_shs, R), out_sh=self._sub_shs)
 
-            def splice(pool, sub, slot):
+            def splice(pools, subs, slot):
                 self.splice_traces += 1
-                return write(pool, sub, slot)
+                return tuple(w(p, s, slot)
+                             for w, p, s in zip(self._writes, pools, subs))
 
             self._splice = _mjit(
-                splice, in_sh=(self._pool_sh, self._sub_sh, R),
-                out_sh=self._pool_sh, donate=(0,))
+                splice, in_sh=(self._pool_shs, self._sub_shs, R),
+                out_sh=self._pool_shs, donate=(0,))
 
-        if not self.spec:
+        if draft is None:
             return
 
         # -- speculative mode: draft k, verify k+1, accept, commit ----------
         K = self.spec_k
 
-        def spec_tick(prm, dprm, pool, dpool, pending, live, keys, temp,
-                      topk):
+        def spec_tick(prms, pools, pending, live, keys, temp, topk):
             """One draft-verify-accept round over ALL live slots, jitted as
             a unit (traces exactly once — asserted like the plain tick):
 
@@ -635,7 +649,7 @@ class ServeEngine:
               3. `spec_accept` runs the rejection rule per slot: the
                  output distribution is exactly the target's, and at
                  temperature 0 the emitted bytes are plain greedy decode;
-              4. both pools COMMIT to each slot's accepted prefix:
+              4. both lanes COMMIT to each slot's accepted prefix:
                  per-step-state select for RNN families, KV suffix
                  restore + pos rewind for attention (the PR 3/4 slot
                  surgery, turned into a rollback primitive).
@@ -646,6 +660,7 @@ class ServeEngine:
             pending/key chains never advance."""
             self.spec_traces += 1
             from repro.kernels import dispatch
+            (prm, dprm), (pool, dpool) = prms, pools
             ks = jax.vmap(jax.random.split)(keys)          # (B, 2, 2)
             rk = ks[:, 1]
             new_keys = jnp.where(live[:, None], ks[:, 0], keys)
@@ -690,60 +705,18 @@ class ServeEngine:
             # accepted count in the last column (a second small transfer
             # costs as much as the whole verify at reduced scale)
             packed = jnp.concatenate([out, n_acc[:, None]], axis=1)
-            return pool, dpool, pending, new_keys, packed
+            return (pool, dpool), pending, new_keys, packed
 
         self._spec_tick = _mjit(
             spec_tick,
-            in_sh=(self._prm_sh, self._dprm_sh, self._pool_sh,
-                   self._dpool_sh, self._vec_sh, self._vec_sh, self._row_sh,
-                   self._vec_sh, self._vec_sh),
-            out_sh=(self._pool_sh, self._dpool_sh, self._vec_sh,
-                    self._row_sh, self._row_sh),
-            donate=(2, 3, 4, 6))
-
-        dwrite = (draft.write_slots if hasattr(draft, "write_slots")
-                  else tree_write_slot)
-
-        def spec_prefill_slot(prm, dprm, pool, dpool, tokens, n, slot):
-            # same in-slot chunk as the plain path, run against BOTH pools
-            # in one jitted region — the draft must carry the same prompt
-            # state as the target or its proposals start from nowhere.
-            # Trace-bounded by the same bucket set (one counter).
-            self.prefill_traces += 1
-            sub = tree_gather_slot(pool, self._ref, slot)
-            logits, sub = rt.prefill_chunk(tokens, sub, n, prm=prm)
-            dsub = tree_gather_slot(dpool, self._dref, slot)
-            _, dsub = draft.prefill_chunk(tokens, dsub, n, prm=dprm)
-            return (logits, write(pool, sub, slot),
-                    dwrite(dpool, dsub, slot))
-
-        self._spec_prefill_slot = _mjit(
-            spec_prefill_slot,
-            in_sh=(self._prm_sh, self._dprm_sh, self._pool_sh,
-                   self._dpool_sh, R, R, R),
-            out_sh=(R, self._pool_sh, self._dpool_sh),
-            donate=(2, 3))
-        self._spec_reset = _mjit(
-            lambda pool, dpool, live, mask: (
-                tree_reset_slots(pool, self._ref, mask),
-                tree_reset_slots(dpool, self._dref, mask),
-                jnp.where(mask, False, live)),
-            in_sh=(self._pool_sh, self._dpool_sh, self._vec_sh,
-                   self._vec_sh),
-            out_sh=(self._pool_sh, self._dpool_sh, self._vec_sh),
-            donate=(0, 1))
-
-        if self.prefix_cache is not None:
-            self._dgather = _mjit(
-                lambda pool, slot: tree_gather_slot(pool, self._dref, slot),
-                in_sh=(self._dpool_sh, R), out_sh=self._dsub_sh)
-
-            def dsplice(dpool, dsub, slot):
-                return dwrite(dpool, dsub, slot)
-
-            self._dsplice = _mjit(
-                dsplice, in_sh=(self._dpool_sh, self._dsub_sh, R),
-                out_sh=self._dpool_sh, donate=(0,))
+            in_sh=(self._prm_shs, self._pool_shs, self._vec_sh, self._vec_sh,
+                   self._row_sh, self._vec_sh, self._vec_sh),
+            out_sh=(self._pool_shs, self._vec_sh, self._row_sh,
+                    self._row_sh),
+            donate=(1, 2, 4))
+        self._lower_tick = lambda: self._spec_tick.lower(
+            self._prms, self._pools, self._pending, self._live, self._keys,
+            self._temp, self._topk)
 
     # -- the settled view ---------------------------------------------------
 
@@ -753,7 +726,7 @@ class ServeEngine:
         first (`_settle`), so every active slot's row has consumed exactly
         its prompt and the tokens recorded for it but the last."""
         self._settle()
-        return self._pool
+        return self._pools[0]
 
     # -- clock --------------------------------------------------------------
 
@@ -819,15 +792,9 @@ class ServeEngine:
         --traffic launcher and the benchmark so both measure the same
         warmed serving path."""
         for Lb in self.declared_buckets(prompt_lens):
-            if self.spec:
-                _, self._pool, self.draft_pool = self._spec_prefill_slot(
-                    self._prm, self._dprm, self._pool, self.draft_pool,
-                    jnp.zeros((1, Lb), jnp.int32), jnp.int32(Lb),
-                    jnp.int32(0))
-            else:
-                _, self._pool = self._prefill_slot(
-                    self._prm, self._pool, jnp.zeros((1, Lb), jnp.int32),
-                    jnp.int32(Lb), jnp.int32(0))
+            _, self._pools = self._prefill_slot(
+                self._prms, self._pools, jnp.zeros((1, Lb), jnp.int32),
+                jnp.int32(Lb), jnp.int32(0))
         # the warm prefills ran junk through slot 0 — scrub it so the pool
         # is indistinguishable from fresh before any real admission.  (They
         # ran OUTSIDE _prefill_step, so no junk prefix was offered to the
@@ -962,90 +929,27 @@ class ServeEngine:
             if rec:
                 telemetry.queue_wait(now - t_sub)
             self._admit(req, rid, slot, now, t_sub)
-        if not self.spec:
-            return self._step_plain(rec, sp)
-
-        retired = np.zeros(self.n_slots, bool)
-        events: List[Tuple[int, List[int]]] = []
-        comps: List[Completion] = []
-
-        # at most ONE prefill chunk per iteration, before the tick
-        if self._prefill_q:
-            act0, (sampled, comp, slot) = self._run_chunk(rec, sp)
-            if sampled:
-                self._gen_tokens += sampled
-                events.append((act0.rid, [act0.tokens[-1]]))
-            if comp is not None:
-                comps.append(comp)
-                retired[slot] = True
-
-        if not self._live_host.any():
-            if retired.any():
-                self._scrub(retired)
-            return events, comps
-
-        with sp("serve.tick"):
-            (self._pool, self.draft_pool, self._pending, self._keys,
-             spec_out) = self._spec_tick(
-                self._prm, self._dprm, self._pool, self.draft_pool,
-                self._pending, self._live, self._keys, self._temp,
-                self._topk)
-        self._count_tick()
-
-        # one small device->host transfer per round: the scheduler needs
-        # the sampled ids to detect EOS / quota and to free slots.  A spec
-        # round emits a VARIABLE number of tokens per slot (accepted
-        # prefix + one); truncate at EOS / quota — the overshoot the
-        # verify consumed dies with the slot scrub
-        now = self._now()
-        out_host = self._read_ids(spec_out, rec)
-        for slot in np.flatnonzero(self._live_host):
-            act = self._active[slot]
-            take = int(out_host[slot, -1])
-            self._drafted += self.spec_k
-            self._accepted += max(take - 1, 0)
-            emitted: List[int] = []
-            done = False
-            for j in range(take):
-                tok = int(out_host[slot, j])
-                act.tokens.append(tok)
-                emitted.append(tok)
-                self._gen_tokens += 1
-                hit_eos = (self.eos_id is not None
-                           and act.tokens[-1] == self.eos_id)
-                if hit_eos or len(act.tokens) >= act.req.max_tokens:
-                    done = True
-                    break
-            events.append((act.rid, emitted))
-            if done:
-                comps.append(self._completion(act, int(slot), now))
-                self._retire(int(slot))
-                retired[slot] = True
-        if retired.any():
-            # scrub the freed slots in ONE batched shape-aware reset:
-            # the next occupant prefills IN the slot, so it must read
-            # exactly like a fresh one
-            self._scrub(retired)
-        return events, comps
-
-    def _step_plain(self, rec: bool, sp):
-        """The plain iteration, with deferred reads: at most one prefill
-        chunk, then tick k+1, then the reads the earlier iterations left
-        (tick k's ids, first tokens), then the scrub of what retired."""
         old = len(self._unread)
         retired = np.zeros(self.n_slots, bool)
         if self._prefill_q:
             self._run_chunk(rec, sp)
-        if self._live_host.any():
-            self._dispatch_tick(rec, sp, retired)
-        self._collect(old, rec, retired)
+        if self.spec:
+            self._spec_round(rec, sp, retired)  # reads all it needs itself
+        else:
+            if self._live_host.any():
+                self._dispatch_tick(rec, sp, retired)
+            # the reads earlier iterations left
+            self._collect(old, rec, retired)
         if retired.any():
+            # scrub the freed slots in ONE batched shape-aware reset: the
+            # next occupant prefills IN the slot, so it must read exactly
+            # like a fresh one
             self._scrub(retired)
         events, comps = self._out_events, self._out_comps
         self._out_events, self._out_comps, self._out_slot = [], [], {}
         return events, comps
 
-    def _run_chunk(self, rec: bool, sp):
+    def _run_chunk(self, rec: bool, sp) -> None:
         """At most ONE prefill chunk per iteration, before the tick."""
         act0 = self._active[self._prefill_q[0]]
         if self._live_host.any():
@@ -1055,7 +959,7 @@ class ServeEngine:
             chunk, n = act0.chunks[0]
             telemetry.count("serve.prefill_pad", chunk.size - n)
         with sp("serve.prefill"):
-            return act0, self._prefill_step()
+            self._prefill_step()
 
     def _count_tick(self) -> None:
         self.ticks += 1
@@ -1076,7 +980,7 @@ class ServeEngine:
         feed = [(int(s), self._active[s])
                 for s in np.flatnonzero(self._live_host)]
         if rec:
-            if any(u.tick for u in self._unread):
+            if any(u.kind == "tick" for u in self._unread):
                 telemetry.count("serve.ticks_ahead")
             # the context each live slot's tick attends over: its prompt
             # and every token sampled for it, the one this tick feeds
@@ -1084,11 +988,12 @@ class ServeEngine:
             telemetry.count("serve.live_context", sum(
                 int(act.prompt.size) + act.sampled for _, act in feed))
         with sp("serve.tick"):
-            self._pool, self._pending, self._keys = self._tick(
-                self._prm, self._pool, self._pending, self._live,
+            pool, self._pending, self._keys = self._tick(
+                self._prms[0], self._pools[0], self._pending, self._live,
                 self._keys, self._temp, self._topk)
+        self._pools = (pool,)  # a plain engine has the target lane only
         self._pending.copy_to_host_async()
-        self._unread.append(_Unread(self._pending, feed, tick=True))
+        self._unread.append(_Unread(self._pending, feed, "tick"))
         self._count_tick()
         for slot, act in feed:
             act.sampled += 1
@@ -1096,35 +1001,85 @@ class ServeEngine:
                 self._retire(slot)
                 retired[slot] = True
 
+    def _spec_round(self, rec: bool, sp, retired: np.ndarray) -> None:
+        """Dispatch a draft-verify-accept round over the live slots and read
+        it in the same iteration.
+
+        The read depth is decided here, by the mode: a speculative engine
+        first reads everything outstanding (a first token may end its
+        request), then reads the round it dispatches.  A deferred round
+        would run a second round past a quota the host has not yet seen:
+        up to 2*(spec_k+1) cache writes past it, more than the attention
+        caches' DECODE_MARGIN (128) slack at spec_k = 64.  So
+        `serve.ticks_ahead` stays 0 here."""
+        self._collect(len(self._unread), rec, retired)
+        if not self._live_host.any():
+            return
+        feed = [(int(s), self._active[s])
+                for s in np.flatnonzero(self._live_host)]
+        with sp("serve.tick"):
+            self._pools, self._pending, self._keys, out = self._spec_tick(
+                self._prms, self._pools, self._pending, self._live,
+                self._keys, self._temp, self._topk)
+        self._count_tick()
+        self._unread.append(_Unread(out, feed, "round"))
+        self._collect(1, rec, retired)
+
     def _collect(self, n: int, rec: bool, retired: np.ndarray) -> None:
         """Read the `n` oldest outstanding reads, in dispatch order, and
         record their tokens: events and completions for the next return
         of step().  A token sampled after its request ended (EOS, or a
         quota of one filled at admission) is dropped; a request that EOS
-        stops while it still holds its slot retires into `retired`."""
+        stops while it still holds its slot retires into `retired`.  A
+        round's ids past its request's end are cut off (the slot scrub
+        takes what the verify wrote for them)."""
         for _ in range(n):
             u = self._unread.popleft()
-            ids = self._read_ids(u.ids, rec) if u.tick else np.asarray(u.ids)
+            if u.kind == "round":
+                # each slot's `take` emitted ids, take in the last column
+                # (the accepted drafts, plus the target's own)
+                rows = [r[:r[-1]]
+                        for r in self._read_ids(u.ids, rec).tolist()]
+                self._drafted += self.spec_k * len(u.feed)
+                self._accepted += sum(max(len(rows[s]) - 1, 0)
+                                      for s, _ in u.feed)
+                now = self._now()
+                for slot, act in u.feed:
+                    for tok in rows[slot]:
+                        if self._record(slot, act, tok, now, retired):
+                            break
+                continue
+            if u.kind == "tick":
+                ids = self._read_ids(u.ids, rec).tolist()
+            else:  # "first": one slot's first token
+                ids = {u.feed[0][0]: int(np.asarray(u.ids))}
             now = self._now()
             for slot, act in u.feed:
-                if self._finished(act):
-                    self._discarded += 1
-                    continue
-                tok = int(ids[slot] if u.tick else ids)
-                act.tokens.append(tok)
-                if act.t_first is None:
-                    act.t_first = now
-                self._gen_tokens += 1
-                out = self._out_slot.get(slot)
-                if out is None or out[0] is not act:
-                    out = self._out_slot[slot] = (act, [])
-                    self._out_events.append((act.rid, out[1]))
-                out[1].append(tok)
-                if self._finished(act):
-                    self._out_comps.append(self._completion(act, slot, now))
-                    if self._active[slot] is act:
-                        self._retire(slot)
-                        retired[slot] = True
+                self._record(slot, act, ids[slot], now, retired)
+
+    def _record(self, slot: int, act: _Active, tok: int, now: float,
+                retired: np.ndarray) -> bool:
+        """Record one token of `act` for step()'s return; True once its
+        request has ended (then the token is dropped, or it was the last)."""
+        if self._finished(act):
+            self._discarded += 1
+            return True
+        act.tokens.append(tok)
+        if act.t_first is None:
+            act.t_first = now
+        self._gen_tokens += 1
+        out = self._out_slot.get(slot)
+        if out is None or out[0] is not act:
+            out = self._out_slot[slot] = (act, [])
+            self._out_events.append((act.rid, out[1]))
+        out[1].append(tok)
+        if not self._finished(act):
+            return False
+        self._out_comps.append(self._completion(act, slot, now))
+        if self._active[slot] is act:
+            self._retire(slot)
+            retired[slot] = True
+        return True
 
     def _finished(self, act: _Active) -> bool:
         return bool(act.tokens) and (
@@ -1169,10 +1124,12 @@ class ServeEngine:
         cached = 0
         if self.prefix_cache is not None:
             cached, entry = self.prefix_cache.lookup(prompt)
-            if entry is not None and self.spec and entry.draft_state is None:
-                cached, entry = 0, None  # stored by a non-spec engine: no
-            if entry is not None:        # draft half to keep in lockstep
-                self._splice_entry(entry, slot)
+            if entry is not None:
+                states = (entry.state, entry.draft_state)[:len(self._rts)]
+                if any(s is None for s in states):
+                    cached = 0  # stored by a non-spec engine: no draft
+                else:           # half to keep in lockstep
+                    self._splice_entry(states, slot)
         chunks: Deque[Tuple[np.ndarray, int]] = deque()
         off = cached
         for Lb, n in self._chunk_plan(prompt.size - cached):
@@ -1186,20 +1143,16 @@ class ServeEngine:
             off=cached, cached=cached)
         self._prefill_q.append(slot)
 
-    def _splice_entry(self, entry, slot: int) -> None:
-        """Copy a cached prefix state into the slot row: widen narrowed
-        attention leaves back to pool capacity (zero tail — masked exactly
-        like the stale bytes retirement leaves), then ONE full-row write —
-        for the RNN family that is the two (L, H) row copies
-        `rnn_write_slots` was built from."""
+    def _splice_entry(self, states, slot: int) -> None:
+        """Copy a cached prefix state (one per lane) into the slot row:
+        widen narrowed attention leaves back to pool capacity (zero tail —
+        masked exactly like the stale bytes retirement leaves), then ONE
+        full-row write per lane in one region — for the RNN family that is
+        the two (L, H) row copies `rnn_write_slots` was built from."""
         from repro.serve.prefixcache import widen_state
 
-        sub = widen_state(entry.state, self._ref)
-        self._pool = self._splice(self._pool, sub, jnp.int32(slot))
-        if self.spec:
-            dsub = widen_state(entry.draft_state, self._dref)
-            self.draft_pool = self._dsplice(self.draft_pool, dsub,
-                                            jnp.int32(slot))
+        subs = tuple(widen_state(s, r) for s, r in zip(states, self._refs))
+        self._pools = self._splice(self._pools, subs, jnp.int32(slot))
 
     def _offer_snapshot(self, slot: int, act: _Active) -> None:
         """Offer the slot's carried state to the prefix cache at the
@@ -1210,41 +1163,30 @@ class ServeEngine:
         prefix = act.prompt[:act.off]
         if self.prefix_cache.contains(prefix):
             return
-        sub = narrow_state(self._gather(self._pool, jnp.int32(slot)), act.off)
-        dsub = None
-        if self.spec:
-            dsub = narrow_state(
-                self._dgather(self.draft_pool, jnp.int32(slot)), act.off)
-        self.prefix_cache.insert(prefix, sub, dsub)
+        subs = self._gather(self._pools, jnp.int32(slot))
+        self.prefix_cache.insert(
+            prefix, *(narrow_state(s, act.off) for s in subs))
 
-    def _prefill_step(self):
+    def _prefill_step(self) -> None:
         """Run ONE chunk of the oldest prefilling slot.  When the last
-        chunk lands, sample the request's first token and turn the slot
-        live.  The plain path queues the token's read for the next
-        iteration (`t_first` is stamped when it is read) and returns
-        (0, None, None).  The speculative path reads it at once, stamps
-        `t_first`, and — max_tokens == 1 / EOS on the first token —
-        completes the request here.  Returns (n_sampled, completion,
-        retired_slot)."""
+        chunk lands, sample the request's first token, turn the slot live
+        and queue the token's read (`t_first` is stamped when it is read;
+        a first token that ends its request — max_tokens == 1, EOS —
+        completes it there)."""
         slot = self._prefill_q[0]
         act = self._active[slot]
         chunk, n = act.chunks.popleft()
         # the batch axis is added on the host: an eager `[None]` on the
         # device would build one program per chunk shape that warm() missed
-        if self.spec:
-            logits, self._pool, self.draft_pool = self._spec_prefill_slot(
-                self._prm, self._dprm, self._pool, self.draft_pool,
-                jnp.asarray(chunk[None]), jnp.int32(n), jnp.int32(slot))
-        else:
-            logits, self._pool = self._prefill_slot(
-                self._prm, self._pool, jnp.asarray(chunk[None]), jnp.int32(n),
-                jnp.int32(slot))
+        logits, self._pools = self._prefill_slot(
+            self._prms, self._pools, jnp.asarray(chunk[None]), jnp.int32(n),
+            jnp.int32(slot))
         act.off += n
         if (self.prefix_cache is not None and n == self.prefill_chunk
                 and act.off % self.prefill_chunk == 0):
             self._offer_snapshot(slot, act)
         if act.chunks:
-            return 0, None, None
+            return
         self._prefill_q.popleft()
         req = act.req
         (tok0, self._pending, self._keys, self._temp, self._topk,
@@ -1253,27 +1195,12 @@ class ServeEngine:
             jnp.float32(req.temperature), jnp.int32(req.top_k),
             self._pending, self._keys, self._temp, self._topk, self._live,
             jnp.int32(slot))
-        if not self.spec:
-            # the device set the slot live in `admit_commit`: the host
-            # follows at once, and reads the token with the next reads
-            tok0.copy_to_host_async()
-            act.sampled = 1
-            self._unread.append(_Unread(tok0, [(slot, act)], tick=False))
-            self._live_host[slot] = True
-            return 0, None, None
-        act.tokens.append(int(tok0))
-        act.t_first = self._now()
-        if (req.max_tokens <= 1
-                or (self.eos_id is not None and act.tokens[0] == self.eos_id)):
-            # completed at admission: the device-side live bit was set by
-            # the fused commit, and the caller's retired-mask scrub clears
-            # it this same scheduler iteration (_live_host stays False, so
-            # no host path ever reads the slot as live)
-            comp = self._completion(act, slot, act.t_first)
-            self._active[slot] = None
-            return 1, comp, slot
+        # the device set the slot live in `admit_commit`: the host
+        # follows at once, and reads the token with the next reads
+        tok0.copy_to_host_async()
+        act.sampled = 1
+        self._unread.append(_Unread(tok0, [(slot, act)], "first"))
         self._live_host[slot] = True
-        return 1, None, None
 
     def _completion(self, act: _Active, slot: int, now: float,
                     finished: Optional[str] = None) -> Completion:
@@ -1296,15 +1223,11 @@ class ServeEngine:
         self._live_host[slot] = False
 
     def _scrub(self, retired: np.ndarray) -> None:
-        """Batched shape-aware reset of the freed slots — state rows, the
-        device live mask, and the draft pool's matching rows in speculative
-        mode (the next occupant prefills into BOTH pools)."""
-        m = jnp.asarray(retired)
-        if self.spec:
-            self._pool, self.draft_pool, self._live = self._spec_reset(
-                self._pool, self.draft_pool, self._live, m)
-        else:
-            self._pool, self._live = self._reset(self._pool, self._live, m)
+        """Batched shape-aware reset of the freed slots — every lane's
+        state rows (the next occupant prefills into all of them) and the
+        device live mask."""
+        self._pools, self._live = self._reset(self._pools, self._live,
+                                              jnp.asarray(retired))
 
     # -- stats (the front door's /v1/stats) ---------------------------------
 
@@ -1363,16 +1286,7 @@ class ServeEngine:
         t, l = self.tick_traces, self.tick_launches
         s, dl = self.spec_traces, self.draft_launches
         try:
-            if self.spec:
-                low = self._spec_tick.lower(
-                    self._prm, self._dprm, self._pool, self.draft_pool,
-                    self._pending, self._live, self._keys, self._temp,
-                    self._topk)
-            else:
-                low = self._tick.lower(
-                    self._prm, self._pool, self._pending, self._live,
-                    self._keys, self._temp, self._topk)
-            return low.compile().as_text()
+            return self._lower_tick().compile().as_text()
         finally:
             self.tick_traces, self.tick_launches = t, l
             self.spec_traces, self.draft_launches = s, dl

@@ -22,9 +22,9 @@ paper's BN-LSTM/BN-GRU, RWKV6, Mamba2, and the attention families:
     runs `wkv6_step` / `ssd_step`; attention layers hold KV caches in the
     same slots.  The runtime treats both identically.
 
-The launcher (`launch/serve.py`), the `serve_decode` benchmark and the
-serving tests all drive this interface, so every arch exercises the same
-prefill → sample → decode loop.
+The launcher (`launch/serve.py`), the serve engine and the serving tests
+all drive this interface, so every arch exercises the same prefill →
+sample → decode loop.
 """
 from __future__ import annotations
 
@@ -371,13 +371,10 @@ def speculative_draft(rt, mode: str = "ternary",
 
 def drive_session(rt, prompt: Array, vocab: int, *, gen: int,
                   temperature: float = 0.8, top_k: int = 0, seed: int = 0,
-                  warmup: bool = False, context: Optional[int] = None):
-    """The canonical prefill -> sample -> decode session, timed.
-
-    One implementation drives the launcher AND the serve_decode benchmark,
-    so the benchmark measures exactly the loop production runs.  With
-    `warmup` an untimed prefill + decode step runs first, so the recorded
-    tok/s measures the serving path rather than jit tracing/compilation.
+                  context: Optional[int] = None):
+    """The canonical prefill -> sample -> decode session, timed (the
+    launcher's lockstep mode, and the sequential oracle the engine tests
+    compare against).
 
     `context` overrides the provisioned context length (default: exactly
     S + gen).  The engine parity tests pass the engine pool's max_context so
@@ -387,21 +384,9 @@ def drive_session(rt, prompt: Array, vocab: int, *, gen: int,
     seconds, tok/s, and the per-session state bytes)."""
     B, S = prompt.shape
     context = context or (S + gen)
-    if warmup:
-        # warmup owns its OWN state; the timed run below starts from a fresh
-        # init_state, so warmup can never leak a prefilled state (or retain
-        # st_w's memory) into the measurement
-        st_w = rt.init_state(B, context)
-        lg_w, st_w = rt.prefill(prompt, st_w)
-        nxt_w = sample(lg_w, jax.random.PRNGKey(0), temperature=temperature,
-                       top_k=top_k, vocab=vocab)
-        jax.block_until_ready(rt.decode_step(nxt_w, st_w)[0])
-        del lg_w, st_w, nxt_w
-
     state = rt.init_state(B, context)
     # clean-state invariant: every position counter of a fresh state is 0
-    # (the float leaves are zeros by construction; pos is what warmup could
-    # plausibly have threaded through)
+    # (the float leaves are zeros by construction)
     assert all(int(jnp.sum(l)) == 0
                for l in jax.tree_util.tree_leaves(state)
                if hasattr(l, "dtype") and jnp.issubdtype(l.dtype, jnp.integer))

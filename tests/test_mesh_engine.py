@@ -229,7 +229,7 @@ else:
         jax.debug's sharding inspection sees the data axis on every
         slot-bearing leaf from INSIDE a jitted computation."""
         eng = _engine("lstm-packed", "data=4")
-        expect = serve_pool_shardings(eng.pool, eng._ref, eng.mesh)
+        expect = serve_pool_shardings(eng.pool, eng._refs[0], eng.mesh)
         for leaf, sh in zip(jax.tree_util.tree_leaves(eng.pool),
                             jax.tree_util.tree_leaves(
                                 expect, is_leaf=lambda x: hasattr(x, "spec"))):
@@ -268,7 +268,7 @@ else:
         comps, _ = eng.run(_scenario_requests(301, vocab), realtime=False)
         assert comps and eng.tick_traces == 1
         qleaves = [l for l in jax.tree_util.tree_leaves(
-            eng._prm, is_leaf=is_qtensor) if is_qtensor(l)]
+            eng._prms[0], is_leaf=is_qtensor) if is_qtensor(l)]
         assert qleaves
         specs = [str(q.codes.sharding.spec) for q in qleaves]
         assert any("model" in s for s in specs), specs

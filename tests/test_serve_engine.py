@@ -595,7 +595,7 @@ def test_params_are_jit_arguments_not_baked_constants():
     c1, _ = eng.run([dataclasses.replace(req)], realtime=False)
     traces = (eng.tick_traces, eng.prefill_traces)
     assert traces[0] == 1
-    eng._prm = rt2.jit_prm  # same treedef/avals, different weights
+    eng._prms = (rt2.jit_prm,)  # same treedef/avals, different weights
     c2, _ = eng.run([dataclasses.replace(req)], realtime=False)
     assert (eng.tick_traces, eng.prefill_traces) == traces, \
         "swapping the param ARGUMENT must not retrace anything"
@@ -700,7 +700,7 @@ def test_cancel_with_a_tick_in_flight_returns_settled_tokens():
     while len(got) < 3:
         for _, toks in eng.step()[0]:
             got.extend(toks)
-    assert any(u.tick for u in eng._unread)   # a tick is in flight
+    assert any(u.kind == "tick" for u in eng._unread)  # a tick in flight
     comp = eng.cancel(0)
     assert comp.finished == "cancelled" and eng.stats()["settles"] == 1
     assert comp.tokens == got + [comp.tokens[-1]]

@@ -30,6 +30,7 @@ from repro.core.quantize import QuantSpec
 from repro.models import transformer as T
 from repro.serve.engine import Request, ServeEngine
 from repro.serve.kvcache import cache_spec_commit, cache_spec_snapshot
+from repro.serve.prefixcache import PrefixCache
 from repro.serve.recurrent import (RNNRuntime, TransformerRuntime,
                                    speculative_draft)
 from repro.serve.sampler import (filtered_probs, residual_probs, sample_slots,
@@ -352,18 +353,89 @@ def test_speculative_draft_requires_fp_masters():
 
 
 def test_spec_engine_warm_then_run_traces_nothing_new():
+    """Both lanes share each slot-surgery region: a speculative engine warms
+    exactly the prefill traces a plain engine over the same runtime does
+    (one per declared bucket), and cancel/admit/retire churn — with a
+    prefix cache splicing both lanes in one row copy — retraces nothing."""
     cfg, rt = _rnn_runtime()
     draft = speculative_draft(rt, mode="ternary")
     eng = ServeEngine(rt, cfg.vocab, slots=2, max_context=64,
-                      prefill_chunk=4, draft=draft, spec_k=2)
+                      prefill_chunk=4, draft=draft, spec_k=2,
+                      prefix_cache=PrefixCache(1 << 24))
     eng.warm()
     pt, st = eng.prefill_traces, eng.spec_traces
     assert st == 1 and pt == len(eng.declared_buckets())
+    plain = ServeEngine(rt, cfg.vocab, slots=2, max_context=64,
+                        prefill_chunk=4)
+    plain.warm()
+    assert plain.prefill_traces == pt, "the draft lane traced its own prefill"
     rng = np.random.default_rng(11)
-    reqs = [Request(prompt=rng.integers(0, cfg.vocab, size=int(rng.integers(1, 13))),
+    system = rng.integers(0, cfg.vocab, size=8)
+    reqs = [Request(prompt=np.concatenate(
+                        [system, rng.integers(0, cfg.vocab,
+                                              size=int(rng.integers(1, 5)))]),
                     max_tokens=int(rng.integers(1, 8)), temperature=0.0,
                     top_k=0, seed=300 + i, rid=i) for i in range(5)]
     comps, m = eng.run(reqs, realtime=False)
     assert len(comps) == len(reqs)
+    # churn: cancel one request mid-prefill and one mid-decode, admit more
+    for i in range(5, 9):
+        eng.submit(dataclasses.replace(reqs[i - 5], rid=i, max_tokens=7))
+    eng.step()
+    assert eng._prefill_q, "the second admission's chunk has not run yet"
+    prefilling = eng._active[eng._prefill_q[0]].rid
+    assert eng.cancel(prefilling).finished == "cancelled"
+    while not eng._live_host.any():
+        eng.step()
+    decoding = next(a.rid for s, a in enumerate(eng._active)
+                    if eng._live_host[s])
+    assert eng.cancel(decoding).finished == "cancelled"
+    while eng.has_work():
+        eng.step()
+    assert eng.stats()["prefix_cache"]["hits"] > 0
     assert eng.prefill_traces == pt, "a prompt length traced a new prefill"
     assert eng.spec_traces == 1, "occupancy churn retraced the spec tick"
+    assert eng.splice_traces == 1, "a splice retraced the lanes' row copy"
+
+
+@pytest.mark.parametrize("max_tokens", [(12,), (12, 1, 7)])
+def test_spec_step_returns_one_event_per_request(max_tokens):
+    """A speculative step in which a request's last prompt chunk lands runs
+    a round over it too: the first token and the round's ids are one event
+    for that request, not two, and the concatenated events are exactly
+    run()'s completion (a quota of one ends the request at its first
+    token)."""
+    cfg, rt = _rnn_runtime()
+    draft = speculative_draft(rt, mode="ternary")
+
+    def engine():
+        return ServeEngine(rt, cfg.vocab, slots=2, max_context=64,
+                           prefill_chunk=4, draft=draft, spec_k=2)
+
+    reqs = [Request(prompt=np.arange(5 + 4 * i, dtype=np.int32) % cfg.vocab,
+                    max_tokens=n, temperature=0.8, top_k=0, seed=40 + i,
+                    rid=i) for i, n in enumerate(max_tokens)]
+    comps, _ = engine().run([dataclasses.replace(r) for r in reqs],
+                            realtime=False)
+    want = {c.rid: c.tokens for c in comps}
+    eng = engine()
+    for r in reqs:
+        eng.submit(dataclasses.replace(r))
+    got = {r.rid: [] for r in reqs}
+    shared = 0
+    while eng.has_work():
+        head = eng._active[eng._prefill_q[0]] if eng._prefill_q else None
+        landing = head.rid if head is not None and len(head.chunks) == 1 \
+            else None
+        ticks = eng.ticks
+        events, _ = eng.step()
+        rids = [rid for rid, _ in events]
+        assert len(rids) == len(set(rids)), f"two events for a rid: {events}"
+        if landing is not None and eng.ticks > ticks:
+            shared += 1
+            toks = dict(events)[landing]
+            assert len(toks) >= min(2, reqs[landing].max_tokens)
+        for rid, toks in events:
+            got[rid].extend(toks)
+    assert shared == len(reqs), "every last chunk shares its step with a round"
+    assert got == want
