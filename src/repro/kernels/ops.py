@@ -35,13 +35,15 @@ def packed_matmul(x: Array, wp: Array, k: int, alpha=1.0, *, mode: str = "ternar
                   interpret: Optional[bool] = None) -> Array:
     """y = alpha * (x @ unpack(wp)).  x: (..., K); wp: (K/G, N) uint32.
 
-    Leading batch dims are flattened into M; M/N/K padded to block multiples.
-    The kernel follows the row count M: M <= 8 (decode, and the one-row
-    GEMV of BN-LSTM prefill) takes `packed_gemv`, which decodes each pair of
-    code planes once for every row and contracts it on the MXU; M > 8 takes
-    the decode-tile GEMM `packed_matmul`, the prefill path.  The paper's
-    zero-multiply loop (`packed_matmul.accumulate_planes`) runs in the fused
-    decode tick (`fused_decode_tick`).
+    Leading batch dims are flattened into M.  The kernel follows the row
+    count M: M <= 8 (decode, and the one-row GEMV of BN-LSTM prefill)
+    takes `packed_gemv`, with K padded to whole 8-word tiles of codes and
+    N to 128 lanes; M > 8 takes the GEMM `packed_matmul`, the prefill
+    path, which pads only the activation and reads the codes as they are.
+    Both decode each code word once per call and contract it on the MXU
+    against x's exact bfloat16 parts.  The paper's zero-multiply loop
+    (`packed_matmul.accumulate_planes`) runs in the fused decode tick
+    (`fused_decode_tick`).
     """
     group = PK.group_of(mode)
     lead = x.shape[:-1]
@@ -58,14 +60,7 @@ def packed_matmul(x: Array, wp: Array, k: int, alpha=1.0, *, mode: str = "ternar
         wpp = jnp.pad(wp, ((0, kp // group - wp.shape[0]), (0, -N % 128)))
         y = PK.packed_gemv(xm, wpp, kp, mode=mode, interpret=interpret)
     else:
-        # 16 rows: the smallest block that tiles bf16 as well as fp32
-        bm = 128 if M >= 128 else 16
-        bn = 128
-        bk = 256 if K % 256 == 0 else group * 8
-        xm = _pad_to(_pad_to(xm, bm, 0), bk, 1)
-        wpp = _pad_to(_pad_to(wp, bk // group, 0), bn, 1)
-        y = PK.packed_matmul(xm, wpp, xm.shape[1], mode=mode,
-                             block=(bm, bn, bk), interpret=interpret)
+        y = PK.packed_matmul(xm, wp, K, mode=mode, interpret=interpret)
     y = y[:M, :N] * jnp.asarray(alpha, jnp.float32)
     return y.reshape(*lead, N)
 

@@ -3,15 +3,21 @@ weights, and fused stochastic quantize+pack.
 
 This is the TPU-native translation of the paper's MAC-free ASIC engine
 (DESIGN.md §2): the ±1/0 weights live PACKED in HBM (16x / 32x fewer weight
-bytes than fp32), are decoded to bf16 inside VMEM by the VPU (shift/and/
-select — no cross-lane work since packing is along the contraction axis), and
-the MXU consumes the decoded tile.  Decode-bound GEMV/GEMM arithmetic
-intensity rises by the packing factor, which is exactly where the paper's
-"12x memory bandwidth" claim lands on a TPU.
+bytes than fp32), are decoded to bf16 inside VMEM by the VPU (one shift and
+one mask put two weights' bit patterns into a word's two halves — no
+cross-lane work since packing is along the contraction axis), and the MXU
+consumes the decoded tile.  Decode-bound GEMV/GEMM arithmetic intensity
+rises by the packing factor, which is exactly where the paper's "12x memory
+bandwidth" claim lands on a TPU.
 
-Tiling: grid (M/bm, N/bn, K/bk), K innermost so the fp32 VMEM accumulator
-carries across the K loop; all dims MXU-aligned (multiples of 8/128).  The
-packed operand's K axis is bk/GROUP uint32 rows per tile.
+Tiling: the GEMV (`packed_gemv`, at most 8 rows) and the GEMM
+(`packed_matmul`, more) grid over N tiles.  Each step decodes its (K, bn)
+code tile once into a bfloat16 VMEM tile and contracts it in one dot
+against every row; the activation, split once per call into exact
+bfloat16 parts, is one block that stays in VMEM across the N tiles.  Only
+the GEMM's edges add grid axes, chosen from M, K and N (`_gemm_plan`):
+row tiles beyond 256 rows, innermost, reusing the first one's decode, and
+K blocks, innermost, where all of K does not fit its VMEM budget.
 """
 from __future__ import annotations
 
@@ -26,23 +32,6 @@ from repro.core.quantize import BINARY_GROUP, TERNARY_GROUP
 from repro.kernels import dispatch
 
 Array = jax.Array
-
-
-def _unpack_ternary_tile(packed: Array, bk: int) -> Array:
-    """(bk/16, bn) uint32 -> (bk, bn) float32 in {-1, 0, +1}."""
-    shifts = (2 * jnp.arange(TERNARY_GROUP, dtype=jnp.uint32))[None, :, None]
-    codes = (packed[:, None, :] >> shifts) & jnp.uint32(3)
-    vals = jnp.where(codes == 1, 1.0, jnp.where(codes == 3, -1.0, 0.0))
-    return vals.reshape(bk, packed.shape[-1])
-
-
-def _unpack_binary_tile(packed: Array, bk: int) -> Array:
-    """(bk/32, bn) uint32 -> (bk, bn) float32 in {-1, +1}.  A select, not a
-    uint32 -> float cast: Mosaic has no such cast."""
-    shifts = jnp.arange(BINARY_GROUP, dtype=jnp.uint32)[None, :, None]
-    bits = (packed[:, None, :] >> shifts) & jnp.uint32(1)
-    vals = jnp.where(bits == 1, 1.0, -1.0)
-    return vals.reshape(bk, packed.shape[-1])
 
 
 def group_of(mode: str) -> int:
@@ -228,58 +217,170 @@ def packed_gemv(x: Array, wp: Array, k: int, *, mode: str,
     return y[:m]
 
 
-def _matmul_kernel(x_ref, wp_ref, o_ref, acc_ref, *, bk: int, mode: str):
-    @pl.when(pl.program_id(2) == 0)
+# the GEMM's tiles: rows in one tile (more rows take tiles of this many),
+# lanes of an N tile, and the VMEM its tiles are planned within.  The v5e
+# has 128 MiB of VMEM; the plan's own count (`_gemm_vmem`) sets the
+# compiler's limit per call
+GEMM_ROWS = 256
+GEMM_COLS = 512
+GEMM_VMEM_BYTES = 48 * 2**20
+
+
+def _up(a: int, m: int) -> int:
+    return -(-a // m) * m
+
+
+def _gemm_vmem(parts: int, bm: int, bn: int, bk: int, kp: int, ni: int,
+               nk: int, group: int) -> int:
+    """VMEM bytes of one plan of the GEMM: the activation block (one buffer
+    when it is the whole operand, two otherwise), two code blocks, the
+    decoded tile (all of K when row tiles reuse it), the dot's result, the
+    accumulator, two output blocks, and the decode's word temporaries."""
+    x = parts * bm * bk * 2 * (1 if ni == nk == 1 else 2)
+    codes = bk // group * bn * 4
+    w = (kp if ni > 1 else bk) * bn * 2
+    acc = parts * bm * bn * 4 + (bm * bn * 4 if nk > 1 else 0)
+    return x + 4 * codes + w + acc + 2 * bm * bn * 4
+
+
+def _gemm_plan(m: int, kp: int, n: int, parts: int, group: int):
+    """(mp, bm, bn, bk) of the GEMM, from its shapes alone: every row in one
+    tile up to GEMM_ROWS (mp is m rounded up to 16, one bfloat16 sublane
+    tile), the widest N tile up to GEMM_COLS, and all of K in one block
+    unless that cannot fit GEMM_VMEM_BYTES; then the fewest K blocks that
+    do, each whole 8-word tiles of codes."""
+    mp = _up(m, 16)
+    bm = min(mp, GEMM_ROWS)
+    mp = _up(mp, bm)
+    unit = 8 * group
+    cols = [n] if n <= 128 else [c for c in (GEMM_COLS, 256, 128)
+                                 if c <= _up(n, 128)]
+    for nk in range(1, kp // unit + 1):
+        if kp % (nk * unit):
+            continue
+        for bn in cols:
+            if _gemm_vmem(parts, bm, bn, kp // nk, kp, mp // bm, nk,
+                          group) <= GEMM_VMEM_BYTES:
+                return mp, bm, bn, kp // nk
+    raise ValueError(f"no GEMM tiles fit {GEMM_VMEM_BYTES} bytes of VMEM "
+                     f"at {(m, kp, n)}")
+
+
+def _matmul_kernel(x_ref, wp_ref, o_ref, w_ref, *acc_ref, parts: int,
+                   keep: bool, mode: str):
+    i, kk = pl.program_id(1), pl.program_id(2)
+    rows2 = 2 * wp_ref.shape[0]
+    bk = rows2 * group_of(mode) // 2
+    off = pl.multiple_of(kk * bk, bk) if keep else 0
+
+    @pl.when(i == 0)
+    def _decode():
+        words = wp_ref[...]                               # (bk/G, bn) uint32
+        for p in range(group_of(mode) // 2):
+            w_ref[pl.ds(off + p * rows2, rows2), :] = _pair_plane(words, p,
+                                                                  mode)
+
+    y = jnp.dot(x_ref[...], w_ref[pl.ds(off, bk), :],
+                preferred_element_type=jnp.float32)      # (parts*bm, bn)
+    bm = o_ref.shape[0]
+    y = functools.reduce(jnp.add, [y[q * bm:(q + 1) * bm]
+                                   for q in range(parts)])
+    if not acc_ref:
+        o_ref[...] = y * 0.5        # the decoded weights' factor of 2
+        return
+    acc, = acc_ref
+
+    @pl.when(kk == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        acc[...] = jnp.zeros_like(acc)
 
-    unpack = _unpack_ternary_tile if mode == "ternary" else _unpack_binary_tile
-    w = unpack(wp_ref[...], bk).astype(jnp.bfloat16)   # +-1/0: exact
-    x = x_ref[...]
-    # float32 activations in float32, where one bfloat16 pass would round x
-    # to 8 significand bits
-    parts = (bf16_parts(x) if x.dtype == jnp.float32
-             else [x.astype(jnp.bfloat16)])
-    for xp in parts:
-        acc_ref[...] += jnp.dot(xp, w, preferred_element_type=jnp.float32)
+    acc[...] += y
 
-    @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
+    @pl.when(kk == pl.num_programs(2) - 1)
     def _done():
-        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+        o_ref[...] = acc[...] * 0.5
 
 
 def packed_matmul(x: Array, wp: Array, k: int, *, mode: str,
-                  block: tuple[int, int, int] = (128, 128, 256),
                   interpret: bool | None = None) -> Array:
-    """x: (M, K) fp; wp: (K/G, N) uint32 packed -> (M, N) fp32 (unscaled).
+    """Prefill-shape matmul against packed codes: x (M, K), packed wp
+    (K/G, N) -> (M, N) fp32 (unscaled), one launch named
+    `<mode>_packed_matmul`.  Any M, K and N: padding is done here, of the
+    activation only.
 
-    M, N, K must already be multiples of the block dims (ops.py pads).
-    """
+    The GEMV's body (`packed_gemv`) at many rows.  x is split once, outside
+    the grid, into its three bfloat16 parts (`bf16_parts`; one part for
+    bfloat16 x), stacked as rows of one operand in `pair_major` column
+    order.  The grid runs over N tiles, and each step decodes its (K, bn)
+    code tile once, a pair of code slots per shift and mask
+    (`_pair_plane`), into a bfloat16 VMEM tile that one dot contracts
+    against every row and part; the parts' float32 sums add up to the
+    float32 result.  With every row in one tile and all of K in one block
+    (`_gemm_plan`: every configuration's prefill at up to 256 rows but the
+    widest K in float32), the activation's block never changes, so Pallas
+    reads it from HBM once a call, and the codes once.  The last N tile
+    may run past N: its columns past N decode whatever lies there, always
+    to finite +-2/0, and their writes are dropped, so the codes are never
+    copied to pad N.  Their K rows are not padded either: a code block
+    past the codes' rows meets zero-padded activations.
+
+    Beyond one row tile (above GEMM_ROWS rows) the rows run innermost, the
+    first row tile decodes, and the rest reuse the decoded tile.  Where all
+    of K would not fit the VMEM budget, K runs in blocks innermost into a
+    float32 accumulator over all of a tile's rows; each code word is still
+    decoded once."""
     group = group_of(mode)
-    M, K = x.shape
-    N = wp.shape[1]
-    if K != k or wp.shape[0] * group != K:
-        raise ValueError(f"packed K mismatch: {wp.shape[0]}*{group} != {K}")
-    bm, bn, bk = block
-    if M % bm or N % bn or K % bk or bk % group:
-        raise ValueError(f"blocks {block} must divide {(M, N, K)} (bk % {group} == 0)")
+    M = x.shape[0]
+    R, N = wp.shape
+    if k != x.shape[1] or k > R * group:
+        raise ValueError(f"packed K mismatch: x {x.shape}, k={k}, codes "
+                         f"cover {R}*{group}")
+    kp = _up(R * group, 8 * group)
+    f32 = x.dtype == jnp.float32
+    parts = 3 if f32 else 1
+    mp, bm, bn, bk = _gemm_plan(M, kp, N, parts, group)
+    ni, nj, nk = mp // bm, pl.cdiv(N, bn), kp // bk
     interpret = dispatch.resolve_interpret(interpret)
 
-    kernel = functools.partial(_matmul_kernel, bk=bk, mode=mode)
-    dispatch.count_launch(f"{mode}_packed_matmul")
-    return pl.pallas_call(
-        kernel,
-        grid=(M // bm, N // bn, K // bk),
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bk // group, bn), lambda i, j, kk: (kk, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+    xp = jnp.pad(x, ((0, mp - M), (0, kp - k)))
+    # each K block in its decoded tile's row order; each row tile's parts
+    # as consecutive row groups
+    xt = jnp.stack([pair_major(v.reshape(mp * nk, bk), group).reshape(mp, kp)
+                    for v in (bf16_parts(xp) if f32
+                              else [xp.astype(jnp.bfloat16)])])
+    xt = xt.reshape(parts, ni, bm, kp).transpose(1, 0, 2, 3).reshape(
+        ni * parts * bm, kp)
+    if ni == nk == 1:       # the whole operand, read once: one buffer
+        x_spec = pl.BlockSpec(xt.shape, lambda j, i, kk: (0, 0),
+                              pipeline_mode=pl.Buffered(1))
+    else:
+        x_spec = pl.BlockSpec((parts * bm, bk), lambda j, i, kk: (i, kk))
+    # row tiles past the first reuse the decoded tile: their code block
+    # stays the last one fetched, so none is fetched again
+    w_spec = pl.BlockSpec((bk // group, bn), lambda j, i, kk: (
+        jnp.where(i == 0, kk, nk - 1), j))
+    keep = ni > 1
+    scratch = [pltpu.VMEM((kp if keep else bk, bn), jnp.bfloat16)]
+    if nk > 1:
+        scratch.append(pltpu.VMEM((bm, bn), jnp.float32))
+    vmem = _gemm_vmem(parts, bm, bn, bk, kp, ni, nk, group)
+    name = f"{mode}_packed_matmul"
+    dispatch.count_launch(name)
+    y = pl.pallas_call(
+        functools.partial(_matmul_kernel, parts=parts, keep=keep, mode=mode),
+        grid=(nj, ni, nk),
+        in_specs=[x_spec, w_spec],
+        out_specs=pl.BlockSpec((bm, bn), lambda j, i, kk: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((mp, N), jnp.float32),
+        scratch_shapes=scratch,
+        # row tiles after the first read the tile the first one decoded
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=max(16 * 2**20, vmem * 5 // 4 + 4 * 2**20)),
         interpret=interpret,
-        name=f"{mode}_packed_matmul",
-    )(x, wp)
+        name=name,
+    )(xt, wp)
+    return y[:M]
 
 
 # ---------------------------------------------------------------------------

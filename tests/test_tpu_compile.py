@@ -29,6 +29,7 @@ from repro.configs import get_config, rnn_paper
 from repro.core.qtensor import QTensor
 from repro.kernels import decode_step as DK
 from repro.kernels import ops
+from repro.kernels import packed_matmul as PK
 
 GROUP = {"ternary": 16, "binary": 32}
 MODES = ("ternary", "binary")
@@ -129,11 +130,17 @@ def test_packed_gemv_compiles(one_chip, mode, M):
 
 
 @pytest.mark.parametrize("mode", MODES)
-@pytest.mark.parametrize("mkn", [(256, 1024, 4096), (32, 1008, 4000)])
+@pytest.mark.parametrize("mkn", [(256, 1024, 4096), (32, 1008, 4000),
+                                 (128, 28672, 8192), (256, 28672, 8192)])
 def test_packed_matmul_compiles(one_chip, mode, mkn):
-    """The MXU decode-tile GEMM (M > 8): a 256-token prefill chunk at
-    rnn-paper's padded width, and a short ragged one."""
+    """The MXU GEMM (M > 8): a 256-token prefill chunk at rnn-paper's
+    padded width, a short ragged one, and llama-3.2-vision-90b's down
+    projection, the widest contraction of the configurations, at 128 rows
+    (all of K in one block) and at 256 (K in blocks: all of K would not fit
+    the VMEM budget in float32)."""
     M, K, N = mkn
+    bk = PK._gemm_plan(M, _up(K, 8 * GROUP[mode]), N, 3, GROUP[mode])[3]
+    assert (bk < K) == (mkn == (256, 28672, 8192))
     x = jax.ShapeDtypeStruct((M, K), jnp.float32, sharding=one_chip)
     wp = jax.ShapeDtypeStruct((_up(K, GROUP[mode]) // GROUP[mode], N),
                               jnp.uint32, sharding=one_chip)
@@ -175,14 +182,15 @@ def _zamba2_7b_matrices():
             "linear": (d, d)}
 
 
-@pytest.mark.parametrize("M", [8, 128])
+@pytest.mark.parametrize("M", [8, 128, 16, 64])
 @pytest.mark.parametrize("mat", ["mamba_in", "mamba_out", "attn_qkv",
                                  "attn_o", "gate_up", "down", "adapter_in",
                                  "adapter_out", "linear"])
 def test_zamba2_7b_matrix_compiles(one_chip, mat, M):
     """zamba2-7b's ternary matrices through `qmatmul` at their published
-    widths, float32 activations: the GEMV (MXU body) of a decode tick over
-    8 slots, and the MXU GEMM of a 128-token prefill chunk."""
+    widths, float32 activations: the GEMV of a decode tick over 8 slots,
+    and the GEMM of a 128-token prefill chunk and of the 16- and 64-row
+    buckets a chunk's tail is padded to."""
     K, N = _zamba2_7b_matrices()[mat]
     S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     qt = QTensor(codes=S((K // 16, N), jnp.uint32), scale=S((N,), jnp.float32),
